@@ -28,6 +28,8 @@ the codec-on COMMS_BUDGET.json entries pin.
 import jax
 import jax.numpy as jnp
 
+from fedml_tpu.core.builder import all_gather_invariant
+
 
 def _is_inexact(leaf):
     return jnp.issubdtype(jnp.asarray(leaf).dtype, jnp.inexact)
@@ -145,10 +147,14 @@ def transport_wsum(codec, wsum_tree, resid_tree, axis, contributors):
             _, idx = jax.lax.top_k(jnp.abs(flat), k)
             idx = idx.astype(jnp.int32)
             values = flat[idx]
-            g_idx = jax.lax.all_gather(idx, axis)       # (D, k) wire
-            g_val = jax.lax.all_gather(values, axis)    # (D, k) wire
-            total = jnp.zeros_like(flat).at[g_idx.reshape(-1)].add(
-                g_val.reshape(-1))
+            # invariant-typed: every device scatter-adds the same pairs,
+            # so the sum types as the replicated value out_specs says it is
+            g_idx = all_gather_invariant(idx, axis)       # (D, k) wire
+            g_val = all_gather_invariant(values, axis)    # (D, k) wire
+            # zeros by shape, not zeros_like(flat): flat varies over `axis`
+            # and zeros_like would carry that type into the invariant sum
+            total = jnp.zeros(flat.shape, flat.dtype).at[
+                g_idx.reshape(-1)].add(g_val.reshape(-1))
             dec_local = jnp.zeros_like(flat).at[idx].set(values)
             return (total.reshape(t.shape),
                     t - dec_local.reshape(t.shape))

@@ -39,6 +39,14 @@ from fedml_tpu.utils.pytree import tree_where
 
 # --------------------------------------------------------- shared fragments
 
+# The gather whose result every device holds alike, TYPED so: under
+# shard_map's check_vma, jax 0.9.0's public `jax.lax.all_gather` is
+# Varying -> Varying, and only this Varying -> Invariant twin lets gathered
+# params feed invariant-typed scan carries and replicated out_specs. Same
+# HLO all-gather. jax 0.9.0 does not export it under `jax.lax` yet — this is
+# the ONE import of the private name; every user takes it from here.
+from jax._src.lax.parallel import all_gather_invariant  # noqa: E402,F401
+
 
 def donation_argnums(donate_state: bool = False,
                      donate_data: bool = False) -> Tuple[int, ...]:

@@ -24,11 +24,10 @@ from fedml_tpu.experiments.common import (
 from fedml_tpu.utils.logging import MetricsLogger
 
 
-def main(argv=None, aggregator_name: str = "fedavg", extra_args=None):
-    parser = add_args(argparse.ArgumentParser())
-    if extra_args:
-        extra_args(parser)
-    args = parser.parse_args(argv)
+def run(args, aggregator_name: str = "fedavg"):
+    """Everything `main` does with parsed arguments. Returns (api, history):
+    a caller that must look at the trained state or at how cohorts were
+    staged (chip_smoke.py --multichip) drives exactly the CLI's path."""
     cfg, ds, trainer = setup_run(args)
     logger = MetricsLogger(run_dir=args.run_dir, config=vars(args))
     api = FedAvgAPI(ds, cfg, trainer, aggregator_name=aggregator_name)
@@ -49,6 +48,14 @@ def main(argv=None, aggregator_name: str = "fedavg", extra_args=None):
     logger.finish()
     if getattr(args, "trace_summary", 0):
         print(tracer.summary_table(), flush=True)
+    return api, history
+
+
+def main(argv=None, aggregator_name: str = "fedavg", extra_args=None):
+    parser = add_args(argparse.ArgumentParser())
+    if extra_args:
+        extra_args(parser)
+    _, history = run(parser.parse_args(argv), aggregator_name)
     return history
 
 

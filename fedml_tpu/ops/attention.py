@@ -19,7 +19,8 @@ Design (flash-attention-1 style, /opt/skills/guides/pallas_guide.md):
   materializes in either direction and the O(T) memory claim holds for
   training too. `parallel/sequence.py` ring attention composes the same
   recurrence across chips.
-- off-TPU (tests, CPU CI) the kernel runs in pallas interpret mode.
+- off-TPU (tests, CPU CI) the kernel runs in pallas interpret mode, and
+  says so once at warning level (ops/interpret.py).
 """
 
 from __future__ import annotations
@@ -30,11 +31,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from fedml_tpu.ops.interpret import interpret_off_chip
+
 
 def _resolve_interpret(interpret):
     if interpret is None:
-        return jax.default_backend() != "tpu"
+        return interpret_off_chip("flash_attention")
     return interpret
+
+
+def _out_struct(shape, dtype, *inputs):
+    """A pallas_call out_shape entry typed as varying over whatever mesh
+    axes its inputs vary over. Under shard_map's check_vma (jax 0.9) a
+    pallas_call whose outputs say nothing about that is refused — and the
+    transformer runs this kernel inside every mesh round. Outside shard_map
+    the set is empty and this is a plain ShapeDtypeStruct."""
+    vma = frozenset().union(*(jax.typeof(a).vma for a in inputs))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _block_live(qi, ki, q_block, k_block, causal):
@@ -154,8 +167,8 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
             pl.BlockSpec((None, block_q, 1), lambda g, i, j: (g, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, tq, 1), jnp.float32),
+            _out_struct((b * h, tq, d), q.dtype, qr, kr, vr),
+            _out_struct((b * h, tq, 1), jnp.float32, qr, kr, vr),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
@@ -277,6 +290,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
                  else jax.lax.Precision.DEFAULT)
     scale = 1.0 / np.sqrt(d)
     n_qb, n_kb = tq // block_q, tk // block_k
+    bwd_in = (qr, kr, vr, gr, lse3, delta)
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, causal=causal, n_kb=n_kb,
@@ -292,10 +306,10 @@ def _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
             pl.BlockSpec((None, block_q, 1), lambda g_, i, j: (g_, i, 0)),
         ],
         out_specs=pl.BlockSpec((None, block_q, d), lambda g_, i, j: (g_, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
+        out_shape=_out_struct((b * h, tq, d), q.dtype, *bwd_in),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-    )(qr, kr, vr, gr, lse3, delta)
+    )(*bwd_in)
 
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, causal=causal, n_qb=n_qb,
@@ -315,13 +329,13 @@ def _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
             pl.BlockSpec((None, block_k, d), lambda g_, j, i: (g_, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, tk, d), v.dtype),
+            _out_struct((b * h, tk, d), k.dtype, *bwd_in),
+            _out_struct((b * h, tk, d), v.dtype, *bwd_in),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
-    )(qr, kr, vr, gr, lse3, delta)
+    )(*bwd_in)
 
     def back4(t, tlen):
         return t.reshape(b, h, tlen, d).transpose(0, 2, 1, 3)

@@ -70,7 +70,7 @@ class FusedEpochSpec:
             raise ValueError("fused path requires samples % batch == 0")
         # sub-batch chunking: the compiled step body scales with the chunk's
         # vector sizes (an inner fori_loop body is compiled ONCE), which is
-        # what keeps the remote Mosaic compiler from being OOM-killed
+        # what keeps the Mosaic compiler from being killed for memory
         self.chunk = math.gcd(batch, chunk) if chunk else batch
         self.nchunks = batch // self.chunk
         self.H, self.W, self.C = height, width, n_classes
@@ -87,7 +87,7 @@ class FusedEpochSpec:
         self.drop1, self.drop2 = drop1, drop2
         self.cdtype = compute_dtype
         # conv2 strategy: "accum" = 9 accumulated K=32 matmuls (no [.,288]
-        # im2col buffers — the remote Mosaic compiler is SIGKILLed by the
+        # im2col buffers — the Mosaic compiler is killed for memory by the
         # vreg volume of the im2col form); "im2col" = one K=288 GEMM
         self.conv2_mode = "accum"
 
@@ -214,7 +214,7 @@ def _epoch_kernel(spec: FusedEpochSpec,
             else:
                 # 9 accumulated K=32 matmuls: ~3x worse MXU K-fill than the
                 # K=288 im2col GEMM, but avoids the [bH2W2, 288] patch buffers
-                # whose vreg volume OOM-kills the remote Mosaic compiler
+                # whose vreg volume gets the Mosaic compiler killed for memory
                 p2 = None
                 z2 = None
                 for k in range(9):

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from fedml_tpu.utils.jax_compat import shard_map
 
 
 def shift_decomposition(W: np.ndarray) -> tuple[list[int], np.ndarray]:
@@ -79,7 +78,7 @@ def build_sharded_mix(W: np.ndarray, mesh: Mesh,
             acc = acc + c[k].reshape((1,) * x.ndim) * shifted
         return acc
 
-    mix_sharded = shard_map(
+    mix_sharded = jax.shard_map(
         mix_leaf, mesh=mesh,
         in_specs=(P(axis_name), P(None, axis_name)),
         out_specs=P(axis_name),

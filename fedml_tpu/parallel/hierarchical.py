@@ -36,7 +36,6 @@ from fedml_tpu.algorithms.aggregators import (
 from fedml_tpu.algorithms.engine import build_local_update
 from fedml_tpu.core.builder import shard_key_slice
 from fedml_tpu.core.config import FedConfig
-from fedml_tpu.utils.jax_compat import pcast, shard_map
 from fedml_tpu.utils.pytree import tree_where
 
 
@@ -91,7 +90,7 @@ def build_sharded_hierarchical_round_fn(
             # inner-scan carry: starts as the invariant global broadcast,
             # exits varying over the groups axis (each group trains its own
             # line) — pcast so the carry types match under check_vma
-            gv = pcast(gv, (group_axis,), to="varying")
+            gv = jax.lax.pcast(gv, (group_axis,), to="varying")
             # the group's total client weight is round-invariant, so its
             # psum is hoisted OUT of the inner-round scan: one scalar
             # all-reduce per global round instead of one per inner round
@@ -186,14 +185,14 @@ def build_sharded_hierarchical_round_fn(
     def round_fn(global_variables, x, y, counts, rng, participation=None):
         data_spec = P(group_axis, client_axis)
         if participation is None:
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 shard_body,
                 mesh=mesh,
                 in_specs=(P(), data_spec, data_spec, data_spec, P()),
                 out_specs=(P(), P()),
             )
             return sharded(global_variables, x, y, counts, rng)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             shard_body,
             mesh=mesh,
             in_specs=(P(), data_spec, data_spec, data_spec, P(), data_spec),

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from fedml_tpu.utils.jax_compat import pcast, shard_map
 
 
 def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
@@ -74,7 +73,7 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
         # pcast to varying: the online-softmax stats become device-varying
         # inside the scan (each device sees different K/V blocks); marking
         # the init values keeps jax's check_vma carry typing satisfied
-        var = lambda a: pcast(a, (axis,), to="varying")
+        var = lambda a: jax.lax.pcast(a, (axis,), to="varying")
         o0 = var(jnp.zeros((b, h, t_local, dd), jnp.float32))
         m0 = var(jnp.full((b, h, t_local), -jnp.inf, jnp.float32))
         l0 = var(jnp.zeros((b, h, t_local), jnp.float32))
@@ -86,7 +85,7 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
         out = o / jnp.maximum(l, 1e-30)[..., None]
         return out.transpose(0, 2, 1, 3).astype(q.dtype)  # [B, T/n, H, D]
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axis), P(None, axis), P(None, axis)),
         out_specs=P(None, axis),
@@ -119,7 +118,7 @@ def ulysses_attention(q, k, v, mesh: Mesh, axis: str = "sp",
         return jax.lax.all_to_all(out, axis_name=axis,
                                   split_axis=1, concat_axis=2, tiled=True)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axis), P(None, axis), P(None, axis)),
         out_specs=P(None, axis),

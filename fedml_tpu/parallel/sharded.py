@@ -28,7 +28,6 @@ from fedml_tpu.algorithms.aggregators import quarantine_stage
 from fedml_tpu.algorithms.engine import build_local_update, cohort_stats
 from fedml_tpu.core.builder import masked_psum_tail, shard_key_slice
 from fedml_tpu.core.config import FedConfig
-from fedml_tpu.utils.jax_compat import shard_map
 
 
 def build_sharded_round_fn(
@@ -115,14 +114,14 @@ def build_sharded_round_fn(
     def round_fn(global_variables, agg_state, x, y, counts, rng,
                  participation=None):
         if participation is None:
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 shard_body,
                 mesh=mesh,
                 in_specs=(P(), st_spec, P(axis), P(axis), P(axis), P()),
                 out_specs=out_specs,
             )
             return sharded(global_variables, agg_state, x, y, counts, rng)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             shard_body,
             mesh=mesh,
             in_specs=(P(), st_spec, P(axis), P(axis), P(axis), P(), P(axis)),
@@ -262,7 +261,7 @@ def build_sharded_buffer_fns(
     def admit_fn(buf, fill, stacked_vars, stacked_steps, stacked_metrics,
                  counts, src, birth_round, *gv):
         # codec-on admits take a trailing replicated gv (the delta base)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             admit_body,
             mesh=mesh,
             in_specs=(buf_spec, P(), P(axis), P(axis), P(axis), P(axis),
@@ -273,7 +272,7 @@ def build_sharded_buffer_fns(
                        stacked_metrics, counts, src, birth_round, *gv)
 
     def commit_fn(global_variables, agg_state, buf, fill, commit_round, rng):
-        sharded = shard_map(
+        sharded = jax.shard_map(
             commit_body,
             mesh=mesh,
             in_specs=(P(), P(), buf_spec, P(), P(), P()),

@@ -53,10 +53,10 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
 from fedml_tpu.analysis.partition import _flat_paths, match_partition_rules
-from fedml_tpu.core.builder import (build_round_core, donation_argnums,
-                                    masked_psum_tail, shard_key_slice)
+from fedml_tpu.core.builder import (all_gather_invariant, build_round_core,
+                                    donation_argnums, masked_psum_tail,
+                                    shard_key_slice)
 from fedml_tpu.core.config import FedConfig
-from fedml_tpu.utils.jax_compat import shard_map
 
 CLIENT_AXIS = "clients"
 TENSOR_AXIS = "tensor"
@@ -232,12 +232,15 @@ class TensorSharding:
 
 def _gather_tree(tree, specs):
     """Reassemble full leaves from tensor shards (tiled all_gather on each
-    sharded leaf's dim) — the round-entry layer boundary."""
+    sharded leaf's dim) — the round-entry layer boundary. The gather is the
+    invariant-typed one: the full leaves are identical on every tensor
+    device and check_vma must know it, or the client step's scan carries
+    (gathered and un-gathered leaves side by side) stop typing."""
     def gather(leaf, spec):
         d = _tensor_dim(spec)
         if d is None:
             return leaf
-        return jax.lax.all_gather(leaf, TENSOR_AXIS, axis=d, tiled=True)
+        return all_gather_invariant(leaf, TENSOR_AXIS, axis=d, tiled=True)
 
     return jax.tree.map(gather, tree, specs,
                         is_leaf=lambda x: isinstance(x, PS))
@@ -284,8 +287,8 @@ def _quantized_gather_tree(tree, specs, tensor_shards: int, levels: int):
         amax = jnp.max(jnp.abs(leaf))
         scale = jnp.where(amax > 0, amax / levels, jnp.ones((), leaf.dtype))
         q = jnp.clip(jnp.round(leaf / scale), -levels, levels).astype(jnp.int8)
-        qg = jax.lax.all_gather(q, TENSOR_AXIS, axis=d, tiled=True)
-        sg = jax.lax.all_gather(scale, TENSOR_AXIS)  # (t_sz,) f32
+        qg = all_gather_invariant(q, TENSOR_AXIS, axis=d, tiled=True)
+        sg = all_gather_invariant(scale, TENSOR_AXIS)  # (t_sz,) f32
         size = leaf.shape[d]
         shp = qg.shape
         qt = qg.reshape(shp[:d] + (tensor_shards, size) + shp[d + 1:])
@@ -569,8 +572,8 @@ def build_tensor_round_fn(trainer, cfg: FedConfig, aggregator,
         out_specs = (specs_gv, specs_st, PS())
         if collect_stats:
             out_specs = out_specs + (PS(CLIENT_AXIS),)
-        fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs)
         donate = donation_argnums(donate_state, donate_data)
         return jax.jit(fn, donate_argnums=donate) if donate else jax.jit(fn)
 

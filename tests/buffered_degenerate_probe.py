@@ -48,10 +48,9 @@ def _bitwise(a, b):
 def main() -> int:
     from fedml_tpu.data.registry import load_dataset
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(repo, ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    from fedml_tpu.utils.cache import enable_compile_cache
+
+    enable_compile_cache(min_compile_secs=0.5)
     ds = load_dataset("mnist", client_num_in_total=8,
                       partition_method="homo", seed=0)
     for agg in ("fedavg", "fedopt"):

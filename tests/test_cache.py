@@ -59,14 +59,49 @@ def test_env_opt_out(tmp_path, restore_jax_cache_config, monkeypatch):
 
 
 def test_env_dir_override(tmp_path, restore_jax_cache_config, monkeypatch):
-    d = str(tmp_path / "envdir")
-    monkeypatch.setenv("FEDML_TPU_COMPILE_CACHE_DIR", d)
+    """A directory placed from outside stands: jax reads
+    JAX_COMPILATION_CACHE_DIR itself (once, at import), so with the variable
+    set enable_compile_cache() leaves the directory as jax read it and sets
+    no other — only the threshold moves."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "envdir"))
+    as_jax_read_it = jax.config.jax_compilation_cache_dir
     assert enable_compile_cache(min_compile_secs=0.0)
-    assert jax.config.jax_compilation_cache_dir == d
+    assert jax.config.jax_compilation_cache_dir == as_jax_read_it
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+
+
+def test_env_dir_is_read_by_jax_not_set_by_repo(tmp_path):
+    """End to end in a fresh interpreter: with the variable set, the
+    directory in effect after enable_compile_cache() is the variable's, and
+    a compile lands there; unset, it is <checkout>/.jax_cache."""
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    prog = ("import jax, jax.numpy as jnp\n"
+            "from fedml_tpu.utils.cache import enable_compile_cache\n"
+            "before = jax.config.jax_compilation_cache_dir\n"
+            "assert enable_compile_cache(min_compile_secs=0.0)\n"
+            "jax.jit(lambda x: jnp.tanh(x) @ x.T)(jnp.ones((8, 8)))"
+            ".block_until_ready()\n"
+            "print(before, jax.config.jax_compilation_cache_dir)\n")
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR",
+                        "FEDML_TPU_NO_COMPILE_CACHE")}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=repo)
+    d = str(tmp_path / "outside")
+    out = subprocess.run([sys.executable, "-c", prog], check=True, cwd=repo,
+                         env={**env, "JAX_COMPILATION_CACHE_DIR": d},
+                         capture_output=True, text=True, timeout=120)
+    assert out.stdout.split() == [d, d]          # jax read it; nobody reset it
+    assert _cache_files(d), "the compile wrote no entry into the outside dir"
+    out = subprocess.run([sys.executable, "-c", prog], check=True, cwd=repo,
+                         env=env, capture_output=True, text=True, timeout=120)
+    assert out.stdout.split() == ["None", os.path.join(repo, ".jax_cache")]
 
 
 def test_default_is_repo_local(restore_jax_cache_config, monkeypatch):
-    monkeypatch.delenv("FEDML_TPU_COMPILE_CACHE_DIR", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.delenv("FEDML_TPU_NO_COMPILE_CACHE", raising=False)
     assert enable_compile_cache()
     assert jax.config.jax_compilation_cache_dir.endswith(".jax_cache")

@@ -1,0 +1,167 @@
+"""The main path's programs compile for the chip — asked of the TPU compiler
+in this sandbox, for a v5e that is described and not attached
+(on-chip-measurement guide, section 2.3). Nothing runs: a pass says that the
+chip's compiler accepts the program and that it fits 16 GB, never how fast
+it is.
+
+Rules this file keeps (the guide's): the topology is described only inside
+the module-scoped `topo` fixture, which skips when it cannot be; nothing
+touches `jax.experimental.topologies` while any module is imported; the
+fixtures are not autouse and live here, in the ONE file that describes the
+chip; every compile happens in the test's own process; the persistent
+compile cache is off around them (an entry compiled for an absent chip
+cannot be read back and would only warn).
+
+`tests/conftest.py` appends `--xla_backend_optimization_level=0` to
+XLA_FLAGS for the whole fast suite. The TPU compile here does not read it:
+with and without the flag the flagship round compiles to the same
+temp/argument/code bytes (measured while writing this file), so what these
+tests accept is what the chip's compiler accepts at its own default level.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+HBM_BYTES = 16e9  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _on(sharding, tree):
+    """ShapeDtypeStructs of `tree`'s leaves, placed on the described chip."""
+    return jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=sharding),
+        tree)
+
+
+def _fits(compiled) -> float:
+    mem = compiled.memory_analysis()
+    need = mem.temp_size_in_bytes + mem.argument_size_in_bytes
+    assert need < HBM_BYTES, f"needs {need / 1e9:.2f} GB of a 16 GB chip"
+    return need
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("shape,dtype", [
+    ((2, 2048, 8, 64), jnp.float32),
+    ((2, 8192, 8, 64), jnp.bfloat16),
+], ids=["T2048-f32", "T8192-bf16"])
+def test_flash_attention_compiles(one_chip, no_compile_cache, shape, dtype,
+                                  direction):
+    from fedml_tpu.ops.attention import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, True, 128, 128, False)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    fn = fwd if direction == "forward" else jax.grad(loss, argnums=(0, 1, 2))
+    arg = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(fn).lower(arg, arg, arg).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+def _round_program(one_chip, model, output_dim, sample_shape, clients,
+                   samples, **cfg_kw):
+    """The round program `FedAvgAPI` builds for the CLI's default drive
+    (pipelined: cohort buffers donated, ledger stats collected), lowered
+    from eval_shape'd variables on the described chip."""
+    from fedml_tpu.algorithms.aggregators import make_aggregator
+    from fedml_tpu.algorithms.engine import build_round_fn
+    from fedml_tpu.core.config import FedConfig
+    from fedml_tpu.core.trainer import ClassificationTrainer
+    from fedml_tpu.models.registry import create_model
+
+    cfg = FedConfig(model=model, client_num_in_total=clients,
+                    client_num_per_round=clients, epochs=1, **cfg_kw)
+    trainer = ClassificationTrainer(
+        create_model(model, output_dim=output_dim, dtype=cfg.dtype))
+    agg = make_aggregator("fedavg", cfg)
+    gv = jax.eval_shape(lambda: trainer.init(
+        jax.random.PRNGKey(0), jnp.zeros((1,) + sample_shape)))
+    state = jax.eval_shape(agg.init_state, gv)
+    round_fn = build_round_fn(trainer, cfg, agg, donate_data=True,
+                              collect_stats=True)
+    args = _on(one_chip, (
+        gv, state,
+        jax.ShapeDtypeStruct((clients, samples) + sample_shape, jnp.float32),
+        jax.ShapeDtypeStruct((clients, samples), jnp.int32),
+        jax.ShapeDtypeStruct((clients,), jnp.int32),
+        jax.eval_shape(lambda: jax.random.PRNGKey(0))))
+    return round_fn.jitted.lower(*args).compile()
+
+
+def test_flagship_round_compiles(one_chip, no_compile_cache):
+    """engine.round for CNN_DropOut, 10 clients x 480 x 28x28, bs 20, f32 —
+    what `chip_smoke.py`'s flagship phase dispatches every round."""
+    compiled = _round_program(
+        one_chip, "cnn", 62, (28, 28, 1), clients=10, samples=480,
+        batch_size=20, lr=0.1)
+    _fits(compiled)
+
+
+def test_flagship_federation_eval_fits_beside_its_split(one_chip,
+                                                        no_compile_cache):
+    """The resident all-clients eval at 3400 FEMNIST writers, in the chunk
+    geometry `FedAvgAPI` picks (fedavg._eval_chunk). At the old 64-client
+    chunk this program needed 19.1 GB (8.7 GB of conv outputs per step on
+    top of the split and XLA's converted copy of it) and the README's first
+    command could not evaluate on a 16 GB chip."""
+    from fedml_tpu.algorithms.engine import build_federation_eval_fn
+    from fedml_tpu.algorithms.fedavg import _eval_chunk
+    from fedml_tpu.core.trainer import ClassificationTrainer
+    from fedml_tpu.models.registry import create_model
+
+    clients, n_max = 3400, 480
+    chunk = _eval_chunk(n_max, clients)
+    nc = -(-clients // chunk)
+    trainer = ClassificationTrainer(create_model("cnn", output_dim=62))
+    gv = jax.eval_shape(lambda: trainer.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 28, 28, 1))))
+    args = _on(one_chip, (
+        gv,
+        jax.ShapeDtypeStruct((nc, chunk, n_max, 28, 28, 1), jnp.float32),
+        jax.ShapeDtypeStruct((nc, chunk, n_max), jnp.int32),
+        jax.ShapeDtypeStruct((nc, chunk), jnp.int32)))
+    compiled = build_federation_eval_fn(trainer).lower(*args).compile()
+    # leave room for the test split, the params and the staged cohorts
+    assert _fits(compiled) < 14e9
+
+
+@pytest.mark.slow  # ~50 s of TPU compile
+def test_cross_silo_round_compiles(one_chip, no_compile_cache):
+    """engine.round for ResNet-56 in bf16, 10 silos x 500 x 32x32x3, bs 64 —
+    `chip_smoke.py`'s cross_silo phase."""
+    compiled = _round_program(
+        one_chip, "resnet56", 10, (32, 32, 3), clients=10, samples=500,
+        batch_size=64, dtype="bfloat16")
+    _fits(compiled)

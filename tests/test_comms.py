@@ -21,7 +21,6 @@ from fedml_tpu.analysis.hlo_engine import (
     parse_hlo_text,
     shape_bytes,
 )
-from fedml_tpu.utils.jax_compat import shard_map
 
 N = 8
 
@@ -33,8 +32,8 @@ def _mesh():
 def _sharded1d(body, n_in=1):
     mesh = _mesh()
     specs = tuple(P("i") for _ in range(n_in))
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=specs,
-                             out_specs=P("i")))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=specs,
+                                 out_specs=P("i")))
 
 
 _S = jax.ShapeDtypeStruct((N, 16), jnp.float32)
