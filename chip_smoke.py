@@ -55,9 +55,6 @@ CROSS_SILO_ARGV = [
     "--batch_size", "64", "--epochs", "1", "--partition_method", "homo",
     "--dtype", "bfloat16"]
 
-BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-
 def emit(record: dict) -> None:
     print(json.dumps(record), flush=True)
 
@@ -75,40 +72,28 @@ def peak_bytes() -> int | None:
     return None if stats is None else stats.get("peak_bytes_in_use")
 
 
-class CompileLog:
-    """(end_time, seconds) of every backend compile — cache hit or miss —
-    while open, on the tracer's clock (time.perf_counter)."""
-
-    def __enter__(self):
-        self.compiles: list[tuple[float, float]] = []
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-        return self
-
-    def _on_event(self, event: str, duration: float, **_) -> None:
-        if event == BACKEND_COMPILE_EVENT:
-            self.compiles.append((time.perf_counter(), duration))
-
-    def __exit__(self, *exc) -> None:
-        jax.monitoring.unregister_event_duration_listener(self._on_event)
-
-
-def compiles_after_round(compiles: list[tuple[float, float]],
+def compiles_after_round(compiles: list[dict],
                          round_span: dict) -> list[float]:
-    """Seconds past the end of `round_span` at which each later compile
-    finished; empty when the program had settled by then."""
+    """Seconds past the end of `round_span` at which each later `compile`
+    event (stamped `t` when the compile ended) fell; empty when the program
+    had settled by then."""
     t_settled = round_span["t0"] + round_span["dur_s"]
-    return [round(t - t_settled, 3) for t, _ in compiles if t > t_settled]
+    return [round(c["t"] - t_settled, 3) for c in compiles
+            if c["t"] > t_settled]
 
 
-def read_spans(run_dir: str) -> dict[str, list[dict]]:
-    """The run's TRACE.jsonl spans, by name."""
+def read_trace(run_dir: str) -> tuple[dict[str, list[dict]], list[dict]]:
+    """The run's TRACE.jsonl: (spans by name, `compile` events)."""
     from fedml_tpu.telemetry.report import load_trace
 
     spans: dict[str, list[dict]] = {}
+    compiles = []
     for rec in load_trace(os.path.join(run_dir, "TRACE.jsonl")):
         if rec.get("type") == "span":
             spans.setdefault(rec["name"], []).append(rec)
-    return spans
+        elif rec.get("kind") == "compile":
+            compiles.append(rec)
+    return spans, compiles
 
 
 def run_fedavg(phase: str, argv: list[str], rounds: int, run_dir: str,
@@ -126,8 +111,7 @@ def run_fedavg(phase: str, argv: list[str], rounds: int, run_dir: str,
                    "--frequency_of_the_test", str(test_every),
                    "--run_dir", run_dir]
     t_enter = time.perf_counter()
-    with CompileLog() as log:
-        history = fedavg_main(argv)
+    history = fedavg_main(argv)
     t_exit = time.perf_counter()
     # FedAvgAPI holds itself in a cycle (stage_fn is its own bound method),
     # so its device-resident eval splits — gigabytes at 3400 clients — go
@@ -162,32 +146,28 @@ def run_fedavg(phase: str, argv: list[str], rounds: int, run_dir: str,
         raise AssertionError(f"{phase}: Test/Acc {last['Test/Acc']} is not "
                              f"above {min_test_acc}")
 
-    spans = read_spans(run_dir)
+    spans, compiles = read_trace(run_dir)
     round_spans = {s["round"]: s for s in spans["round"]}
     if sorted(round_spans) != list(range(rounds)):
         raise AssertionError(f"{phase}: TRACE.jsonl has round spans "
                              f"{sorted(round_spans)}")
-    late = compiles_after_round(log.compiles, round_spans[compile_free_after])
+    late = compiles_after_round(compiles, round_spans[compile_free_after])
     if late:
         raise AssertionError(
             f"{phase}: {len(late)} compile(s) after round "
             f"{compile_free_after} ended (seconds after: {late})")
 
     drive_t0 = spans["drive"][0]["t0"]
-    # the data build is host-only numpy: the first compile (PRNGKey in
-    # FedAvgAPI.__init__) starts right after it, so entry -> first compile
-    # is argument parsing + the data build
-    first_compile_start = log.compiles[0][0] - log.compiles[0][1]
     later = [round_spans[r]["dur_s"] for r in range(1, rounds)]
     return {
         "phase": phase, "ok": True, "rounds": rounds,
         "first": {k: first[k] for k in ("Train/Loss", "Test/Loss",
                                         "Test/Acc")},
         "last": {k: last[k] for k in ("Train/Loss", "Test/Loss", "Test/Acc")},
-        "compiles": len(log.compiles),
-        "compile_s_total": sum(d for _, d in log.compiles),
+        "compiles": len(compiles),
+        "compile_s_total": sum(c["dur_s"] for c in compiles),
         "smoke_timing": {
-            "data_build_s": first_compile_start - t_enter,
+            "data_build_s": spans["data_load"][0]["dur_s"],
             "setup_s": drive_t0 - t_enter,
             "first_round_s": round_spans[0]["dur_s"],
             "later_rounds_median_s": statistics.median(later),

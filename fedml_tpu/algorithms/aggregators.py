@@ -72,6 +72,7 @@ def client_finite_mask(stacked_tree) -> jnp.ndarray:
     return jnp.stack(per_leaf, axis=0).all(axis=0)
 
 
+@jax.named_scope("quarantine")
 def quarantine_stage(result, weights, participation):
     """Compose the participation mask with per-client finite-ness and zero
     out dead rows BEFORE aggregation.

@@ -472,10 +472,7 @@ def train_buffered(api, start_round: int, ckpt_dir, ckpt_every,
                 retries = 0
                 if (round_idx % cfg.frequency_of_the_test == 0
                         or round_idx == cfg.comm_round - 1):
-                    with tracer.span("eval", round_idx):
-                        record.update(
-                            api.local_test_on_all_clients(round_idx))
-                        record.update(api.test_global(round_idx))
+                    record.update(api.evaluate(round_idx, tracer))
                 records.add(record)
                 records.flush(round_idx)
                 if ckpt_dir and (round_idx + 1) % ckpt_every == 0:

@@ -135,6 +135,23 @@ def _merge_variables(variables, new_params, new_state):
     return out
 
 
+def epoch_batches(n_max: int, batch_size: int) -> tuple[int, int]:
+    """(steps, batch) of one local epoch over a client padded to `n_max`
+    rows: the ONE place that owns the `nb * b` arithmetic. `_build_epoch_fn`
+    shapes its scan with it and `round_slots` counts with it, so a change to
+    the padding changes the count too."""
+    b = n_max if batch_size <= 0 else min(batch_size, n_max)
+    return math.ceil(n_max / b), b
+
+
+def round_slots(cfg: FedConfig, clients: int, n_max: int) -> int:
+    """Sample slots the round program executes for a staged cohort of
+    `clients` x `n_max` rows, padding included: clients x local steps x
+    batch x epochs. A host integer from shapes alone."""
+    nb, b = epoch_batches(n_max, cfg.batch_size)
+    return clients * nb * b * cfg.epochs
+
+
 def _build_epoch_fn(trainer, cfg: FedConfig, opt) -> Callable:
     """Shared one-local-epoch body: epoch_fn(global_params, carry, x, y,
     count, erng) -> (carry, auxs) with carry = (variables, opt_state, steps).
@@ -161,8 +178,7 @@ def _build_epoch_fn(trainer, cfg: FedConfig, opt) -> Callable:
 
     def epoch_fn(global_params, carry, x, y, count, erng):
         n_max = x.shape[0]
-        b = n_max if cfg.batch_size <= 0 else min(cfg.batch_size, n_max)
-        nb = math.ceil(n_max / b)
+        nb, b = epoch_batches(n_max, cfg.batch_size)
         n_pad = nb * b
         if full and n_pad != n_max:
             raise ValueError(
@@ -357,6 +373,7 @@ def _vmapped_personal_update(trainer, cfg: FedConfig) -> Callable:
     return batched
 
 
+@jax.named_scope("cohort_stats")
 def cohort_stats(global_variables, result: LocalResult) -> dict:
     """Static-shape per-cohort health stats for the client ledger.
 
@@ -891,6 +908,7 @@ def _vmapped_client_eval(trainer) -> Callable:
     the shared core of both eval builders below (one mask/eval definition so
     the chunked and resident paths cannot drift apart)."""
 
+    @jax.named_scope("client_eval")
     def one(variables, x, y, count):
         mask = (jnp.arange(x.shape[0]) < count).astype(jnp.float32)
         return trainer.eval_fn(variables, {"x": x, "y": y, "mask": mask})
@@ -914,6 +932,7 @@ def build_personal_client_eval_fn(trainer) -> Callable:
     bank's lift column). Same mask/eval body as _vmapped_client_eval so
     the two eval definitions cannot drift."""
 
+    @jax.named_scope("client_eval")
     def one(variables, personal, x, y, count):
         effective = dict(variables)
         effective["params"] = jax.tree.map(

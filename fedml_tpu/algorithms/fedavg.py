@@ -26,6 +26,7 @@ from fedml_tpu.algorithms.engine import (
     build_eval_fn,
     build_federation_eval_fn,
     build_round_fn,
+    round_slots,
     stage_to_device,
 )
 from fedml_tpu.core.config import FedConfig
@@ -306,7 +307,8 @@ class FedAvgAPI(Checkpointable):
         if tracer is None:
             tracer = telemetry.get_tracer() or telemetry.NULL_TRACER
         staged = self.stage_fn(round_idx, faults=faults, tracer=tracer)
-        with tracer.span("dispatch", round_idx):
+        with tracer.span("dispatch", round_idx,
+                         rows=staged.rows * cfg.epochs, slots=staged.slots):
             rng = jax.random.fold_in(jax.random.PRNGKey(cfg.seed), round_idx)
             if rng_salt:
                 rng = jax.random.fold_in(rng, rng_salt)
@@ -399,7 +401,13 @@ class FedAvgAPI(Checkpointable):
         start_round = 0
         if ckpt_dir:
             start_round = self.maybe_restore(ckpt_dir)
+        from fedml_tpu.utils.cache import hook_monitoring
+
+        hook_monitoring()  # `compile` events, whoever enabled the cache
         telemetry.install(tracer)
+        # a profiler window (--profile_rounds) drains through this before
+        # it opens and closes: the rounds serialize on the global model
+        tracer.drain_fn = lambda: jax.block_until_ready(self.global_variables)
         try:
             with tracer.span("drive"):
                 if cfg.buffer_size > 0:
@@ -435,6 +443,7 @@ class FedAvgAPI(Checkpointable):
                 # memmap writes are already durable pages; flush fsyncs so
                 # a resumed run reads the bank bitwise
                 self.bank.flush()
+            tracer.drain_fn = None
             telemetry.uninstall(tracer)
             if owns_tracer:
                 tracer.close()
@@ -517,10 +526,7 @@ class FedAvgAPI(Checkpointable):
                     if guard is not None and retries:
                         record["guard_retries"] = retries
                     if round_idx % cfg.frequency_of_the_test == 0 or round_idx == cfg.comm_round - 1:
-                        with tracer.span("eval", round_idx):
-                            record.update(self.local_test_on_all_clients(round_idx))
-                            record.update(self.test_global(round_idx))
-                            record.update(self.personalization_lift(round_idx))
+                        record.update(self.evaluate(round_idx, tracer))
                     records.add(record)
                     records.flush(round_idx)
                     if ckpt_dir and (round_idx + 1) % ckpt_every == 0:
@@ -669,7 +675,13 @@ class FedAvgAPI(Checkpointable):
                 if chaos is not None:
                     faults_list, masks = chaos.events_block(r0, k, cohort)
                     per_round.update(masks)
-            with tracer.span("h2d", r0):
+                # the cohorts are gathered in-graph from the resident store:
+                # what is staged is indices, what the chunk trains is this
+                rows = int(self.dataset.train.counts[idx_block].sum())
+                slots = k * round_slots(cfg, cohort,
+                                        self.dataset.train.x.shape[1])
+            with tracer.span("h2d", r0, bytes=sum(
+                    a.nbytes for a in per_round.values())):
                 per_round = jax.device_put(per_round)
             snapshot = guard_state = None
             if guard is not None:
@@ -678,7 +690,8 @@ class FedAvgAPI(Checkpointable):
                 # the eager replay below must re-inspect from the SAME state
                 guard_state = copy.deepcopy(vars(guard))
             superstep = self._superstep_fn(k, chaos is not None, in_graph)
-            with tracer.span("dispatch", r0, rounds=k):
+            with tracer.span("dispatch", r0, rounds=k,
+                             rows=rows * cfg.epochs, slots=slots):
                 out = superstep(self.global_variables, self.agg_state,
                                 *resident, jax.random.PRNGKey(cfg.seed),
                                 per_round)
@@ -746,10 +759,7 @@ class FedAvgAPI(Checkpointable):
                     if j == k - 1 and (
                             r % cfg.frequency_of_the_test == 0
                             or r == cfg.comm_round - 1):
-                        with tracer.span("eval", r):
-                            record.update(
-                                self.local_test_on_all_clients(r))
-                            record.update(self.test_global(r))
+                        record.update(self.evaluate(r, tracer))
                     records.add(record)
                 records.flush(r0 + k - 1)
                 tracer.event("superstep_committed", round=r0, rounds=k,
@@ -865,13 +875,22 @@ class FedAvgAPI(Checkpointable):
                 rows = self._bank_rows(idx)
                 with tracer.span("bank_gather", round_idx, rows=len(rows)):
                     gathered = self.bank.gather(rows)
-        with tracer.span("h2d", round_idx):
+            # counted here, where `counts` is still a host array: the real
+            # rows, and the slots the round program runs for them
+            n_rows = int(counts.sum())
+            n_slots = round_slots(cfg, x.shape[0], x.shape[1])
+        host = [x, y, counts] + ([] if participation is None
+                                 else [participation])
+        if self.cfg.personalize:
+            host += jax.tree.leaves(gathered)
+        with tracer.span("h2d", round_idx,
+                         bytes=sum(a.nbytes for a in host)):
             dx, dy, dc, dp = stage_to_device(x, y, counts, participation,
                                              sharding=self._cohort_sharding())
             if self.cfg.personalize:
                 personal = {"rows": rows, "tree": jax.device_put(gathered)}
         return StagedCohort(round_idx, dx, dy, dc, dp, faults, idx,
-                            personal=personal)
+                            personal=personal, rows=n_rows, slots=n_slots)
 
     def _cohort_sharding(self):
         """Where a staged cohort goes on a mesh round: rows over the
@@ -911,9 +930,13 @@ class FedAvgAPI(Checkpointable):
                 x = apply_faults(faults, x)
             if counts.shape[0] < cohort:
                 x, y, counts = pad_clients(x, y, counts, cohort)
-        with tracer.span("h2d", round_idx):
+            n_rows = int(counts.sum())
+        with tracer.span("h2d", round_idx,
+                         bytes=x.nbytes + y.nbytes + counts.nbytes):
             dx, dy, dc, _ = stage_to_device(x, y, counts, None)
-        return StagedCohort(round_idx, dx, dy, dc, None, faults, idx)
+        return StagedCohort(round_idx, dx, dy, dc, None, faults, idx,
+                            rows=n_rows,
+                            slots=round_slots(cfg, x.shape[0], x.shape[1]))
 
     def _train_pipelined(self, start_round, ckpt_dir, ckpt_every,
                          metrics_logger, chaos, guard, tracer,
@@ -976,7 +999,9 @@ class FedAvgAPI(Checkpointable):
                     snapshot = None
                     if guard is not None:
                         snapshot = (self._ckpt_tree(), self._ckpt_meta())
-                    with tracer.span("dispatch", round_idx):
+                    with tracer.span("dispatch", round_idx,
+                                     rows=staged.rows * cfg.epochs,
+                                     slots=staged.slots):
                         rng = jax.random.fold_in(jax.random.PRNGKey(cfg.seed),
                                                  round_idx)
                         if retries:
@@ -1061,10 +1086,7 @@ class FedAvgAPI(Checkpointable):
                     if is_test:
                         # eval reads the post-round model, so these dispatches
                         # block on the round chain anyway — resolving now is free
-                        with tracer.span("eval", round_idx):
-                            record.update(self.local_test_on_all_clients(round_idx))
-                            record.update(self.test_global(round_idx))
-                            record.update(self.personalization_lift(round_idx))
+                        record.update(self.evaluate(round_idx, tracer))
                     records.add(record)
                     # flush at sync points, and ALSO whenever the pending
                     # backlog exceeds ~2x the pipeline depth: unbounded
@@ -1116,6 +1138,14 @@ class FedAvgAPI(Checkpointable):
         self.history[:] = meta.get("history", [])
 
     # ------------------------------------------------------------------- eval
+    def evaluate(self, round_idx: int, tracer) -> dict[str, float]:
+        """A test round's evaluation, under one `eval` span."""
+        with tracer.span("eval", round_idx):
+            out = self.local_test_on_all_clients(round_idx)
+            out.update(self.test_global(round_idx))
+            out.update(self.personalization_lift(round_idx))
+        return out
+
     def test_global(self, round_idx: int) -> dict[str, float]:
         bx, by, bm = self._test_batches
         m = self.eval_fn(self.global_variables, jnp.asarray(bx), jnp.asarray(by), jnp.asarray(bm))
@@ -1171,7 +1201,8 @@ class FedAvgAPI(Checkpointable):
         num = 1 if self.cfg.ci else ds.client_num
         splits = (("Train", ds.train), ("Test", ds.test or ds.train))
         out = {}
-        resident = (not self.cfg.ci) and self._resident_eval_data(splits)
+        resident = (not self.cfg.ci) and self._resident_eval_data(
+            splits, round_idx)
         for split_name, packed in splits:
             chunk = _eval_chunk(packed.x.shape[1], num)
             sums: dict[str, float] = {}
@@ -1196,10 +1227,11 @@ class FedAvgAPI(Checkpointable):
             out[f"{split_name}/Loss"] = sums.get("test_loss", 0.0) / total
         return out
 
-    def _resident_eval_data(self, splits):
+    def _resident_eval_data(self, splits, round_idx=None):
         """Device-resident [nc, chunk, n_max, ...] eval arrays per split,
         built once; None when disabled, over the byte budget, or more than
-        the device has room for."""
+        the device has room for. The one-off transfer is the `eval_h2d`
+        span, a child of the `eval` that needed it."""
         if not self.cfg.resident_eval:
             return None
         if self._resident_cache is not None:
@@ -1263,9 +1295,14 @@ class FedAvgAPI(Checkpointable):
 
         staged: dict[int, tuple] = {}  # test may BE train (no test split)
         cache = {}
-        for name, p in splits:
-            if id(p) not in staged:
-                staged[id(p)] = stage(p)
-            cache[name] = staged[id(p)]
+        tracer = telemetry.get_tracer() or telemetry.NULL_TRACER
+        with tracer.span("eval_h2d", round_idx):
+            for name, p in splits:
+                if id(p) not in staged:
+                    staged[id(p)] = stage(p)
+                cache[name] = staged[id(p)]
+            # the eval that follows needs them all: waiting here costs
+            # nothing and gives the span the transfer's true length
+            jax.block_until_ready(cache)
         self._resident_cache = cache
         return self._resident_cache

@@ -64,6 +64,7 @@ class CodecAggregator:
             "codec": slot_residual(self.codec, global_variables, self.slots),
         }
 
+    @jax.named_scope("codec")
     def _stage(self, global_variables, result, weights, resid):
         """Per-row encode -> wire -> decode; returns (decoded_result,
         new_resid). Rows whose update is dead (zero weight) or non-finite

@@ -111,6 +111,13 @@ def build_round_core(batched_update, aggregator,
     (new_gv, new_state, metrics, stats-or-None); `participation=None`
     traces the legacy unmasked program, an array arms the quarantine stage
     (see engine.build_round_fn_from_update's docstring for the contract).
+
+    The server-side phases carry `jax.named_scope` names — `cohort_stats`,
+    `quarantine` (each set where the phase is defined, so the mesh rounds
+    inherit them), `aggregate` here and `codec` inside a wrapped aggregator:
+    metadata of the compiled ops, the same program. The client update has
+    none and is what lies outside them: a scope around it put 2-4 s on the
+    trace of a ResNet-56 round (PERF.md section 6, PR 27).
     """
     # function-level import: aggregators.make_server_optimizer imports
     # engine.torch_adagrad, so the modules must not need each other at
@@ -128,9 +135,9 @@ def build_round_core(batched_update, aggregator,
             else None
         weights = counts.astype(jnp.float32)
         if participation is None:
-            new_global, new_state = aggregator(
-                global_variables, result, weights, rng, agg_state
-            )
+            with jax.named_scope("aggregate"):
+                new_global, new_state = aggregator(
+                    global_variables, result, weights, rng, agg_state)
             # LoRA: aggregation ran adapters-only (results are stripped);
             # the server's frozen base re-attaches untouched (no-op when
             # the trainer isn't wrapped)
@@ -140,9 +147,9 @@ def build_round_core(batched_update, aggregator,
             return new_global, new_state, metrics, stats
         result, weights, alive, quarantined = quarantine_stage(
             result, weights, participation)
-        new_global, new_state = aggregator(
-            global_variables, result, weights, rng, agg_state
-        )
+        with jax.named_scope("aggregate"):
+            new_global, new_state = aggregator(
+                global_variables, result, weights, rng, agg_state)
         any_alive = jnp.any(alive)
         # the all-dead fallback must match the aggregator output's
         # (adapters-only under LoRA) structure; base re-attaches after
@@ -193,17 +200,17 @@ def build_personal_round_core(batched_update, aggregator,
             else None
         weights = counts.astype(jnp.float32)
         if participation is None:
-            new_global, new_state = aggregator(
-                global_variables, result, weights, rng, agg_state
-            )
+            with jax.named_scope("aggregate"):
+                new_global, new_state = aggregator(
+                    global_variables, result, weights, rng, agg_state)
             new_global = attach_lora_base(new_global, global_variables)
             metrics = {k: v.sum() for k, v in result.metrics.items()}
             return new_global, new_state, metrics, stats, new_personal
         result, weights, alive, quarantined = quarantine_stage(
             result, weights, participation)
-        new_global, new_state = aggregator(
-            global_variables, result, weights, rng, agg_state
-        )
+        with jax.named_scope("aggregate"):
+            new_global, new_state = aggregator(
+                global_variables, result, weights, rng, agg_state)
         any_alive = jnp.any(alive)
         new_global = tree_where(any_alive, new_global,
                                 strip_lora_base(global_variables))

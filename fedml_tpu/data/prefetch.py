@@ -56,7 +56,10 @@ class StagedCohort:
     unless the run personalizes) is `{"rows": host bank row ids, "tree":
     device-resident [C, ...] adapter rows}`, staged alongside the data so
     the round dispatch stays one hop and the scatter-back targets exactly
-    the rows that were fed."""
+    the rows that were fed. `rows` (the cohort's real rows) and `slots`
+    (what the round program executes for it, padding included:
+    engine.round_slots) are host integers counted at staging, for the
+    `dispatch` span."""
 
     round_idx: int
     x: Any
@@ -66,6 +69,8 @@ class StagedCohort:
     faults: Any | None
     client_idx: np.ndarray
     personal: Any | None = None
+    rows: int = 0
+    slots: int = 0
 
 
 #: invalidate()'s default scope: every job's in-flight stagings (the
@@ -109,14 +114,20 @@ class CohortPrefetcher:
         self._staged_at: dict[tuple, float] = {}  # key -> staging-done time
 
     def _submit(self, round_idx: int, job=None) -> Future:
+        # the span open on the scheduling thread (the round that called
+        # prefetch(), or the stage_wait of a missing get()) becomes the
+        # parent of the spans the worker opens for this staging
+        cause = telemetry.open_span_id()
+
         def work():
             # the append is atomic under the GIL; single worker => ordered
             self.staged_rounds.append(round_idx)
-            if job is None:
-                staged = self._stage_fn(round_idx)
-            else:
-                with telemetry.job_scope(job):
-                    staged = self._stage_fn(round_idx, job)
+            with telemetry.adopt(cause):
+                if job is None:
+                    staged = self._stage_fn(round_idx)
+                else:
+                    with telemetry.job_scope(job):
+                        staged = self._stage_fn(round_idx, job)
             # stager thread vs invalidate()'s clear() on the main thread —
             # the timestamp write must not resurrect an invalidated round
             with self._lock:

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import argparse
 
+from fedml_tpu import telemetry
 from fedml_tpu.algorithms.fedavg import FedAvgAPI
 from fedml_tpu.experiments.common import (
     add_args,
@@ -28,18 +29,24 @@ def run(args, aggregator_name: str = "fedavg"):
     """Everything `main` does with parsed arguments. Returns (api, history):
     a caller that must look at the trained state or at how cohorts were
     staged (chip_smoke.py --multichip) drives exactly the CLI's path."""
-    cfg, ds, trainer = setup_run(args)
+    # the tracer comes first and is installed at once, so that set-up is on
+    # the record too: the data_load span, and every compile before the drive
     logger = MetricsLogger(run_dir=args.run_dir, config=vars(args))
-    api = FedAvgAPI(ds, cfg, trainer, aggregator_name=aggregator_name)
-    chaos, guard = robustness_from_args(args)
     tracer = tracer_from_args(args, metrics_logger=logger)
-    ledger = ledger_from_args(args, ds.client_num)
-    bank = bank_from_args(args, ds.client_num, api)
+    telemetry.install(tracer)
+    ledger = bank = None
     try:
+        with tracer.span("data_load"):   # seeds, logging, data, model
+            cfg, ds, trainer = setup_run(args)
+        api = FedAvgAPI(ds, cfg, trainer, aggregator_name=aggregator_name)
+        chaos, guard = robustness_from_args(args)
+        ledger = ledger_from_args(args, ds.client_num)
+        bank = bank_from_args(args, ds.client_num, api)
         history = api.train(ckpt_dir=args.ckpt_dir, metrics_logger=logger,
                             chaos=chaos, guard=guard, tracer=tracer,
                             ledger=ledger, bank=bank)
     finally:
+        telemetry.uninstall(tracer)
         tracer.close()
         if ledger is not None:
             ledger.close()

@@ -92,9 +92,9 @@ def build_sharded_round_fn(
         # test_psum_aggregation_halves_all_gather_bytes), and psum outputs
         # are invariant-typed — shard_map's check_vma replication
         # verification stays ON (VERDICT r4 weak #3)
-        new_global, new_state = aggregator.sharded(
-            global_variables, result, weights, rng, agg_state, axis
-        )
+        with jax.named_scope("aggregate"):
+            new_global, new_state = aggregator.sharded(
+                global_variables, result, weights, rng, agg_state, axis)
         metrics = {k: jax.lax.psum(v.sum(), axis) for k, v in result.metrics.items()}
         if participation is None:
             if collect_stats:
@@ -240,8 +240,9 @@ def build_sharded_buffer_fns(
         result = LocalResult(buf["vars"], buf["steps"], buf["metrics"])
         result, weights, alive, quarantined = quarantine_stage(
             result, weights, participation)
-        new_global, new_state = aggregator.sharded(
-            global_variables, result, weights, rng, agg_state, axis)
+        with jax.named_scope("aggregate"):
+            new_global, new_state = aggregator.sharded(
+                global_variables, result, weights, rng, agg_state, axis)
         metrics = {k: jax.lax.psum(v.sum(), axis)
                    for k, v in result.metrics.items()}
         new_global, new_state, metrics = masked_psum_tail(

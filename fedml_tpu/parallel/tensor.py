@@ -371,6 +371,7 @@ def _add_noise_sharded(aggregator, avg_shard, rng, full_params, specs_params,
     return out
 
 
+@jax.named_scope("aggregate")
 def _aggregate_sharded(aggregator, gv_shard, gv_full, result, result_shard,
                        weights, rng, agg_state, specs_gv, tensor_shards):
     """Dispatch one aggregator over tensor-sharded client stacks.

@@ -440,10 +440,7 @@ class Job:
                         record["guard_retries"] = retries
                     if (r % cfg.frequency_of_the_test == 0
                             or r == cfg.comm_round - 1):
-                        with tracer.span("eval", r):
-                            record.update(
-                                self.api.local_test_on_all_clients(r))
-                            record.update(self.api.test_global(r))
+                        record.update(self.api.evaluate(r, tracer))
                     self.records.add(record)
                     self.records.flush(r)
             if not rejected:
@@ -510,10 +507,7 @@ class Job:
                         record["guard_retries"] = retries
                     if (r % cfg.frequency_of_the_test == 0
                             or r == cfg.comm_round - 1):
-                        with tracer.span("eval", r):
-                            record.update(
-                                self.api.local_test_on_all_clients(r))
-                            record.update(self.api.test_global(r))
+                        record.update(self.api.evaluate(r, tracer))
                     self.records.add(record)
                     self.records.flush(r)
             if not rejected:

@@ -11,12 +11,14 @@ from fedml_tpu.telemetry.tracer import (  # noqa: F401
     NULL_TRACER,
     NullTracer,
     Tracer,
+    adopt,
     current_job,
     emit,
     gauge,
     get_tracer,
     install,
     job_scope,
+    open_span_id,
     parse_profile_rounds,
     uninstall,
 )

@@ -12,7 +12,13 @@ This module is the replacement: a zero-dependency `Tracer` that records
   ...) per round, from any thread. Spans are recorded *around* jitted
   calls, never inside traces — the tracer never enters a jaxpr, so lowered
   programs, COMMS_BUDGET.json, and the PR 4/5 bit-identity pins are
-  untouched by its presence.
+  untouched by its presence. Every span has an `id` and a `parent`: the
+  innermost span open on its thread, or, on a thread that works for
+  another (the cohort stager), the span that was open where the work was
+  scheduled (`adopt`). `round` stays what the spans of one round share.
+  Work counts known at the boundary (`rows`, `slots`, `bytes`) ride the
+  span as attributes. While a `jax.profiler` session runs, every span is
+  also a `TraceAnnotation("host:<name>")` on the profiler's clock.
 - **events**: schema-checked ledger entries (chaos injections, guard
   verdicts/rollbacks, MQTT reconnects, compile-cache activity, committed
   round records). Events are flushed to the JSONL sink the moment they
@@ -38,8 +44,10 @@ installs its tracer for the duration of the drive.
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -69,6 +77,10 @@ EVENT_SCHEMAS: Dict[str, set] = {
     "mqtt_reconnect": {"client_id", "ok", "attempts"},
     # persistent compile cache (utils/cache.py via jax.monitoring)
     "compile_cache": {"name"},
+    # every backend compile, cache-served or not (utils/cache.py via
+    # jax.monitoring); also carries `round` and `span`: the round and id of
+    # the span open on the compiling thread (None outside any span)
+    "compile": {"dur_s"},
     # round-program construction (algorithms/engine.py)
     "round_fn_built": {"program", "donate"},
     # buffered aggregation (algorithms/buffered.py): one per admitted client
@@ -134,19 +146,43 @@ def _thread_label() -> str:
     return "stager" if name.startswith("cohort-prefetch") else "main"
 
 
+def _annotation(name: str):
+    """`jax.profiler.TraceAnnotation("host:<name>")`, or None before jax is
+    imported (this module stays stdlib-only; a span opened that early has
+    no profiler to be seen by). Outside a profiler session the annotation
+    costs its constructor and two no-op calls."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    return jax.profiler.TraceAnnotation("host:" + name)
+
+
 class _SpanHandle:
     """Live span: open time is queryable before the span closes (the drive
     loop reads `elapsed()` for the history record's `round_time` while the
     round span is still open)."""
 
-    __slots__ = ("_tracer", "t0")
+    __slots__ = ("_tracer", "t0", "id", "round")
 
-    def __init__(self, tracer: "Tracer", t0: float):
+    def __init__(self, tracer: "Tracer", t0: float, span_id: int,
+                 round_idx: Optional[int]):
         self._tracer = tracer
         self.t0 = t0
+        self.id = span_id
+        self.round = round_idx
 
     def elapsed(self) -> float:
         return self._tracer.now() - self.t0
+
+
+class _Adopted:
+    """Stack entry standing for a span open on ANOTHER thread (`adopt`)."""
+
+    __slots__ = ("id", "round")
+
+    def __init__(self, span_id: Optional[int]):
+        self.id = span_id
+        self.round = None
 
 
 class Tracer:
@@ -179,6 +215,12 @@ class Tracer:
                                 if profile_rounds else None)
         self._profile_dir = profile_dir or "/tmp/fedml_tpu_trace"
         self._profiling = False
+        #: set by the drive (FedAvgAPI.train) for its duration: blocks until
+        #: the device has finished everything dispatched so far, so that a
+        #: profiler window holds the device work of its rounds and no other
+        self.drain_fn: Optional[Callable[[], None]] = None
+        self._ids = itertools.count(1)
+        self._open = threading.local()  # .stack: this thread's open spans
         self._file = None
         self._jsonl_path = jsonl_path
         self._max_bytes = max_bytes
@@ -236,16 +278,52 @@ class Tracer:
         self._bytes = len(meta_line)
 
     # ---------------------------------------------------------------- spans
+    def _stack(self) -> list:
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        return stack
+
+    def open_span(self) -> tuple:
+        """(id, round) of the innermost span open on THIS thread — `round`
+        from the innermost one that has a round — or (None, None)."""
+        stack = self._stack()
+        if not stack:
+            return None, None
+        rounds = [h.round for h in stack if h.round is not None]
+        return stack[-1].id, rounds[-1] if rounds else None
+
+    @contextmanager
+    def adopt(self, span_id: Optional[int]):
+        """Spans this thread opens inside take `span_id` — a span open on
+        the thread that scheduled the work — as their parent."""
+        stack = self._stack()
+        stack.append(_Adopted(span_id))
+        try:
+            yield
+        finally:
+            stack.pop()
+
     @contextmanager
     def span(self, name: str, round_idx: Optional[int] = None, **attrs):
+        stack = self._stack()
+        parent = stack[-1].id if stack else None
+        annotation = _annotation(name)
+        if annotation is not None:
+            annotation.__enter__()
         t0 = self.now()
-        handle = _SpanHandle(self, t0)
+        handle = _SpanHandle(self, t0, next(self._ids), round_idx)
+        stack.append(handle)
         try:
             yield handle
         finally:
             dur = self.now() - t0
+            stack.pop()
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
             rec = {"type": "span", "name": name, "round": round_idx,
-                   "thread": _thread_label(), "t0": t0, "dur_s": dur}
+                   "thread": _thread_label(), "t0": t0, "dur_s": dur,
+                   "id": handle.id, "parent": parent}
             job = current_job()
             if job is not None:
                 rec["job"] = job
@@ -258,6 +336,24 @@ class Tracer:
                     acc = self._round_phase_acc.setdefault(round_idx, {})
                     acc[name] = acc.get(name, 0.0) + dur
             self._write(rec)
+
+    def self_time(self, span: Dict[str, Any]) -> float:
+        """`span`'s duration minus the union of the intervals of its
+        children on its own thread. A child on another thread (a `stage`
+        the round scheduled on the stager) was caused by the span but runs
+        beside it, and takes nothing from its self time."""
+        t0, t1 = span["t0"], span["t0"] + span["dur_s"]
+        with self._lock:
+            kids = sorted((max(s["t0"], t0), min(s["t0"] + s["dur_s"], t1))
+                          for s in self.spans
+                          if s.get("parent") == span["id"]
+                          and s["thread"] == span["thread"])
+        covered, end = 0.0, t0
+        for k0, k1 in kids:
+            if k1 > end:
+                covered += k1 - max(k0, end)
+                end = k1
+        return span["dur_s"] - covered
 
     @contextmanager
     def round(self, round_idx: int):
@@ -283,17 +379,30 @@ class Tracer:
                 step=round_idx)
 
     def _profile_edge(self, round_idx: int, starting: bool) -> None:
+        """Start the profiler before round `lo`, stop it after round
+        `hi - 1`, each time with the device drained first: the pipelined
+        host runs rounds ahead of the device, and an undrained window would
+        hold other rounds' device work. The python tracer is off: it slows
+        the host it traces."""
         if self._profile_window is None:
             return
         lo, hi = self._profile_window
+        start = starting and round_idx == lo and not self._profiling
+        stop = not starting and round_idx == hi - 1 and self._profiling
+        if not (start or stop):
+            return
         try:
             import jax
-            if starting and round_idx == lo and not self._profiling:
-                jax.profiler.start_trace(self._profile_dir)
-                self._profiling = True
-            elif not starting and round_idx == hi - 1 and self._profiling:
+            if self.drain_fn is not None:
+                self.drain_fn()
+            if start:
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                jax.profiler.start_trace(self._profile_dir,
+                                         profiler_options=options)
+            else:
                 jax.profiler.stop_trace()
-                self._profiling = False
+            self._profiling = start
         except Exception:  # profiler unavailable on this backend — trace on
             self._profile_window = None
 
@@ -329,6 +438,12 @@ class Tracer:
         with self._lock:
             self.gauges.append(rec)
         self._write(rec)
+
+    def compile_event(self, dur_s: float) -> None:
+        """One backend compile, attributed to the span open on the
+        compiling thread (jit compiles synchronously on its caller)."""
+        span_id, round_idx = self.open_span()
+        self.event("compile", dur_s=dur_s, round=round_idx, span=span_id)
 
     # ------------------------------------------------------------ accessors
     def find_spans(self, name: Optional[str] = None,
@@ -534,3 +649,23 @@ def gauge(name: str, **fields) -> None:
     tracer = get_tracer()
     if tracer is not None:
         tracer.gauge(name, **fields)
+
+
+def open_span_id() -> Optional[int]:
+    """Id of the innermost span of the installed tracer open on THIS
+    thread: what a scheduler captures before it hands work to another
+    thread. None when there is none."""
+    tracer = get_tracer()
+    return tracer.open_span()[0] if tracer is not None else None
+
+
+@contextmanager
+def adopt(span_id: Optional[int]):
+    """On a worker thread: the installed tracer's spans opened inside take
+    `span_id` (from `open_span_id()` on the scheduling thread) as parent."""
+    tracer = get_tracer()
+    if tracer is None or span_id is None:
+        yield
+    else:
+        with tracer.adopt(span_id):
+            yield

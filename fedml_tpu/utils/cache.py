@@ -8,23 +8,34 @@ import os
 # exactly once per process no matter how many runs enable the cache.
 _MONITORING_HOOKED = False
 
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
-def _hook_cache_monitoring() -> None:
-    """Forward jax's compilation-cache monitoring events (hits, misses,
-    writes) into the telemetry ledger as `compile_cache` events. No-op when
-    no tracer is installed."""
+
+def hook_monitoring() -> None:
+    """Forward jax's monitoring into the telemetry ledger, once a process:
+    compilation-cache events (hits, misses, writes) as `compile_cache`
+    events, and every backend compile — cache-served or not — as a `compile`
+    event with its seconds and the round and span it fell in. Both are
+    no-ops while no tracer is installed."""
     global _MONITORING_HOOKED
     if _MONITORING_HOOKED:
         return
     import jax
 
+    from fedml_tpu import telemetry
+
     def _forward(event: str, **kw) -> None:
-        if "cache" not in event:
-            return
-        from fedml_tpu import telemetry
-        telemetry.emit("compile_cache", name=event)
+        if "cache" in event:
+            telemetry.emit("compile_cache", name=event)
+
+    def _forward_duration(event: str, duration: float, **kw) -> None:
+        if event == BACKEND_COMPILE_EVENT:
+            tracer = telemetry.get_tracer()
+            if tracer is not None:
+                tracer.compile_event(duration)
 
     jax.monitoring.register_event_listener(_forward)
+    jax.monitoring.register_event_duration_secs_listener(_forward_duration)
     _MONITORING_HOOKED = True
 
 
@@ -44,6 +55,7 @@ def enable_compile_cache(min_compile_secs: float = 1.0,
 
     Opt out with FEDML_TPU_NO_COMPILE_CACHE=1 (e.g. when timing cold-start
     compiles). Returns True when the cache was enabled."""
+    hook_monitoring()
     if os.environ.get("FEDML_TPU_NO_COMPILE_CACHE"):
         return False
     import jax
@@ -56,5 +68,4 @@ def enable_compile_cache(min_compile_secs: float = 1.0,
         jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       min_compile_secs)
-    _hook_cache_monitoring()
     return True

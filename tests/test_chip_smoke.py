@@ -142,10 +142,10 @@ def test_kernel_phase_interpreted_by_the_test():
 def test_compiles_after_round_sees_a_late_compile():
     """The no-compile-after-round check must be able to fail."""
     span = {"t0": 10.0, "dur_s": 2.0}
-    assert chip_smoke.compiles_after_round([(9.0, 1.0), (12.0, 0.5)],
-                                           span) == []
-    assert chip_smoke.compiles_after_round([(11.0, 1.0), (12.5, 0.1),
-                                            (20.0, 3.0)], span) == [0.5, 8.0]
+    ends = lambda *ts: [{"kind": "compile", "t": t, "dur_s": 0.5} for t in ts]
+    assert chip_smoke.compiles_after_round(ends(9.0, 12.0), span) == []
+    assert chip_smoke.compiles_after_round(ends(11.0, 12.5, 20.0),
+                                           span) == [0.5, 8.0]
 
 
 def test_main_fails_off_chip_without_reporting_ok(capsys):

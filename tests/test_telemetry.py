@@ -18,6 +18,7 @@ The pins that matter:
 
 import json
 import os
+import re
 
 import pytest
 
@@ -104,6 +105,7 @@ _SAMPLE_EVENTS = {
     "checkpoint_save": dict(step=5),
     "mqtt_reconnect": dict(client_id="c0", ok=True, attempts=2),
     "compile_cache": dict(name="persistent_cache_hit"),
+    "compile": dict(dur_s=0.25, round=0, span=3),
     "round_fn_built": dict(program="engine.round", donate=True),
     "update_admitted": dict(round=3, birth=1, fill=2),
     "buffer_committed": dict(round=3, size=4, staleness_p50=1.0,
@@ -571,3 +573,273 @@ def test_download_retry_emits_schema_checked_events(tmp_path):
     assert [e["status"] for e in events] == ["503", "ConnectionResetError"]
     assert [e["backoff_s"] for e in events] == sleeps == [1.0, 2.0]
     assert calls["n"] == 3  # third call succeeded — no further retries
+
+
+# ------------------------------------------- span identity and work counts
+
+def test_span_ids_are_unique_and_parent_is_innermost_open_span():
+    clock = _FakeClock()
+    t = Tracer(clock=clock)
+    with t.round(5) as r:
+        with t.span("stage_wait", 5) as a:
+            with t.span("inner", 5) as b:
+                assert t.open_span() == (b.id, 5)
+        with t.span("dispatch", 5) as c:
+            pass
+    with t.span("drive") as d:
+        assert t.open_span() == (d.id, None)
+    assert t.open_span() == (None, None)
+    by = {s["name"]: s for s in t.spans}
+    assert len({s["id"] for s in t.spans}) == len(t.spans) == 5
+    assert by["round"]["parent"] is None and by["drive"]["parent"] is None
+    assert by["stage_wait"]["parent"] == by["dispatch"]["parent"] == r.id
+    assert by["inner"]["parent"] == a.id and by["dispatch"]["id"] == c.id
+
+
+def test_self_time_is_duration_minus_union_of_same_thread_children():
+    clock = _FakeClock()
+    t = Tracer(clock=clock)
+    with t.span("round", 0) as r:
+        clock.t += 1.0                       # self
+        with t.span("a", 0):
+            clock.t += 2.0
+        with t.span("b", 0):
+            clock.t += 3.0
+            with t.span("grandchild", 0):    # its parent's, not the round's
+                clock.t += 1.0
+        clock.t += 0.5                       # self
+    span, = t.find_spans("round")
+    assert span["dur_s"] == pytest.approx(7.5)
+    assert t.self_time(span) == pytest.approx(1.5)
+    b, = t.find_spans("b")
+    assert t.self_time(b) == pytest.approx(3.0)
+    # two children that overlap count once; a child on another thread (the
+    # stager working for this round) takes nothing
+    for name, dur, thread in (("x", 2.0, "main"), ("stage", 7.5, "stager")):
+        t.spans.append({"name": name, "thread": thread, "t0": 0.0,
+                        "dur_s": dur, "id": -1, "parent": r.id})
+    assert t.self_time(span) == pytest.approx(0.5)
+
+
+def test_adopted_parent_crosses_threads():
+    import threading
+
+    t = Tracer()
+    telemetry.install(t)
+    try:
+        with t.span("round", 3) as r:
+            cause = telemetry.open_span_id()
+
+            def work():
+                with telemetry.adopt(cause):
+                    with t.span("stage", 4):
+                        with t.span("bank_gather", 4):
+                            pass
+
+            th = threading.Thread(target=work)
+            th.start()
+            th.join(timeout=10)
+            assert not th.is_alive()
+    finally:
+        telemetry.uninstall(t)
+    by = {s["name"]: s for s in t.spans}
+    assert cause == r.id == by["stage"]["parent"]
+    assert by["bank_gather"]["parent"] == by["stage"]["id"]
+
+
+@pytest.mark.parametrize("depth", [0, 2])
+def test_stage_spans_name_the_span_that_scheduled_them(ds8, depth):
+    """Eager: `stage` is a child of its own round. Pipelined: a stager-thread
+    `stage` names the round span whose prefetch() scheduled it (an earlier
+    round), or the `stage_wait` of the get() that found it missing."""
+    t = Tracer()
+    _api(ds8, _cfg(5, pipeline_depth=depth)).train(tracer=t)
+    by_id = {s["id"]: s for s in t.spans}
+    assert all("id" in s and "parent" in s for s in t.spans)
+    stages = t.find_spans("stage")
+    assert sorted(s["round"] for s in stages) == list(range(5))
+    for s in stages + t.find_spans("h2d"):
+        cause = by_id[s["parent"]]
+        if depth == 0:
+            assert (s["thread"], cause["name"], cause["round"]) == (
+                "main", "round", s["round"])
+        else:
+            assert s["thread"] == "stager" and cause["thread"] == "main"
+            assert cause["name"] in ("round", "stage_wait")
+            assert cause["round"] <= s["round"]
+    if depth:
+        ahead = [s for s in stages if by_id[s["parent"]]["name"] == "round"]
+        assert ahead and all(by_id[s["parent"]]["round"] < s["round"]
+                             for s in ahead)
+        # the drive loop closes: a round is its children plus its self time
+        for r in t.find_spans("round"):
+            kids = sum(s["dur_s"] for s in t.spans if s["parent"] == r["id"]
+                       and s["thread"] == "main")
+            assert kids + t.self_time(r) == pytest.approx(r["dur_s"])
+
+
+@pytest.mark.parametrize("epochs", [1, 2])
+def test_work_counts_match_numpy(ds8, epochs):
+    from fedml_tpu.algorithms.engine import round_slots
+    from fedml_tpu.algorithms.fedavg import client_sampling
+
+    cfg = _cfg(3, client_num_per_round=3, pipeline_depth=2, epochs=epochs)
+    t = Tracer()
+    _api(ds8, cfg).train(tracer=t)
+    n_max = ds8.train.x.shape[1]
+    for r in range(3):
+        idx = client_sampling(r, 8, 3)
+        x, y, counts = ds8.train.select(idx)
+        h2d, = t.find_spans("h2d", r)
+        dispatch, = t.find_spans("dispatch", r)
+        assert h2d["bytes"] == x.nbytes + y.nbytes + counts.nbytes
+        assert dispatch["rows"] == int(counts.sum()) * epochs
+        assert dispatch["slots"] == round_slots(cfg, 3, n_max)
+        assert dispatch["slots"] >= 3 * n_max * epochs
+
+
+class _BatchSpy:
+    """Stands where a trainer stands in the epoch function and notes the
+    batch it is handed while the function is traced."""
+
+    def __init__(self):
+        self.batch = None
+
+    def loss_fn(self, variables, batch, rng, train):
+        import jax.numpy as jnp
+
+        self.batch = batch["x"].shape[0]
+        loss = jnp.sum(variables["params"]["w"]) * jnp.sum(batch["mask"])
+        return loss, ({}, {"total": jnp.sum(batch["mask"])})
+
+
+@pytest.mark.parametrize("n_max,batch_size", [(480, 20), (50, 64), (37, 8)])
+def test_round_slots_is_what_the_epoch_function_pads_to(n_max, batch_size):
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from fedml_tpu.algorithms.engine import _build_epoch_fn, round_slots
+
+    import dataclasses
+
+    cfg = dataclasses.replace(_cfg(1, epochs=3), batch_size=batch_size)
+    spy = _BatchSpy()
+    opt = optax.sgd(0.1)
+    params = {"w": jnp.zeros(2)}
+    variables = {"params": params}
+    carry = (variables, opt.init(params), jnp.zeros((), jnp.int32))
+    _, auxs = jax.eval_shape(
+        _build_epoch_fn(spy, cfg, opt), params, carry,
+        jnp.zeros((n_max, 4)), jnp.zeros((n_max,), jnp.int32),
+        jnp.asarray(n_max - 1), jax.random.PRNGKey(0))
+    steps, = auxs["total"].shape
+    assert steps * spy.batch >= n_max
+    assert round_slots(cfg, 7, n_max) == 7 * steps * spy.batch * 3
+
+
+@pytest.mark.parametrize("drive", [
+    {}, {"pipeline_depth": 2}, {"rounds_per_dispatch": 2},
+    {"buffer_size": 2}])
+def test_every_drive_loop_evaluates_under_one_eval_span(ds8, drive):
+    """Eager, pipelined, superstep and buffered drives open a test round's
+    `eval` span through FedAvgAPI.evaluate(): one span a test round, a child
+    of its round, and the one-off transfer of the resident splits its child."""
+    t = Tracer()
+    hist = _api(ds8, _cfg(4, frequency_of_the_test=2, **drive)).train(tracer=t)
+    by_id = {s["id"]: s for s in t.spans}
+    evals = t.find_spans("eval")
+    tested = [rec["round"] for rec in hist if "Test/Acc" in rec]
+    assert sorted(e["round"] for e in evals) == tested and len(tested) >= 2
+    for e in evals:
+        assert (by_id[e["parent"]]["name"], e["thread"]) == ("round", "main")
+    sent, = t.find_spans("eval_h2d")
+    first = min(evals, key=lambda e: e["t0"])
+    assert sent["parent"] == first["id"] and sent["dur_s"] <= first["dur_s"]
+
+
+def test_compile_events_name_the_round_and_span_they_fell_in(ds8):
+    t = Tracer()
+    with t.span("dispatch", 3) as h:
+        t.compile_event(0.5)
+    t.compile_event(0.25)
+    inside, outside = t.find_events("compile")
+    assert (inside["dur_s"], inside["round"], inside["span"]) == (0.5, 3, h.id)
+    assert (outside["round"], outside["span"]) == (None, None)
+
+    t = Tracer()
+    _api(ds8, _cfg(2, pipeline_depth=2)).train(tracer=t)
+    by_id = {s["id"]: s for s in t.spans}
+    compiles = t.find_events("compile")
+    assert compiles and all(e["dur_s"] >= 0 for e in compiles)
+    # a fresh API's round program compiles (or is served) in round 0's
+    # dispatch; nothing compiles once the program has settled
+    assert any(e["round"] == 0 and by_id[e["span"]]["name"] == "dispatch"
+               for e in compiles)
+    assert all(e["round"] in (None, 0) for e in compiles)
+    for e in compiles:
+        if e["span"] is not None and by_id[e["span"]]["round"] is not None:
+            assert by_id[e["span"]]["round"] == e["round"]
+
+
+def test_lowered_programs_hold_the_scope_names(ds8):
+    import jax
+    import jax.numpy as jnp
+
+    from fedml_tpu.algorithms.aggregators import make_aggregator
+    from fedml_tpu.algorithms.engine import (build_client_eval_fn,
+                                             build_round_fn)
+    from fedml_tpu.codecs import make_codec
+
+    cfg = _cfg(1, client_num_per_round=3, update_codec="int8")
+    trainer = ClassificationTrainer(create_model("lr", output_dim=10))
+    round_fn = build_round_fn(trainer, cfg, make_aggregator("fedavg", cfg),
+                              collect_stats=True,
+                              codec=make_codec("int8", cfg))
+    x, y, counts = ds8.train.select([0, 1, 2])
+    gv = trainer.init(jax.random.PRNGKey(0), jnp.asarray(x[:1, 0]))
+    from fedml_tpu.core.builder import wrap_codec
+
+    state = wrap_codec(make_aggregator("fedavg", cfg),
+                       make_codec("int8", cfg), 3).init_state(gv)
+    text = round_fn.lower(gv, state, x, y, counts, jax.random.PRNGKey(1),
+                          jnp.ones(3, bool)).as_text(debug_info=True)
+    for scope in ("cohort_stats", "quarantine", "codec", "aggregate"):
+        assert re.search(rf"[/(]{scope}[/)]", text), scope
+    # the client update carries no scope of its own (the trace of a deep
+    # model paid seconds for one): it is what lies outside the others
+    assert not re.search(r"[/(]local_update[/)]", text)
+    text = build_client_eval_fn(trainer).lower(
+        gv, x, y, counts).as_text(debug_info=True)
+    assert "vmap(client_eval)/" in text
+
+
+def test_profile_window_drains_before_it_starts_and_before_it_stops(
+        ds8, monkeypatch):
+    """The pipelined host runs rounds ahead of the device: without the
+    drains the trace of rounds [1, 3) would hold other rounds' device work."""
+    import jax
+
+    calls = []
+    monkeypatch.setattr(
+        jax.profiler, "start_trace", lambda log_dir, **kw: calls.append(
+            ("start", kw["profiler_options"].python_tracer_level)))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: calls.append(("stop",)))
+    t = Tracer(profile_rounds="1:3", profile_dir="/unused")
+    t.drain_fn = lambda: calls.append(("drain",))
+    for r in range(4):
+        with t.round(r):
+            calls.append(("round", r))
+    assert calls == [("round", 0), ("drain",), ("start", 0), ("round", 1),
+                     ("round", 2), ("drain",), ("stop",), ("round", 3)]
+
+    # train() lends the tracer its drain for the drive, and takes it back
+    class Peek(Tracer):
+        def round(self, round_idx):
+            self.lent = self.drain_fn is not None
+            return super().round(round_idx)
+
+    t = Peek()
+    _api(ds8, _cfg(1, pipeline_depth=2)).train(tracer=t)
+    assert t.lent and t.drain_fn is None
