@@ -16,8 +16,12 @@ torch DataLoader(shuffle=True, drop_last=False) epoch semantics are reproduced
 inside jit by sorting a uniform draw restricted to the valid prefix —
 `argsort(where(valid, u, +inf))` yields a permutation of the real samples
 followed by padding, so batches are full except the last, which is masked.
-Steps on all-padding batches are made no-ops via `tree_where` so Adam/momentum
-state is not polluted (SURVEY §7 hard part (b)).
+Steps on all-padding batches are no-ops via `tree_where` so Adam/momentum
+state is not polluted (SURVEY §7 hard part (b)) — and the one-chip vmap
+engine does not execute the steps past the cohort's last real batch at all:
+its step loop's trip count is the traced `live_steps(counts)`, one program
+for every cohort. The mesh, chunked and single-client callers keep the
+static `nb`-step scan (`live=None`).
 """
 
 from __future__ import annotations
@@ -138,23 +142,50 @@ def _merge_variables(variables, new_params, new_state):
 def epoch_batches(n_max: int, batch_size: int) -> tuple[int, int]:
     """(steps, batch) of one local epoch over a client padded to `n_max`
     rows: the ONE place that owns the `nb * b` arithmetic. `_build_epoch_fn`
-    shapes its scan with it and `round_slots` counts with it, so a change to
-    the padding changes the count too."""
+    shapes its batches with it, `live_steps` bounds its step loop with it and
+    `round_slots` counts with it, so a change to the padding changes the
+    count too. `steps` is the most a client can need; how many of them a
+    cohort executes is `live_steps`."""
     b = n_max if batch_size <= 0 else min(batch_size, n_max)
     return math.ceil(n_max / b), b
 
 
-def round_slots(cfg: FedConfig, clients: int, n_max: int) -> int:
+def live_steps(counts, n_max: int, batch_size: int):
+    """Steps of one local epoch in which ANY client of the cohort has a real
+    row: min(nb, ceil(max(counts) / b)). Real rows come first in every
+    epoch's order, so each later step is padding for every client and the
+    step loop stops there. A traced scalar: the loop's trip count
+    (`round_slots` does the same arithmetic on the host's integers)."""
+    nb, b = epoch_batches(n_max, batch_size)
+    return jnp.minimum(nb, (jnp.max(counts).astype(jnp.int32) + b - 1) // b)
+
+
+def round_slots(cfg: FedConfig, clients: int, n_max: int, counts=None) -> int:
     """Sample slots the round program executes for a staged cohort of
-    `clients` x `n_max` rows, padding included: clients x local steps x
-    batch x epochs. A host integer from shapes alone."""
+    `clients` x `n_max` rows, padding included: clients x executed local
+    steps x batch x epochs, a host integer. `counts` (the cohort's host
+    counts) is given by callers whose program runs `live_steps(counts)`
+    steps (the vmap engine); without it, and under `assume_full_clients`,
+    the program runs every one of the `nb` steps."""
     nb, b = epoch_batches(n_max, cfg.batch_size)
+    if counts is not None and not cfg.assume_full_clients:
+        nb = min(nb, math.ceil(int(max(counts)) / b))
     return clients * nb * b * cfg.epochs
 
 
 def _build_epoch_fn(trainer, cfg: FedConfig, opt) -> Callable:
     """Shared one-local-epoch body: epoch_fn(global_params, carry, x, y,
-    count, erng) -> (carry, auxs) with carry = (variables, opt_state, steps).
+    count, erng, live=None) -> (carry, auxs) with carry = (variables,
+    opt_state, steps) and auxs the step metrics stacked on a leading axis,
+    whose sum over that axis is the epoch's.
+
+    `live` (a traced scalar, the same for every client under vmap) stops the
+    step loop after the cohort's last real batch: a `while` over steps
+    [0, live) in place of the static `nb`-step scan. The steps left out are
+    all-padding for every client — zero masked loss, zero gradients,
+    `has_data` false — so the carry is bitwise the scan's and the metrics
+    are the same terms summed (tests/test_live_steps.py). `live=None` IS
+    the static scan.
 
     Both the monolithic E-epoch scan (build_local_update) and the chunked
     donated-carry dispatch (build_chunked_round_runner) scan this same
@@ -176,7 +207,7 @@ def _build_epoch_fn(trainer, cfg: FedConfig, opt) -> Callable:
                      and not cfg.wd and cfg.fedprox_mu == 0.0)
     full = cfg.assume_full_clients
 
-    def epoch_fn(global_params, carry, x, y, count, erng):
+    def epoch_fn(global_params, carry, x, y, count, erng, live=None):
         n_max = x.shape[0]
         nb, b = epoch_batches(n_max, cfg.batch_size)
         n_pad = nb * b
@@ -262,19 +293,44 @@ def _build_epoch_fn(trainer, cfg: FedConfig, opt) -> Callable:
             return (variables, opt_state, steps), aux
 
         srngs = jax.random.split(step_rng, nb)
-        (variables, opt_state, steps), auxs = jax.lax.scan(
-            step_body, (variables, opt_state, steps), (xe, ye, batch_valid, srngs)
-        )
-        return (variables, opt_state, steps), auxs
+        carry, batches = (variables, opt_state, steps), (xe, ye, batch_valid, srngs)
+        if live is None or full:
+            # full clients: live == nb, keep the static trip count
+            return jax.lax.scan(step_body, carry, batches)
+
+        def batch_at(i):
+            return jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+                batches)
+
+        # jitted so that the model is traced ONCE: the shapes of the step's
+        # metrics below and the loop's body share the one cached trace (a
+        # second trace of ResNet-56's step cost cross_silo 22 s of set-up)
+        step = jax.jit(step_body)
+
+        def live_body(i, state):
+            carry, sums = state
+            carry, aux = step(carry, batch_at(i))
+            return carry, jax.tree.map(jnp.add, sums, aux)
+
+        # the metrics are summed step by step in the loop's state, where the
+        # scan stacks them for its caller to sum: one row, already the sum
+        sums = jax.tree.map(
+            jnp.zeros_like, jax.eval_shape(step, carry, batch_at(0))[1])
+        carry, sums = jax.lax.fori_loop(0, live, live_body, (carry, sums))
+        return carry, jax.tree.map(lambda a: a[None], sums)
 
     return epoch_fn
 
 
 def build_local_update(trainer, cfg: FedConfig, pvary_axes: tuple = ()) -> Callable:
-    """Returns local_update(global_variables, x, y, count, rng) -> LocalResult.
+    """Returns local_update(global_variables, x, y, count, rng, live=None)
+    -> LocalResult.
 
     x: [n_max, ...], y: [n_max, ...], count: scalar int. Runs cfg.epochs of
-    minibatch SGD (lax.scan over epochs and batches).
+    minibatch SGD (lax.scan over epochs and batches). `live` (see
+    `_build_epoch_fn`; the vmap engine passes the cohort's `live_steps`
+    unbatched) bounds each epoch's step loop; None keeps the static scan.
 
     ``pvary_axes``: mesh axis names to `jax.lax.pcast(..., to='varying')` the
     incoming global variables over — REQUIRED when this update runs inside
@@ -290,7 +346,8 @@ def build_local_update(trainer, cfg: FedConfig, pvary_axes: tuple = ()) -> Calla
     opt = make_local_optimizer(cfg)
     epoch_fn = _build_epoch_fn(trainer, cfg, opt)
 
-    def local_update(global_variables, x, y, count, rng) -> LocalResult:
+    def local_update(global_variables, x, y, count, rng,
+                     live=None) -> LocalResult:
         if pvary_axes:
             global_variables = jax.lax.pcast(
                 global_variables, pvary_axes, to="varying")
@@ -298,7 +355,7 @@ def build_local_update(trainer, cfg: FedConfig, pvary_axes: tuple = ()) -> Calla
         opt_state = opt.init(global_params)
 
         def epoch_body(carry, erng):
-            return epoch_fn(global_params, carry, x, y, count, erng)
+            return epoch_fn(global_params, carry, x, y, count, erng, live)
 
         erngs = jax.random.split(rng, cfg.epochs)
         # steps starts as count*0 rather than a literal 0 so that under
@@ -324,12 +381,16 @@ def build_local_update(trainer, cfg: FedConfig, pvary_axes: tuple = ()) -> Calla
 
 def _vmapped_update(trainer, cfg: FedConfig) -> Callable:
     """batched_update(gv, x[C,...], y, counts, crngs) -> LocalResult — the
-    standard client-axis execution: vmap over local_update."""
+    standard client-axis execution: vmap over local_update. The cohort's
+    live steps are computed OUTSIDE the vmap and passed unbatched, so the
+    step loop's condition reads no per-client value (a batched condition
+    would make vmap `select` the whole carry every step)."""
     local_update = build_local_update(trainer, cfg)
 
     def batched(global_variables, x, y, counts, crngs):
-        return jax.vmap(local_update, in_axes=(None, 0, 0, 0, 0))(
-            global_variables, x, y, counts, crngs)
+        live = live_steps(counts, x.shape[1], cfg.batch_size)
+        return jax.vmap(local_update, in_axes=(None, 0, 0, 0, 0, None))(
+            global_variables, x, y, counts, crngs, live)
 
     return batched
 
@@ -348,11 +409,12 @@ def build_personal_local_update(trainer, cfg: FedConfig) -> Callable:
     entering aggregation or the wire."""
     local_update = build_local_update(trainer, cfg)
 
-    def personal_update(global_variables, x, y, count, rng, personal):
+    def personal_update(global_variables, x, y, count, rng, personal,
+                        live=None):
         effective = dict(global_variables)
         effective["params"] = jax.tree.map(
             jnp.add, global_variables["params"], personal)
-        result = local_update(effective, x, y, count, rng)
+        result = local_update(effective, x, y, count, rng, live)
         new_personal = jax.tree.map(
             jnp.subtract, result.variables["params"],
             global_variables["params"])
@@ -367,8 +429,9 @@ def _vmapped_personal_update(trainer, cfg: FedConfig) -> Callable:
     personal_update = build_personal_local_update(trainer, cfg)
 
     def batched(global_variables, x, y, counts, crngs, personal):
-        return jax.vmap(personal_update, in_axes=(None, 0, 0, 0, 0, 0))(
-            global_variables, x, y, counts, crngs, personal)
+        live = live_steps(counts, x.shape[1], cfg.batch_size)
+        return jax.vmap(personal_update, in_axes=(None, 0, 0, 0, 0, 0, None))(
+            global_variables, x, y, counts, crngs, personal, live)
 
     return batched
 

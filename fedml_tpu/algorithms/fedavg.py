@@ -159,6 +159,11 @@ class FedAvgAPI(Checkpointable):
         # Direct builder callers (bench, analysis enumeration) keep the
         # legacy 3-tuple default, so COMPILE/COMMS budgets are untouched.
         self._round_has_stats = True
+        # whether the round program stops its step loop at the cohort's last
+        # real batch (engine.live_steps: the vmap engine, plain or under
+        # GSPMD) or runs every step (shard_map meshes, silo groups, the
+        # fused kernel): what `round_slots` is told at staging
+        self._live_steps = False
         if config.tensor_shards > 0:
             # tensor path keeps the INNER aggregator — the codec lives in
             # the round's own wire transports (build_tensor_round_fn), and
@@ -169,6 +174,7 @@ class FedAvgAPI(Checkpointable):
                 param_sharding=self._tensor_sharding,
                 collect_stats=True,
                 codec=self.codec)
+            self._live_steps = bool(config.shard_step)
         elif config.backend == "shard_map":
             from fedml_tpu.parallel import build_sharded_round_fn, make_mesh
 
@@ -213,6 +219,7 @@ class FedAvgAPI(Checkpointable):
                 slots = min(config.client_num_per_round, dataset.client_num)
                 self.aggregator = wrap_codec(
                     self.aggregator, self.codec, slots)
+            self._live_steps = not config.fused_kernel
             # the pipelined drive loop stages a fresh device copy of the
             # cohort every round, so its buffers can be donated into the
             # round; eager callers (bench.py re-feeds one staged cohort)
@@ -677,9 +684,11 @@ class FedAvgAPI(Checkpointable):
                     per_round.update(masks)
                 # the cohorts are gathered in-graph from the resident store:
                 # what is staged is indices, what the chunk trains is this
-                rows = int(self.dataset.train.counts[idx_block].sum())
-                slots = k * round_slots(cfg, cohort,
-                                        self.dataset.train.x.shape[1])
+                counts_block = self.dataset.train.counts[idx_block]
+                rows = int(counts_block.sum())
+                slots = sum(round_slots(cfg, cohort,
+                                        self.dataset.train.x.shape[1], c)
+                            for c in counts_block)
             with tracer.span("h2d", r0, bytes=sum(
                     a.nbytes for a in per_round.values())):
                 per_round = jax.device_put(per_round)
@@ -878,7 +887,7 @@ class FedAvgAPI(Checkpointable):
             # counted here, where `counts` is still a host array: the real
             # rows, and the slots the round program runs for them
             n_rows = int(counts.sum())
-            n_slots = round_slots(cfg, x.shape[0], x.shape[1])
+            n_slots = self._round_slots(x, counts)
         host = [x, y, counts] + ([] if participation is None
                                  else [participation])
         if self.cfg.personalize:
@@ -891,6 +900,12 @@ class FedAvgAPI(Checkpointable):
                 personal = {"rows": rows, "tree": jax.device_put(gathered)}
         return StagedCohort(round_idx, dx, dy, dc, dp, faults, idx,
                             personal=personal, rows=n_rows, slots=n_slots)
+
+    def _round_slots(self, x, counts) -> int:
+        """engine.round_slots of a staged host cohort, by the steps THIS
+        API's round program executes for it."""
+        return round_slots(self.cfg, x.shape[0], x.shape[1],
+                           counts if self._live_steps else None)
 
     def _cohort_sharding(self):
         """Where a staged cohort goes on a mesh round: rows over the
@@ -935,8 +950,7 @@ class FedAvgAPI(Checkpointable):
                          bytes=x.nbytes + y.nbytes + counts.nbytes):
             dx, dy, dc, _ = stage_to_device(x, y, counts, None)
         return StagedCohort(round_idx, dx, dy, dc, None, faults, idx,
-                            rows=n_rows,
-                            slots=round_slots(cfg, x.shape[0], x.shape[1]))
+                            rows=n_rows, slots=self._round_slots(x, counts))
 
     def _train_pipelined(self, start_round, ckpt_dir, ckpt_every,
                          metrics_logger, chaos, guard, tracer,

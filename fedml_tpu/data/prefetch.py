@@ -57,9 +57,10 @@ class StagedCohort:
     device-resident [C, ...] adapter rows}`, staged alongside the data so
     the round dispatch stays one hop and the scatter-back targets exactly
     the rows that were fed. `rows` (the cohort's real rows) and `slots`
-    (what the round program executes for it, padding included:
-    engine.round_slots) are host integers counted at staging, for the
-    `dispatch` span."""
+    (what the round program executes for it, padding included, i.e. up to
+    the cohort's last real batch where the program stops there:
+    engine.round_slots with the host counts) are host integers counted at
+    staging, for the `dispatch` span."""
 
     round_idx: int
     x: Any
