@@ -5,7 +5,7 @@ alone, so every seed of a cell is served by one compiled program.
 
 A configuration's `data` group names its `kind`, and
 `benchmarks/datasets/<kind>.py` builds it (`make(spec, seed)`): a later PR
-brings another kind as a file of its own. Every kind so far is "class
+brings another kind as a file of its own. The image kinds are "class
 prototype * 0.6 + gaussian noise * 0.35" (a copy of the program's surrogates,
 fedml_tpu/data/sources.py, which a later PR may change and the yardstick may
 not follow), through `federation` below.

@@ -9,9 +9,12 @@ of a peak.
 
 `ctx` is what run.py knows after the window: the tracer with its spans, the
 window's seconds, rounds and real samples, the model's FLOPs, the peaks, (in
-a traced run) the reduced device trace, and for a reader of its own the raw
-material: the cell's files (`spec`), the program's config (`cfg`) and the
-federation's row counts (`counts`).
+a traced run) the reduced device trace (`ctx["trace"]`, whose `ops` holds
+every device op by name: what a kernel's own reader looks its kernel up
+in), and for a reader of its own the raw
+material: the cell's files (`spec`), the program's config (`cfg`), the
+federation's row counts (`counts`) and the real rows x epochs the harness
+counts in one round (`rows_of_round(r)`).
 """
 
 from __future__ import annotations
@@ -92,11 +95,6 @@ def model_flops_utilization(ctx, params):
     return 100.0 * need / ctx["window_s"] / ctx["chips"] / _peak_flops(ctx)
 
 
-def _trace_rounds(ctx):
-    lo, hi = ctx["tracer"].trace_rounds
-    return None if lo is None or hi is None or hi <= lo else hi - lo
-
-
 def trace_top_module_ms(ctx, params):
     """Mean device milliseconds of one execution of the compiled program
     that took most device time in the traced stretch: in a training cell,
@@ -109,15 +107,43 @@ def trace_top_module_ms(ctx, params):
 
 
 def executed_flops_roofline(ctx, params):
-    """FLOPs the device EXECUTES in the traced rounds (every padded slot of
-    every batch included) over peak and the seconds the device was busy.
-    Compute side of the roofline only: bytes are not counted."""
+    """FLOPs the device EXECUTES in the traced rounds over peak and the
+    seconds the device was busy: the sample slots the program says it ran
+    there (`slots` of the `dispatch` spans: every padded slot of every
+    executed step, epochs included) times the model's FLOPs a sample. The
+    harness keeps no slot formula of its own, and finds nothing to read
+    where no such span carries `slots`. Compute side of the roofline only:
+    bytes are not counted.
+
+    What the program reports is held between two counts of the harness's
+    own, so that a miscount cannot move the share with no kernel changed:
+    no fewer slots than the real rows the harness counts in those rounds
+    (`rows_of_round`), no more than every client of every such cohort padded
+    to the federation's longest. Outside them the run ends: the spans are
+    wrong, and so is every metric read from them."""
     t = ctx["trace"]
-    n = _trace_rounds(ctx) if t else None
-    if not n or t["busy_s"] <= 0 or ctx["peaks"] is None:
+    lo, hi = ctx["tracer"].trace_rounds
+    if (not t or lo is None or hi is None or t["busy_s"] <= 0
+            or ctx["peaks"] is None):
         return None
-    executed = (n * ctx["slots_per_round"] * ctx["epochs"]
-                * ctx["train_flops_per_sample"])
+    spans = [s for s in ctx["tracer"].window_spans("dispatch")
+             if "slots" in s and lo <= s["round"] < hi]
+    if not spans:
+        return None
+    slots = sum(s["slots"] for s in spans)
+    cfg, counts, chips = ctx["cfg"], ctx["counts"], ctx["chips"]
+    rows = sum(ctx["rows_of_round"](s["round"]) for s in spans)
+    n_max = int(max(counts))
+    b = min(cfg.batch_size, n_max) if cfg.batch_size > 0 else n_max
+    # a mesh round pads its cohort to a multiple of the chips (10 silos: 12)
+    cohort = -(-min(cfg.client_num_per_round, len(counts)) // chips) * chips
+    most = len(spans) * cohort * math.ceil(n_max / b) * b * cfg.epochs
+    if not rows <= slots <= most:
+        raise SystemExit(
+            f"the dispatch spans of rounds [{lo}, {hi}) report {slots} "
+            f"executed slots; the harness counts {rows} real rows there and "
+            f"at most {most} slots with every client padded to {n_max} rows")
+    executed = slots * ctx["train_flops_per_sample"]
     return 100.0 * executed / t["busy_s"] / _peak_flops(ctx)
 
 

@@ -106,12 +106,16 @@ def modules(events: list[tuple[str, float]]) -> list[list]:
             sorted(by_name.items(), key=lambda kv: -kv[1][1])]
 
 
-def top_ops(ops: list[tuple[str, float]], top: int = 10) -> list[list]:
-    by_name: dict[str, float] = {}
+def ops_by_name(ops: list[tuple[str, float]]) -> list[list]:
+    """[[name, seconds, executions]] of (name, seconds) events, most seconds
+    first: every op, for a reader that looks for its kernel by name."""
+    by_name: dict[str, list] = {}
     for name, dur in ops:
-        by_name[name] = by_name.get(name, 0.0) + dur
-    return [[n, s] for n, s in
-            sorted(by_name.items(), key=lambda kv: -kv[1])[:top]]
+        row = by_name.setdefault(name, [0.0, 0])
+        row[0] += dur
+        row[1] += 1
+    return [[n, s, c] for n, (s, c) in
+            sorted(by_name.items(), key=lambda kv: -kv[1][0])]
 
 
 def newest(trace_dir: str) -> str | None:
@@ -123,8 +127,10 @@ def newest(trace_dir: str) -> str | None:
 
 def read(trace_dir: str, chips: int) -> dict | None:
     """-> {"busy_s" (mean over chips), "window_s", "modules" (of the first
-    chip), "device_ops", "idle_gaps"} of the newest trace under `trace_dir`,
-    or None where there is no trace or no device plane in it."""
+    chip), "ops" (every op of the first chip: [short name, self seconds,
+    executions]), "device_ops" (the ten with most seconds, for the
+    breakdown), "idle_gaps"} of the newest trace under `trace_dir`, or None
+    where there is no trace or no device plane in it."""
     from jax.profiler import ProfileData
 
     path = newest(trace_dir)
@@ -156,10 +162,10 @@ def read(trace_dir: str, chips: int) -> dict | None:
     t1 = max([s + d for s, d in every] + [m[1] for m in marks])
     busy = [busy_union([(s, d) for _, s, d in ev]) for ev in devices]
     first = [(s, d) for _, s, d in devices[0]]
+    ops = ops_by_name([(short_name(n), d) for n, d in self_times(devices[0])])
     return {
         "busy_s": sum(busy) / len(busy), "window_s": t1 - t0,
         "modules": modules(programs),
-        "device_ops": top_ops([(short_name(n), d)
-                               for n, d in self_times(devices[0])]),
+        "ops": ops, "device_ops": [[n, s] for n, s, _ in ops[:10]],
         "idle_gaps": name_gaps(idle_gaps(first, t0, t1), host),
     }

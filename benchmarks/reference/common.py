@@ -26,7 +26,7 @@ def operand(a, compute: str):
     return a.astype(ACT_DTYPE[compute])
 
 
-def _precision(compute: str):
+def precision(compute: str):
     return jax.lax.Precision.HIGHEST if compute == "f32" else None
 
 
@@ -35,14 +35,18 @@ def conv(x, kernel, compute: str, stride: int = 1, pad: int = 0):
     return jax.lax.conv_general_dilated(
         operand(x, compute), operand(kernel, compute), (stride, stride),
         ((pad, pad), (pad, pad)), dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        precision=_precision(compute),
+        precision=precision(compute),
         preferred_element_type=ACT_DTYPE[compute])
 
 
+def matmul(x, kernel, compute: str):
+    return jnp.dot(operand(x, compute), operand(kernel, compute),
+                   precision=precision(compute),
+                   preferred_element_type=ACT_DTYPE[compute])
+
+
 def dense(x, kernel, bias, compute: str):
-    y = jnp.dot(operand(x, compute), operand(kernel, compute),
-                precision=_precision(compute),
-                preferred_element_type=ACT_DTYPE[compute])
+    y = matmul(x, kernel, compute)
     return y + bias.astype(y.dtype)
 
 
@@ -67,6 +71,13 @@ def dropout(x, rate: float, key):
     keep = 1.0 - rate
     mask = jax.random.bernoulli(key, p=keep, shape=x.shape)
     return jnp.where(mask, x / keep, jnp.zeros_like(x))
+
+
+def softmax_xent(logits, labels):
+    """Softmax cross-entropy of integer labels over the last axis."""
+    logits = logits - jax.lax.stop_gradient(logits.max(axis=-1, keepdims=True))
+    picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+    return jnp.log(jnp.exp(logits).sum(axis=-1)) - picked
 
 
 def scaled_normal(key, shape, fan_in: int):

@@ -8,11 +8,14 @@ computes from a global model, a cohort and a seed, client by client.
                   order: the client's real rows first, by ascending
                          uniform(shuffle_key, n_max); padding rows after
                   batch j: rows order[j*b:(j+1)*b], key split(step_key, nb)[j]
-                    g = grad of the mean loss over the batch's real rows
+                    g = grad of the model's mean loss over the batch's real
+                        rows (`model.loss`; softmax cross-entropy over one
+                        label a row where the model module has none)
                     g = g / max(1, |g|/clip);  w -= lr * (g + wd * w)
                     (a batch with no real row changes nothing)
               global = sum_i n_i * client_i / sum_i n_i, every variable
-              loss_r = sum of the LAST epoch's per-row losses / rows
+              loss_r = sum of the LAST epoch's losses / what the loss counts
+                       (rows; non-pad tokens for a language model)
 
 The key schedule and the batch layout are part of the semantics: they decide
 which rows share a batch (and a BatchNorm statistic) and which units dropout
@@ -34,6 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .common import softmax_xent
+
 
 def sample_cohort(round_idx: int, n_total: int, n_round: int) -> np.ndarray:
     """FedML's sampling: every client when the cohort is the population, else
@@ -44,10 +49,17 @@ def sample_cohort(round_idx: int, n_total: int, n_round: int) -> np.ndarray:
         n_total, min(n_round, n_total), replace=False)
 
 
-def _softmax_xent(logits, labels):
-    logits = logits - jax.lax.stop_gradient(logits.max(axis=-1, keepdims=True))
-    picked = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
-    return jnp.log(jnp.exp(logits).sum(axis=-1)) - picked
+def classifier_loss(logits, y, mask):
+    """The loss of a model module that brings none: softmax cross-entropy
+    over one label a row. -> (mean over the real rows, in the logits' type;
+    sum of the per-row losses f32; rows counted f32). A module's own
+    `loss(outputs, y, mask)` returns the same three, `total` being what the
+    program's trainer counts (non-pad tokens for next-word prediction)."""
+    per = softmax_xent(logits, y)
+    m = mask.astype(per.dtype)
+    loss = (per * m).sum() / jnp.maximum(m.sum(), 1.0)
+    per32, m32 = per.astype(jnp.float32), mask.astype(jnp.float32)
+    return loss, (per32 * m32).sum(), m32.sum()
 
 
 def _global_norm(tree):
@@ -55,19 +67,17 @@ def _global_norm(tree):
 
 
 def make_client_update(model, hp: dict, compute: str):
-    """client_update(variables, x[n_max,...], y[n_max], count, key) ->
+    """client_update(variables, x[n_max,...], y[n_max,...], count, key) ->
     (variables, loss_sum, total)."""
     b, epochs = hp["batch_size"], hp["epochs"]
     lr, wd, clip = hp["lr"], hp["wd"], hp["grad_clip"]
+    model_loss = getattr(model, "loss", classifier_loss)
 
     def loss_fn(params, state, bx, by, mask, key):
-        logits, new_state = model.apply({"params": params, **state}, bx, True,
-                                        key, compute, mask)
-        per = _softmax_xent(logits, by)
-        m = mask.astype(per.dtype)
-        loss = (per * m).sum() / jnp.maximum(m.sum(), 1.0)
-        per32, m32 = per.astype(jnp.float32), mask.astype(jnp.float32)
-        return loss, (new_state, (per32 * m32).sum(), m32.sum())
+        outputs, new_state = model.apply({"params": params, **state}, bx, True,
+                                         key, compute, mask)
+        loss, loss_sum, total = model_loss(outputs, by, mask)
+        return loss, (new_state, loss_sum, total)
 
     def step(carry, batch):
         params, state = carry
@@ -101,7 +111,7 @@ def make_client_update(model, hp: dict, compute: str):
             order = jnp.concatenate(
                 [order, jnp.zeros(nb * bs - n_max, order.dtype)])
             xe = x[order].reshape((nb, bs) + x.shape[1:])
-            ye = y[order].reshape(nb, bs)
+            ye = y[order].reshape((nb, bs) + y.shape[1:])
             valid = (jnp.arange(nb * bs) < count).reshape(nb, bs)
             (params, state), (loss_sum, total) = jax.lax.scan(
                 step, (params, state),

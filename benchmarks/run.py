@@ -17,8 +17,12 @@ whose `data.kind` one of `benchmarks/datasets/`), a traffic mix
 and bring the `limits` of what that adds) and, through the metrics that list
 it, readers (`benchmarks/end_to_end/<metric>.json`,
 `benchmarks/layer_metrics/<metric>.json`). Nothing here names a cell, a
-metric, a model or a kind of data. The last line has the contract's keys and
-`compared`; what else a run has to say (`run`) is the line before it.
+metric, a model, a kind of data, a task, a trainer, a loss or a kind of
+layer: the program picks model and trainer from the argv and the dataset's
+`meta` (`build_api`), the reference model's module brings its layers'
+FLOPs and its loss, and what the device executed is read from the program's
+spans. The last line has the contract's keys and `compared`; what else a run
+has to say (`run`) is the line before it.
 """
 
 from __future__ import annotations
@@ -98,26 +102,65 @@ def device_info(chips: int) -> dict:
 
 
 def build_api(config: dict, traffic: dict, data: dict, seed: int):
-    """The API as `main_fedavg.run` builds it (add_args -> config_from_args
-    -> create_model -> ClassificationTrainer -> FedAvgAPI), on the
-    benchmark's own dataset."""
+    """-> (FedAvgAPI, FedConfig) as `main_fedavg.run` builds them from the
+    configuration's and the traffic mix's argv, on the benchmark's own
+    dataset (what a `benchmarks/datasets/` kind returns; its `meta`, where
+    it brings one, is the dataset's: `{"task": "nwp"}`). Which model an argv
+    means, which trainer (and so which loss) a dataset's task takes and what
+    wraps it (`--lora_rank`) is decided by the program's preamble
+    (`fedml_tpu/experiments/common.py`) and by nothing here: a PR that gives
+    the program a new model or trainer changes the program alone."""
     from fedml_tpu.algorithms.fedavg import FedAvgAPI
-    from fedml_tpu.core.trainer import ClassificationTrainer
     from fedml_tpu.data import FederatedDataset, PackedClients
-    from fedml_tpu.experiments.common import add_args, config_from_args
-    from fedml_tpu.models import create_model
+    from fedml_tpu.experiments import common
 
     argv = (list(config["argv"]) + list(traffic.get("argv", []))
             + ["--comm_round", str(10 ** 6), "--seed", str(seed)])
-    args = add_args(argparse.ArgumentParser()).parse_args(argv)
-    cfg = config_from_args(args)
+    args = common.add_args(argparse.ArgumentParser()).parse_args(argv)
     ds = FederatedDataset(
         name=args.dataset, train=PackedClients(*data["train"]),
         test=PackedClients(*data["test"]), train_global=data["train_global"],
-        test_global=data["test_global"], class_num=data["classes"])
-    module = create_model(args.model, output_dim=ds.class_num,
-                          dtype=cfg.dtype)
-    return FedAvgAPI(ds, cfg, ClassificationTrainer(module)), cfg
+        test_global=data["test_global"], class_num=data["classes"],
+        meta=dict(data.get("meta", {})))
+    cfg, trainer = _through_setup_run(common, args, ds)
+    return FedAvgAPI(ds, cfg, trainer), cfg
+
+
+def _through_setup_run(common, args, ds) -> tuple:
+    """(config, trainer) for `ds` through `setup_run(args)`, the program's
+    only entry that picks model and trainer. It loads the data itself in the
+    same body (an entry that takes a dataset, `build_trainer(args, cfg,
+    ds)`, is the program's to cut: PERF.md section 7), so it is called with
+    the benchmark's dataset in its loader's place, and has to hand that very
+    dataset back: if it does not, the program resolves its loader another
+    way now, model and trainer were built for data of its own, and the run
+    ends here. It also seeds the global generators, as the CLI does (kept:
+    nothing the window runs draws from them), turns INFO logging on, and
+    sets the compile cache's threshold to the CLI's 1 s, which would leave
+    this run's fast programs out of the cache: those two are put back, so
+    that the process is the one the benchmark has measured since PR 26."""
+    import logging
+
+    import jax
+
+    root = logging.getLogger()
+    level, handlers = root.level, root.handlers[:]
+    secs = jax.config.jax_persistent_cache_min_compile_time_secs
+    loader = common.load_dataset
+    common.load_dataset = lambda *a, **k: ds
+    try:
+        cfg, loaded, trainer = common.setup_run(args)
+    finally:
+        common.load_dataset = loader
+        root.setLevel(level)
+        root.handlers[:] = handlers
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", secs)
+    if loaded is not ds:
+        raise SystemExit(
+            "fedml_tpu.experiments.common.setup_run did not take the "
+            "benchmark's dataset through common.load_dataset: its model and "
+            "trainer are not this cell's")
+    return cfg, trainer
 
 
 def check_hyper(cfg, hyper: dict) -> None:
@@ -254,12 +297,12 @@ def _run_cell(spec: dict, seed: int, seconds: float, trace: bool,
         "samples": window_samples(counts, first, last,
                                   cfg.client_num_per_round, cfg.epochs,
                                   sample_cohort),
-        "setup_s": tracer.t_open - t_start, "epochs": cfg.epochs,
+        "rows_of_round": lambda r: window_samples(
+            counts, r, r + 1, cfg.client_num_per_round, cfg.epochs,
+            sample_cohort),
+        "setup_s": tracer.t_open - t_start,
         "train_flops_per_sample": flops.train_flops_per_sample(
             model.layers(config["sizes"])),
-        "slots_per_round": (min(cfg.client_num_per_round, len(counts))
-                            * math.ceil(data["train"][0].shape[1]
-                                        / cfg.batch_size) * cfg.batch_size),
         # a CPU (the tests) has no peak: its readers then find nothing
         "peaks": (readers.peaks_for(jax.devices()[0].device_kind)
                   if tpu else None),
