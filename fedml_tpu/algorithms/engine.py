@@ -20,8 +20,11 @@ Steps on all-padding batches are no-ops via `tree_where` so Adam/momentum
 state is not polluted (SURVEY §7 hard part (b)) — and the one-chip vmap
 engine does not execute the steps past the cohort's last real batch at all:
 its step loop's trip count is the traced `live_steps(counts)`, one program
-for every cohort. The mesh, chunked and single-client callers keep the
-static `nb`-step scan (`live=None`).
+for every cohort. Where the federation's clients differ in size, FedAvgAPI
+packs the cohort onto fewer vmap lanes (`packed_lanes`, `_packed_update`):
+a lane that has finished a client starts the next, so a small client no
+longer sits through the largest one's steps. The mesh, chunked and
+single-client callers keep the static `nb`-step scan (`live=None`).
 """
 
 from __future__ import annotations
@@ -32,6 +35,7 @@ from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 
 from fedml_tpu.core.config import FedConfig
@@ -160,17 +164,97 @@ def live_steps(counts, n_max: int, batch_size: int):
     return jnp.minimum(nb, (jnp.max(counts).astype(jnp.int32) + b - 1) // b)
 
 
-def round_slots(cfg: FedConfig, clients: int, n_max: int, counts=None) -> int:
-    """Sample slots the round program executes for a staged cohort of
-    `clients` x `n_max` rows, padding included: clients x executed local
-    steps x batch x epochs, a host integer. `counts` (the cohort's host
-    counts) is given by callers whose program runs `live_steps(counts)`
-    steps (the vmap engine); without it, and under `assume_full_clients`,
-    the program runs every one of the `nb` steps."""
+def client_steps(counts, n_max: int, batch_size: int):
+    """Steps of one local epoch in which each client has a real row:
+    ceil(count / b), elementwise. Host integers in (numpy), host integers
+    out; traced in, traced out."""
+    nb, b = epoch_batches(n_max, batch_size)
+    return ((counts + (b - 1)) // b).clip(0, nb)
+
+
+def lane_schedule(counts, n_max: int, batch_size: int, epochs: int,
+                  lanes: int):
+    """The packed round's schedule, traced: the cohort's C clients dealt to
+    `lanes` queues longest first (LPT), each to the lane of least load, a
+    client's load being the steps it trains, epochs x ceil(count / b).
+    -> (queue [lanes, C] int32: a lane's clients in the order it runs them,
+    C where the queue has ended; per_epoch [C] int32: a client's steps an
+    epoch; trip: the most steps any lane runs, the loop's trip count). A
+    client of no rows has no load and is in no queue. `packed_trip` is the
+    same arithmetic on the host's integers."""
+    c = counts.shape[0]
+    per_epoch = client_steps(counts.astype(jnp.int32), n_max, batch_size)
+    load = per_epoch * epochs
+
+    def place(state, client):
+        lane_load, queue, length = state
+        lane = jnp.argmin(lane_load)
+        real = load[client] > 0  # the sort puts the clients of no rows last
+        queue = queue.at[lane, length[lane]].set(
+            jnp.where(real, client, c).astype(jnp.int32))
+        return (lane_load.at[lane].add(load[client]), queue,
+                length.at[lane].add(real.astype(jnp.int32))), None
+
+    order = jnp.argsort(-load, stable=True)
+    (lane_load, queue, _), _ = jax.lax.scan(
+        place, (jnp.zeros(lanes, jnp.int32),
+                jnp.full((lanes, c), c, jnp.int32),
+                jnp.zeros(lanes, jnp.int32)), order)
+    return queue, per_epoch, jnp.max(lane_load)
+
+
+def packed_trip(counts, n_max: int, batch_size: int, epochs: int,
+                lanes: int) -> int:
+    """`lane_schedule`'s trip count from the host's integers (the lanes'
+    loads do not depend on how ties are broken)."""
+    lane_load = [0] * lanes
+    for steps in sorted(client_steps(np.asarray(counts), n_max,
+                                     batch_size).tolist(), reverse=True):
+        lane_load[lane_load.index(min(lane_load))] += steps * epochs
+    return max(lane_load)
+
+
+def packed_lanes(counts, clients: int, n_max: int, batch_size: int) -> int:
+    """How many vmap lanes a cohort of `clients` drawn from a federation of
+    these `counts` is packed onto: the fewest whose balanced depth does not
+    exceed the steps the federation's largest client needs anyway,
+    min(C, ceil(C x mean_k steps_k / max_k steps_k)) with steps_k =
+    ceil(count_k / b). A federation of
+    like-sized clients gets C, which is the unpacked program; so does one
+    whose counts are not known (None)."""
+    if counts is None or not np.any(counts):
+        return clients
+    steps = client_steps(np.asarray(counts), n_max, batch_size)
+    return min(clients, math.ceil(clients * steps.mean() / steps.max()))
+
+
+def round_work(cfg: FedConfig, clients: int, n_max: int, counts=None,
+               lanes: int | None = None) -> dict:
+    """What the round program executes for a staged cohort of `clients` x
+    `n_max` rows, host integers: the vmap `lanes` it runs, the local steps
+    each of them executes (`trip`, all epochs together) and the sample
+    `slots` that makes, padding included: lanes x trip x batch. `counts`
+    (the cohort's host counts) is given by callers whose program stops at
+    the last real batch: after `live_steps(counts)` steps an epoch with a
+    lane a client (the vmap engine) or, with `lanes` < clients, after the
+    packed schedule's `packed_trip`. Without it, and under
+    `assume_full_clients`, the program runs every one of the `nb` steps of
+    every epoch."""
     nb, b = epoch_batches(n_max, cfg.batch_size)
-    if counts is not None and not cfg.assume_full_clients:
-        nb = min(nb, math.ceil(int(max(counts)) / b))
-    return clients * nb * b * cfg.epochs
+    if counts is None or cfg.assume_full_clients:
+        lanes, trip = clients, nb * cfg.epochs
+    elif lanes is not None and lanes < clients:
+        trip = packed_trip(counts, n_max, cfg.batch_size, cfg.epochs, lanes)
+    else:
+        lanes, trip = clients, cfg.epochs * int(client_steps(
+            np.asarray(counts), n_max, cfg.batch_size).max())
+    return {"lanes": lanes, "trip": trip, "slots": lanes * trip * b}
+
+
+def round_slots(cfg: FedConfig, clients: int, n_max: int, counts=None,
+                lanes: int | None = None) -> int:
+    """`round_work`'s sample slots."""
+    return round_work(cfg, clients, n_max, counts, lanes)["slots"]
 
 
 def _build_epoch_fn(trainer, cfg: FedConfig, opt) -> Callable:
@@ -195,10 +279,11 @@ def _build_epoch_fn(trainer, cfg: FedConfig, opt) -> Callable:
     # Stateless-optimizer fast path: with plain SGD (no momentum/wd) a zero
     # gradient IS a no-op update — masked losses give exactly-zero grads on
     # all-padding batches (mask is a constant factor of the loss), so the
-    # per-leaf tree_where select machinery is dead weight. The round profile
-    # is tiny-op latency-bound (~56 ops/step at ~20us), so dropping ~2 selects
-    # per param leaf per step is a real win; model state (e.g. BatchNorm
-    # running stats) is still masked because padded samples DO pollute it.
+    # per-leaf tree_where select machinery is dead weight and is left out
+    # (not measured on the chip since PR 1: the ledger shows the flagship
+    # round device-bound, three convolutions holding 65 % of its busy time);
+    # model state (e.g. BatchNorm running stats) is still masked because
+    # padded samples DO pollute it.
     # FedProx disqualifies the fast path: the proximal term mu*(p - g) is
     # nonzero even when the data-loss gradient is masked to zero, so an
     # all-padding batch WOULD take a prox-only step toward the global params
@@ -207,16 +292,14 @@ def _build_epoch_fn(trainer, cfg: FedConfig, opt) -> Callable:
                      and not cfg.wd and cfg.fedprox_mu == 0.0)
     full = cfg.assume_full_clients
 
-    def epoch_fn(global_params, carry, x, y, count, erng, live=None):
-        n_max = x.shape[0]
+    def epoch_order(count, erng, n_max):
+        """(perm [nb * b], step_rng) of one client's local epoch: the rows
+        in the order they are trained, real rows first and the tail padded
+        with row 0, and the key whose `split(step_rng, nb)[s]` step s draws
+        from. The one definition of the batch order and key derivation for
+        the per-client scan below and the packed lanes (`_packed_update`)."""
         nb, b = epoch_batches(n_max, cfg.batch_size)
         n_pad = nb * b
-        if full and n_pad != n_max:
-            raise ValueError(
-                f"assume_full_clients requires n_max ({n_max}) % batch_size "
-                f"({b}) == 0 — padded batches would be trained unmasked")
-
-        variables, opt_state, steps = carry
         shuffle_rng, step_rng = jax.random.split(erng)
         if cfg.shuffle and full:
             # all rows valid: argsort(u) IS argsort(where(valid,u,inf))
@@ -231,17 +314,12 @@ def _build_epoch_fn(trainer, cfg: FedConfig, opt) -> Callable:
             perm = jnp.arange(n_max)
         if n_pad > n_max:
             perm = jnp.concatenate([perm, jnp.zeros(n_pad - n_max, perm.dtype)])
-        # ONE epoch-level gather instead of a gather per step: scan then
-        # slices contiguous batches from the pre-permuted copy (dispatch-
-        # latency-bound regime — fewer, larger ops win).
-        xe = jnp.take(x, perm, axis=0).reshape((nb, b) + x.shape[1:])
-        ye = jnp.take(y, perm, axis=0).reshape((nb, b) + y.shape[1:])
-        if full:
-            # literal ones: XLA folds the mask multiplies away and the
-            # all-padding-batch selects below turn statically true
-            batch_valid = jnp.ones((nb, b), bool)
-        else:
-            batch_valid = (jnp.arange(n_pad) < count).reshape(nb, b)
+        return perm, step_rng
+
+    def step_for(global_params):
+        """step_body(carry, (bx, by, bvalid, srng)) -> (carry, aux): one
+        local SGD step of one client on one batch of `b` rows; FedProx pulls
+        towards `global_params`."""
 
         def step_body(carry, scan_in):
             variables, opt_state, steps = carry
@@ -292,8 +370,32 @@ def _build_epoch_fn(trainer, cfg: FedConfig, opt) -> Callable:
             steps = steps + has_data.astype(jnp.int32)
             return (variables, opt_state, steps), aux
 
+        return step_body
+
+    def epoch_fn(global_params, carry, x, y, count, erng, live=None):
+        n_max = x.shape[0]
+        nb, b = epoch_batches(n_max, cfg.batch_size)
+        n_pad = nb * b
+        if full and n_pad != n_max:
+            raise ValueError(
+                f"assume_full_clients requires n_max ({n_max}) % batch_size "
+                f"({b}) == 0 — padded batches would be trained unmasked")
+
+        perm, step_rng = epoch_order(count, erng, n_max)
+        # ONE epoch-level gather: the loop slices contiguous batches from
+        # the pre-permuted copy (the packed lanes, whose clients change
+        # inside the loop, gather a step's rows by index instead)
+        xe = jnp.take(x, perm, axis=0).reshape((nb, b) + x.shape[1:])
+        ye = jnp.take(y, perm, axis=0).reshape((nb, b) + y.shape[1:])
+        if full:
+            # literal ones: XLA folds the mask multiplies away and the
+            # all-padding-batch selects below turn statically true
+            batch_valid = jnp.ones((nb, b), bool)
+        else:
+            batch_valid = (jnp.arange(n_pad) < count).reshape(nb, b)
+        step_body = step_for(global_params)
         srngs = jax.random.split(step_rng, nb)
-        carry, batches = (variables, opt_state, steps), (xe, ye, batch_valid, srngs)
+        batches = (xe, ye, batch_valid, srngs)
         if live is None or full:
             # full clients: live == nb, keep the static trip count
             return jax.lax.scan(step_body, carry, batches)
@@ -320,6 +422,8 @@ def _build_epoch_fn(trainer, cfg: FedConfig, opt) -> Callable:
         carry, sums = jax.lax.fori_loop(0, live, live_body, (carry, sums))
         return carry, jax.tree.map(lambda a: a[None], sums)
 
+    # the pieces the packed lanes compose into a loop of their own
+    epoch_fn.order, epoch_fn.step_for = epoch_order, step_for
     return epoch_fn
 
 
@@ -391,6 +495,122 @@ def _vmapped_update(trainer, cfg: FedConfig) -> Callable:
         live = live_steps(counts, x.shape[1], cfg.batch_size)
         return jax.vmap(local_update, in_axes=(None, 0, 0, 0, 0, None))(
             global_variables, x, y, counts, crngs, live)
+
+    return batched
+
+
+def _packed_update(trainer, cfg: FedConfig, lanes: int) -> Callable:
+    """batched_update(gv, x[C,...], y, counts, crngs) -> LocalResult, the
+    cohort's C clients trained on `lanes` < C vmap lanes: `lane_schedule`
+    gives every lane a queue of clients, ONE step loop of `trip` steps runs
+    `_build_epoch_fn`'s step under vmap over the lanes, and a lane whose
+    client has trained its epochs x ceil(count / b) steps writes that
+    client's row of the [C, ...] result and starts its next client from the
+    global model with a fresh optimizer state. A client trains the batches
+    `_vmapped_update` gives it, in that order and with those keys; what is
+    left out is the all-padding steps a lane a client sits through while
+    the cohort's largest finishes (tests/test_lane_packing.py). A cohort of
+    `lanes` clients or fewer, and `assume_full_clients` (every client trains
+    every step: nothing to pack), run `_vmapped_update` itself."""
+    lane_a_client = _vmapped_update(trainer, cfg)  # checks cfg.epochs
+    opt = make_local_optimizer(cfg)
+    epoch_fn = _build_epoch_fn(trainer, cfg, opt)
+    epochs = cfg.epochs
+
+    def lane_where(pred, a, b):
+        return jax.tree.map(
+            lambda u, v: jnp.where(
+                pred.reshape(pred.shape + (1,) * (v.ndim - 1)), u, v), a, b)
+
+    def stacked(tree, n):
+        return jax.tree.map(
+            lambda l: jnp.broadcast_to(l, (n,) + l.shape), tree)
+
+    def trained(variables):
+        # the frozen LoRA base leaves the result, as in `local_update`
+        return {k: v for k, v in variables.items() if k != "lora_base"}
+
+    def batched(global_variables, x, y, counts, crngs):
+        c, n_max = x.shape[:2]
+        if lanes >= c or cfg.assume_full_clients:
+            return lane_a_client(global_variables, x, y, counts, crngs)
+        nb, b = epoch_batches(n_max, cfg.batch_size)
+        queue, per_epoch, trip = lane_schedule(
+            counts, n_max, cfg.batch_size, epochs, lanes)
+
+        # every client's batch order and step keys of every epoch, hoisted:
+        # perms [C, E, nb * b] row numbers, keys [C, E, nb, ...]
+        def client_orders(count, crng):
+            perms, step_rngs = jax.vmap(
+                lambda erng: epoch_fn.order(count, erng, n_max))(
+                    jax.random.split(crng, epochs))
+            return perms, jax.vmap(lambda k: jax.random.split(k, nb))(
+                step_rngs)
+
+        perms, keys = jax.vmap(client_orders)(counts, crngs)
+        flat_x = x.reshape((c * n_max,) + x.shape[2:])
+        flat_y = y.reshape((c * n_max,) + y.shape[2:])
+
+        global_params = global_variables["params"]
+        fresh = (global_variables, opt.init(global_params),
+                 jnp.zeros((), jnp.int32))
+        # jitted for ONE trace of the model, as in the per-client loop
+        step = jax.jit(jax.vmap(epoch_fn.step_for(global_params)))
+        lane_ids = jnp.arange(lanes)
+        in_batch = jnp.arange(b)
+
+        def batch_of(client, active, j):
+            """The batch of a lane's client at its j-th step, and where that
+            step lies in the client's epochs."""
+            spe = per_epoch[client]
+            epoch = j // jnp.maximum(spe, 1)
+            s = j - epoch * spe
+            rows = jax.vmap(
+                lambda cl, e, s0: jax.lax.dynamic_slice_in_dim(
+                    perms[cl, e], s0, b))(client, epoch, s * b)
+            at = client[:, None] * n_max + rows
+            bvalid = active[:, None] & (
+                s[:, None] * b + in_batch < counts[client][:, None])
+            return (jnp.take(flat_x, at, axis=0), jnp.take(flat_y, at, axis=0),
+                    bvalid, keys[client, epoch, s]), s
+
+        def body(_, state):
+            carry, sums, pos, j, out = state
+            client = queue[lane_ids, pos]
+            active = client < c
+            client = jnp.minimum(client, c - 1)
+            batch, s = batch_of(client, active, j)
+            # a lane that starts a client takes the global model, a fresh
+            # optimizer state and no steps; the metrics are an epoch's
+            carry = lane_where(active & (j == 0), fresh, carry)
+            sums = jax.tree.map(lambda v: jnp.where(s == 0, 0, v), sums)
+            carry, aux = step(carry, batch)
+            sums = jax.tree.map(jnp.add, sums, aux)
+            j = j + active.astype(jnp.int32)
+            done = active & (j == per_epoch[client] * epochs)
+            # the client's row of the result; a lane that is not done
+            # writes out of bounds, which is dropped
+            row = jnp.where(done, client, c)
+            variables, _, steps = carry
+            out = jax.tree.map(
+                lambda o, v: o.at[row].set(v, mode="drop"), out,
+                LocalResult(trained(variables), steps, sums))
+            return (carry, sums, pos + done.astype(jnp.int32),
+                    jnp.where(done, 0, j), out)
+
+        carry = stacked(fresh, lanes)
+        zeros = jnp.zeros(lanes, jnp.int32)
+        sums = jax.tree.map(jnp.zeros_like, jax.eval_shape(
+            lambda: step(carry, batch_of(zeros, zeros < 0, zeros)[0])[1]))
+        # a client that is never scheduled (no rows) keeps the global model,
+        # no steps and zero metrics: what its lane returns unpacked
+        out = LocalResult(
+            stacked(trained(global_variables), c), jnp.zeros(c, jnp.int32),
+            jax.tree.map(lambda l: jnp.zeros((c,) + l.shape[1:], l.dtype),
+                         sums))
+        *_, out = jax.lax.fori_loop(0, trip, body,
+                                    (carry, sums, zeros, zeros, out))
+        return out
 
     return batched
 
@@ -534,8 +754,14 @@ def build_round_fn(trainer, cfg: FedConfig, aggregator,
                    donate_data: bool = False,
                    param_sharding=None,
                    collect_stats: bool = False,
-                   codec=None) -> Callable:
+                   codec=None, lanes: int | None = None) -> Callable:
     """Jitted synchronous round: vmap(local_update) + aggregate.
+
+    `lanes` (a static integer, `packed_lanes` of the federation; FedAvgAPI
+    derives it) below the cohort's size packs the clients onto that many
+    vmap lanes (`_packed_update`). None, or the cohort's size or more, IS
+    the lane-a-client program: the same jaxpr. The mesh rounds and the
+    fused kernel take no lanes.
 
     `param_sharding` (a parallel.tensor.TensorSharding) switches the round
     onto the 2D ('clients', 'tensor') mesh: params and aggregator state live
@@ -639,9 +865,10 @@ def build_round_fn(trainer, cfg: FedConfig, aggregator,
     from fedml_tpu.core.builder import wrap_codec
 
     aggregator = wrap_codec(aggregator, codec, slots=cfg.client_num_per_round)
-    return build_round_fn_from_update(_vmapped_update(trainer, cfg),
-                                      aggregator, donate_data=donate_data,
-                                      collect_stats=collect_stats)
+    return build_round_fn_from_update(
+        _vmapped_update(trainer, cfg) if lanes is None
+        else _packed_update(trainer, cfg, lanes),
+        aggregator, donate_data=donate_data, collect_stats=collect_stats)
 
 
 def build_personal_round_fn(trainer, cfg: FedConfig, aggregator,

@@ -26,7 +26,9 @@ from fedml_tpu.algorithms.engine import (
     build_eval_fn,
     build_federation_eval_fn,
     build_round_fn,
+    packed_lanes,
     round_slots,
+    round_work,
     stage_to_device,
 )
 from fedml_tpu.core.config import FedConfig
@@ -164,6 +166,10 @@ class FedAvgAPI(Checkpointable):
         # GSPMD) or runs every step (shard_map meshes, silo groups, the
         # fused kernel): what `round_slots` is told at staging
         self._live_steps = False
+        # vmap lanes the cohort's clients are packed onto (engine.
+        # packed_lanes); None: a lane a client, every program but the plain
+        # vmap round below
+        self._lanes = None
         if config.tensor_shards > 0:
             # tensor path keeps the INNER aggregator — the codec lives in
             # the round's own wire transports (build_tensor_round_fn), and
@@ -238,10 +244,21 @@ class FedAvgAPI(Checkpointable):
                     donate_data=config.pipeline_depth > 0,
                     collect_stats=True)
             else:
+                if not (config.fused_kernel or config.buffer_size > 0
+                        or config.rounds_per_dispatch > 1):
+                    # derived from the federation, never set. The buffered
+                    # drive's client step and the superstep drive's K-round
+                    # program run a lane a client, and so does the eager
+                    # round the superstep is held bit-identical to
+                    train = dataset.train
+                    self._lanes = packed_lanes(
+                        getattr(train, "counts", None),
+                        min(config.client_num_per_round, dataset.client_num),
+                        train.n_max, config.batch_size)
                 self.round_fn = build_round_fn(
                     model_trainer, config, self.aggregator,
                     donate_data=config.pipeline_depth > 0,
-                    collect_stats=True)
+                    collect_stats=True, lanes=self._lanes)
         self._personalized = bool(config.personalize)
         #: the attached personal adapter bank (models/adapter_bank.py) —
         #: set by train(bank=...) or directly; required when personalizing
@@ -315,7 +332,8 @@ class FedAvgAPI(Checkpointable):
             tracer = telemetry.get_tracer() or telemetry.NULL_TRACER
         staged = self.stage_fn(round_idx, faults=faults, tracer=tracer)
         with tracer.span("dispatch", round_idx,
-                         rows=staged.rows * cfg.epochs, slots=staged.slots):
+                         rows=staged.rows * cfg.epochs, slots=staged.slots,
+                         lanes=staged.lanes, trip=staged.trip):
             rng = jax.random.fold_in(jax.random.PRNGKey(cfg.seed), round_idx)
             if rng_salt:
                 rng = jax.random.fold_in(rng, rng_salt)
@@ -887,7 +905,7 @@ class FedAvgAPI(Checkpointable):
             # counted here, where `counts` is still a host array: the real
             # rows, and the slots the round program runs for them
             n_rows = int(counts.sum())
-            n_slots = self._round_slots(x, counts)
+            work = self._round_work(x, counts)
         host = [x, y, counts] + ([] if participation is None
                                  else [participation])
         if self.cfg.personalize:
@@ -899,13 +917,13 @@ class FedAvgAPI(Checkpointable):
             if self.cfg.personalize:
                 personal = {"rows": rows, "tree": jax.device_put(gathered)}
         return StagedCohort(round_idx, dx, dy, dc, dp, faults, idx,
-                            personal=personal, rows=n_rows, slots=n_slots)
+                            personal=personal, rows=n_rows, **work)
 
-    def _round_slots(self, x, counts) -> int:
-        """engine.round_slots of a staged host cohort, by the steps THIS
-        API's round program executes for it."""
-        return round_slots(self.cfg, x.shape[0], x.shape[1],
-                           counts if self._live_steps else None)
+    def _round_work(self, x, counts) -> dict:
+        """engine.round_work of a staged host cohort (lanes, trip, slots),
+        by the steps THIS API's round program executes for it."""
+        return round_work(self.cfg, x.shape[0], x.shape[1],
+                          counts if self._live_steps else None, self._lanes)
 
     def _cohort_sharding(self):
         """Where a staged cohort goes on a mesh round: rows over the
@@ -950,7 +968,7 @@ class FedAvgAPI(Checkpointable):
                          bytes=x.nbytes + y.nbytes + counts.nbytes):
             dx, dy, dc, _ = stage_to_device(x, y, counts, None)
         return StagedCohort(round_idx, dx, dy, dc, None, faults, idx,
-                            rows=n_rows, slots=self._round_slots(x, counts))
+                            rows=n_rows, **self._round_work(x, counts))
 
     def _train_pipelined(self, start_round, ckpt_dir, ckpt_every,
                          metrics_logger, chaos, guard, tracer,
@@ -1015,7 +1033,8 @@ class FedAvgAPI(Checkpointable):
                         snapshot = (self._ckpt_tree(), self._ckpt_meta())
                     with tracer.span("dispatch", round_idx,
                                      rows=staged.rows * cfg.epochs,
-                                     slots=staged.slots):
+                                     slots=staged.slots, lanes=staged.lanes,
+                                     trip=staged.trip):
                         rng = jax.random.fold_in(jax.random.PRNGKey(cfg.seed),
                                                  round_idx)
                         if retries:

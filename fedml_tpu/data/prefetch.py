@@ -60,7 +60,8 @@ class StagedCohort:
     (what the round program executes for it, padding included, i.e. up to
     the cohort's last real batch where the program stops there:
     engine.round_slots with the host counts) are host integers counted at
-    staging, for the `dispatch` span."""
+    staging, for the `dispatch` span, as are `lanes` and `trip`
+    (engine.round_work: slots = lanes x trip x batch)."""
 
     round_idx: int
     x: Any
@@ -72,6 +73,8 @@ class StagedCohort:
     personal: Any | None = None
     rows: int = 0
     slots: int = 0
+    lanes: int = 0
+    trip: int = 0
 
 
 #: invalidate()'s default scope: every job's in-flight stagings (the

@@ -63,6 +63,26 @@ def _pcts(durs: List[float]) -> Dict[str, float]:
     }
 
 
+def _dispatch_work(spans: List[Dict[str, Any]]) -> Dict[str, Any]:
+    """What the round programs executed, from the work counts the
+    `dispatch` spans carry: the vmap lanes (one value, or each that
+    occurred), the mean steps a lane ran and the share of the executed
+    sample slots that held no real row. Empty where no span counts."""
+    counted = [s for s in spans if s.get("slots")]
+    if not counted:
+        return {}
+    work = {"padding_pct": round(100.0 * (1.0 - sum(
+        s.get("rows", 0) for s in counted) / sum(
+            s["slots"] for s in counted)), 4)}
+    stepped = [s for s in counted if "trip" in s]
+    if stepped:
+        lanes = sorted({s["lanes"] for s in stepped})
+        work["lanes"] = lanes[0] if len(lanes) == 1 else lanes
+        work["trip_mean"] = round(
+            sum(s["trip"] for s in stepped) / len(stepped), 4)
+    return work
+
+
 def _union_len(intervals: List[Tuple[float, float]]) -> float:
     """Total length covered by possibly-overlapping [lo, hi) intervals."""
     total, cursor = 0.0, None
@@ -169,6 +189,9 @@ def fold(records: List[Dict[str, Any]]) -> Dict[str, Any]:
         "truncated_lines": sum(r.get("count", 0) for r in records
                                if r.get("type") == "truncated_lines"),
     }
+    if "dispatch" in report["phases"]:
+        report["phases"]["dispatch"].update(_dispatch_work(
+            [s for s in spans if s["name"] == "dispatch"]))
     if compile_counts is not None:
         report["compile"] = compile_counts
     for k in ("platform", "cpu_cores", "cpu_capped", *_WORKLOAD_KEYS):

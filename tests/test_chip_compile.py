@@ -91,7 +91,7 @@ def test_flash_attention_compiles(one_chip, no_compile_cache, shape, dtype,
 
 
 def _round_program(one_chip, model, output_dim, sample_shape, clients,
-                   samples, **cfg_kw):
+                   samples, lanes=None, **cfg_kw):
     """The round program `FedAvgAPI` builds for the CLI's default drive
     (pipelined: cohort buffers donated, ledger stats collected), lowered
     from eval_shape'd variables on the described chip."""
@@ -110,7 +110,7 @@ def _round_program(one_chip, model, output_dim, sample_shape, clients,
         jax.random.PRNGKey(0), jnp.zeros((1,) + sample_shape)))
     state = jax.eval_shape(agg.init_state, gv)
     round_fn = build_round_fn(trainer, cfg, agg, donate_data=True,
-                              collect_stats=True)
+                              collect_stats=True, lanes=lanes)
     args = _on(one_chip, (
         gv, state,
         jax.ShapeDtypeStruct((clients, samples) + sample_shape, jnp.float32),
@@ -120,12 +120,15 @@ def _round_program(one_chip, model, output_dim, sample_shape, clients,
     return round_fn.jitted.lower(*args).compile()
 
 
-def test_flagship_round_compiles(one_chip, no_compile_cache):
+@pytest.mark.parametrize("lanes", [None, 5])
+def test_flagship_round_compiles(one_chip, no_compile_cache, lanes):
     """engine.round for CNN_DropOut, 10 clients x 480 x 28x28, bs 20, f32 —
-    what `chip_smoke.py`'s flagship phase dispatches every round."""
+    what `chip_smoke.py`'s flagship phase dispatches every round: a lane a
+    client, and packed onto the 5 lanes `FedAvgAPI` derives for FEMNIST's
+    writers (engine.packed_lanes)."""
     compiled = _round_program(
         one_chip, "cnn", 62, (28, 28, 1), clients=10, samples=480,
-        batch_size=20, lr=0.1)
+        lanes=lanes, batch_size=20, lr=0.1)
     _fits(compiled)
 
 

@@ -298,8 +298,13 @@ def test_staged_cohort_slots_follow_the_round_program(backend):
         clients = staged.x.shape[0]  # the mesh pads 10 up to its 8 devices
         assert staged.rows == int(counts.sum())
         if backend == "vmap":
-            assert staged.slots == round_slots(cfg, 10, n_max, counts)
-            assert staged.slots == 10 * live * b
+            # this ragged federation is packed (tests/test_lane_packing.py):
+            # fewer lanes than clients, each running `trip` steps
+            assert staged.slots == round_slots(cfg, 10, n_max, counts,
+                                               api._lanes)
+            assert staged.slots == staged.lanes * staged.trip * b
+            assert staged.lanes == api._lanes < 10 and staged.trip >= live
+            assert staged.slots <= 10 * live * b
         else:
             assert staged.slots == clients * nb * b
         seen.add(live)

@@ -429,6 +429,32 @@ def test_fold_produces_bench_style_report(ds8, tmp_path):
     assert report["events"]["round_committed"] == 3
 
 
+def test_fold_shows_what_the_dispatches_executed(tmp_path):
+    """The `dispatch` row of tools/trace_report.py: lanes, mean trip and
+    the padding share, from the spans' own work counts."""
+    path = str(tmp_path / "TRACE.jsonl")
+    t = Tracer(jsonl_path=path)
+    for r, (rows, trip) in enumerate([(90, 6), (150, 10)]):
+        with t.round(r):
+            with t.span("dispatch", r, rows=rows, slots=4 * trip * 5,
+                        lanes=4, trip=trip):
+                pass
+            with t.span("metrics_fetch", r):
+                pass
+    t.close()
+    row = fold(load_trace(path))["phases"]["dispatch"]
+    assert row["count"] == 2
+    assert (row["lanes"], row["trip_mean"]) == (4, 8.0)
+    assert row["padding_pct"] == 25.0      # 240 rows in 320 slots
+    assert "lanes" not in fold(load_trace(path))["phases"]["metrics_fetch"]
+    # spans of a program that counts no steps (the superstep's): the share
+    with Tracer(jsonl_path=path) as t:
+        with t.span("dispatch", 0, rows=30, slots=40, rounds=2):
+            pass
+    row = fold(load_trace(path))["phases"]["dispatch"]
+    assert row["padding_pct"] == 25.0 and "lanes" not in row
+
+
 def test_perf_gate_trips_with_readable_diff():
     report = {"value": 4.0, "platform": "cpu"}
     bench = {"rounds_per_sec": 40.0, "platform": "cpu"}
