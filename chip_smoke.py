@@ -48,7 +48,7 @@ FLAGSHIP_ARGV = [
     "--client_num_in_total", "3400", "--client_num_per_round", "10",
     "--batch_size", "20", "--epochs", "1", "--lr", "0.1"]
 #: the reference's second published shape and the widest conv model the
-#: engine vmaps (bf16 is the dtype bench.py runs it in)
+#: engine vmaps (bf16 is the dtype the cross_silo benchmark cell runs it in)
 CROSS_SILO_ARGV = [
     "--dataset", "cifar10", "--model", "resnet56",
     "--client_num_in_total", "10", "--client_num_per_round", "10",

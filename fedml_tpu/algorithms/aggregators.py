@@ -26,12 +26,10 @@ order (tests/test_parallel.py asserts <=1e-6)."""
 
 from __future__ import annotations
 
-import logging
 from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import optax
 
 from fedml_tpu.core.config import FedConfig
@@ -42,18 +40,6 @@ from fedml_tpu.utils.pytree import (
     tree_weighted_mean,
     tree_where,
 )
-
-log = logging.getLogger(__name__)
-
-# flat_agg stages the whole round as ONE [C, P] f32 buffer — a second full
-# copy of every client's params. Cheap at flagship size (10 x 1.2M x 4B =
-# 48 MiB) but quadratic-feeling at scale: 100 silos of a 100M-param model
-# would stage 40 GiB and OOM the chip with an opaque XLA allocation error.
-# Shapes are static, so the guard runs at TRACE time (before any device
-# allocation) against this cap; mirrors FedConfig.resident_eval_budget's
-# bytes-budget convention and is overridable per-call (the aggregator
-# forwards FedConfig.extra["flat_agg_budget"]).
-FLAT_AGG_DEFAULT_BUDGET = 2 << 30
 
 
 def client_finite_mask(stacked_tree) -> jnp.ndarray:
@@ -124,74 +110,20 @@ def tree_weighted_mean_psum(stacked_tree, weights, axis):
     return tree_weighted_sum_psum(stacked_tree, w, axis)
 
 
-def tree_weighted_mean_flat(stacked_tree, weights, byte_budget=None):
-    """tree_weighted_mean as ONE [C] x [C, P] matvec over the raveled
-    concatenation of all leaves, split back afterwards.
-
-    The flagship round is tiny-op latency-bound (docs/PERF.md): the r4
-    ablation measured the per-leaf weighted mean at ~3% of the round
-    (flagship_ablation.json identity-agg rung). Collapsing the ~8 per-leaf
-    multiply-reduces into one fused contraction trades two P-sized copies
-    (concat in, slice out — HBM-cheap) for fewer dispatched ops. Opt in via
-    FedConfig.extra["flat_agg"]; measured A/B in docs/PERF.md.
-
-    Raises (at trace time, before any allocation) when the staged [C, P]
-    f32 concat would exceed ``byte_budget`` (default
-    FLAT_AGG_DEFAULT_BUDGET) — the per-leaf tree_weighted_mean computes the
-    same mean without the extra full-federation copy."""
-    leaves, treedef = jax.tree.flatten(stacked_tree)
-    c = leaves[0].shape[0]
-    p = sum(int(np.prod(l.shape[1:])) if l.ndim > 1 else 1 for l in leaves)
-    staged = 4 * c * p  # the [C, P] f32 concat below
-    budget = FLAT_AGG_DEFAULT_BUDGET if byte_budget is None else int(byte_budget)
-    log.debug("flat_agg staging [C=%d, P=%d] f32 = %.1f MiB (budget %.1f MiB)",
-              c, p, staged / 2**20, budget / 2**20)
-    if staged > budget:
-        raise ValueError(
-            f"flat_agg would stage a [{c}, {p}] f32 copy of the round "
-            f"({staged / 2**30:.2f} GiB > budget {budget / 2**30:.2f} GiB) "
-            f"on top of the client-stacked params already resident — likely "
-            f"OOM. flat_agg is a small-model latency probe (and a measured "
-            f"NEGATIVE at flagship size, docs/PERF.md §agg): drop "
-            f"extra['flat_agg'] to use the per-leaf weighted mean (same "
-            f"result, no staged copy), or raise "
-            f"extra['flat_agg_budget'] if the chip really has the headroom.")
-    flat = jnp.concatenate(
-        [l.reshape(c, -1).astype(jnp.float32) for l in leaves], axis=1)
-    w = (weights / jnp.maximum(jnp.sum(weights), 1e-12)).astype(jnp.float32)
-    avg = w @ flat  # [P]
-    out, off = [], 0
-    for l in leaves:
-        n = int(np.prod(l.shape[1:])) if l.ndim > 1 else 1
-        out.append(avg[off:off + n].reshape(l.shape[1:]).astype(l.dtype))
-        off += n
-    return jax.tree.unflatten(treedef, out)
-
-
 class FedAvgAggregator:
     """Sample-weighted mean over every variable collection (the reference
     averages the full state_dict, BN stats included)."""
 
     def __init__(self, cfg: FedConfig):
         self.cfg = cfg
-        self.flat = bool(cfg.extra.get("flat_agg", False))
-        self.flat_budget = cfg.extra.get("flat_agg_budget")
 
     def init_state(self, global_variables) -> Any:
         return ()
 
     def __call__(self, global_variables, result, weights, rng, state):
-        if self.flat:
-            return tree_weighted_mean_flat(
-                result.variables, weights, byte_budget=self.flat_budget), state
         return tree_weighted_mean(result.variables, weights), state
 
     def sharded(self, global_variables, result, weights, rng, state, axis):
-        if self.flat:
-            raise ValueError(
-                "flat_agg is a single-chip latency probe (and a measured "
-                "negative, docs/PERF.md) — it has no sharded rule; drop "
-                "extra['flat_agg'] for shard_map runs")
         return tree_weighted_mean_psum(result.variables, weights, axis), state
 
 

@@ -724,10 +724,9 @@ def build_round_fn_from_update(batched_update, aggregator,
     `donate_data=True` donates the (x, y, counts) cohort buffers into the
     round — the pipelined drive loop stages a FRESH device copy per round,
     so XLA may reuse that HBM in place. Donation is strictly opt-in: callers
-    that re-feed the same buffers across rounds (bench.py holds one staged
-    cohort for every timed rep) would hit deleted-buffer errors. Donation
-    never changes the traced program, only buffer aliasing, so donated and
-    undonated rounds are bit-identical.
+    that feed the same buffers to more than one round would hit
+    deleted-buffer errors. Donation never changes the traced program, only
+    buffer aliasing, so donated and undonated rounds are bit-identical.
     """
     core = _round_core(batched_update, aggregator, collect_stats)
 
@@ -760,8 +759,8 @@ def build_round_fn(trainer, cfg: FedConfig, aggregator,
     `lanes` (a static integer, `packed_lanes` of the federation; FedAvgAPI
     derives it) below the cohort's size packs the clients onto that many
     vmap lanes (`_packed_update`). None, or the cohort's size or more, IS
-    the lane-a-client program: the same jaxpr. The mesh rounds and the
-    fused kernel take no lanes.
+    the lane-a-client program: the same jaxpr. The mesh rounds take no
+    lanes.
 
     `param_sharding` (a parallel.tensor.TensorSharding) switches the round
     onto the 2D ('clients', 'tensor') mesh: params and aggregator state live
@@ -786,64 +785,6 @@ def build_round_fn(trainer, cfg: FedConfig, aggregator,
     (and an unwrapped aggregator) traces the exact legacy program —
     codec-off rounds stay bit-identical.
     """
-    if getattr(cfg, "fused_kernel", False):
-        # ROADMAP item 1a: route the epoch through the fused pallas SGD
-        # kernel (ops/fused_sgd.py). The kernel IS the model+optimizer
-        # program, so every knob it cannot honor is rejected loudly here
-        # instead of silently diverging from the engine trajectory.
-        # config-level exclusions + value constraints live in the ONE
-        # table (core/spec.py, graft-matrix); only the checks on runtime
-        # ARGUMENTS (param_sharding/codec objects, the trainer's module)
-        # stay local — the config cannot see those
-        from fedml_tpu.core.spec import validate_config
-        validate_config(cfg)
-        if param_sharding is not None:
-            raise ValueError(
-                "--fused_kernel is mutually exclusive with --tensor_shards "
-                "(the kernel owns the whole client step)")
-        if codec is not None:
-            raise ValueError(
-                "--fused_kernel is mutually exclusive with --update_codec")
-        if type(trainer.module).__name__ != "CNN_DropOut":
-            raise ValueError(
-                "--fused_kernel supports the femnist CNN_DropOut model only")
-        from fedml_tpu.ops.fused_sgd import (FusedEpochSpec,
-                                             build_fused_round_fn)
-
-        # CPU runs the kernel in pallas interpret mode: correctness-honest,
-        # no speed claim (tools/bench_fused.py) — the Mosaic path needs a
-        # real TPU backend, and on one the kernel is never interpreted
-        from fedml_tpu.ops.interpret import interpret_off_chip
-        interpret = interpret_off_chip("fused_epoch")
-        n_classes = int(getattr(trainer.module, "output_dim", 62))
-        compute_dtype = (jnp.bfloat16 if cfg.dtype == "bfloat16"
-                         else jnp.float32)
-        _specialized: dict = {}
-
-        def fused_round(gv, agg_state, x, y, counts, rng, *rest):
-            # per-client sample count is data geometry, not config — build
-            # the spec (and jit) once per cohort shape, like the engine's
-            # own shape-keyed retraces
-            key = tuple(x.shape)
-            if key not in _specialized:
-                spec = FusedEpochSpec(
-                    height=int(x.shape[2]), width=int(x.shape[3]),
-                    n_classes=n_classes, samples=int(x.shape[1]),
-                    batch=cfg.batch_size, lr=cfg.lr,
-                    grad_clip=cfg.grad_clip, compute_dtype=compute_dtype,
-                    # mirror the module's own rates — a drop-free CNN twin
-                    # (bench_fused's allclose arm) must stay drop-free fused
-                    drop1=float(getattr(trainer.module, "drop1", 0.25)),
-                    drop2=float(getattr(trainer.module, "drop2", 0.5)))
-                _specialized[key] = build_fused_round_fn(
-                    spec, aggregator, shuffle=cfg.shuffle,
-                    interpret=interpret, collect_stats=collect_stats)
-            return _specialized[key](gv, agg_state, x, y, counts, rng, *rest)
-
-        from fedml_tpu import telemetry
-        telemetry.emit("round_fn_built", program="engine.round[fused]",
-                       donate=False)
-        return fused_round
     if param_sharding is not None:
         if getattr(cfg, "shard_step", False):
             # activation-sharded client step (GSPMD) — allclose contract,
@@ -1030,56 +971,6 @@ def build_chunked_round_runner(trainer, cfg: FedConfig, aggregator,
     return round_runner
 
 
-def build_multi_round_fn_from_update(batched_update, cfg: FedConfig,
-                                     aggregator, num_rounds: int) -> Callable:
-    """R federated rounds as ONE jitted lax.scan — the dispatch-amortized fast
-    path, over any batched client update. The whole federation's packed data
-    lives on device; per round, client sampling happens in-graph
-    (jax.random.permutation prefix, the in-XLA analog of the reference's
-    np.random.seed(round_idx) choice at FedAVGAggregator.py:89-97 — same
-    distribution, different stream).
-
-    With client_num_per_round == total clients the per-round computation is
-    bit-identical to build_round_fn called sequentially with
-    rng = fold_in(base_rng, round_idx) (tested in tests/test_fedavg.py).
-    """
-
-    def multi_round(global_variables, agg_state, x, y, counts, base_rng):
-        c_total = x.shape[0]
-        k = min(cfg.client_num_per_round, c_total)
-
-        def body(carry, round_idx):
-            gv, st = carry
-            rng = jax.random.fold_in(base_rng, round_idx)
-            if k < c_total:
-                idx = jax.random.permutation(jax.random.fold_in(rng, 0x5A11), c_total)[:k]
-                xs = jnp.take(x, idx, axis=0)
-                ys = jnp.take(y, idx, axis=0)
-                cs = jnp.take(counts, idx, axis=0)
-            else:
-                # full participation: the identity gather would still move the
-                # whole federation through HBM every round — skip it
-                xs, ys, cs = x, y, counts
-            crngs = jax.random.split(rng, k)
-            result = batched_update(gv, xs, ys, cs, crngs)
-            gv, st = aggregator(gv, result, cs.astype(jnp.float32), rng, st)
-            metrics = {mk: mv.sum() for mk, mv in result.metrics.items()}
-            return (gv, st), metrics
-
-        (gv, st), metrics = jax.lax.scan(
-            body, (global_variables, agg_state), jnp.arange(num_rounds)
-        )
-        return gv, st, metrics  # metrics leaves have leading [num_rounds]
-
-    return jax.jit(multi_round)
-
-
-def build_multi_round_fn(trainer, cfg: FedConfig, aggregator, num_rounds: int) -> Callable:
-    """R vmap-engine rounds as one jitted lax.scan."""
-    return build_multi_round_fn_from_update(
-        _vmapped_update(trainer, cfg), cfg, aggregator, num_rounds)
-
-
 def build_superstep_fn_from_update(batched_update, cfg: FedConfig,
                                    aggregator, num_rounds: int, *,
                                    client_num_in_total: int,
@@ -1088,9 +979,7 @@ def build_superstep_fn_from_update(batched_update, cfg: FedConfig,
                                    in_graph_sampling: bool = False) -> Callable:
     """K federated rounds as ONE jitted `lax.scan` over `_round_core` —
     BIT-identical to K eager `build_round_fn_from_update` rounds on the
-    `rng = fold_in(base_rng, round_idx)` stream (tests/test_superstep.py),
-    unlike build_multi_round_fn_from_update above, whose in-graph
-    `jax.random.permutation` sampling is a different seeded trajectory.
+    `rng = fold_in(base_rng, round_idx)` stream (tests/test_superstep.py).
 
     Per-round traced inputs arrive as a `per_round` dict of [K]-leading
     arrays (the scan's xs):

@@ -158,13 +158,13 @@ class FedAvgAPI(Checkpointable):
         # stats rows (collect_stats=True): whether a ledger is attached to
         # the drive only changes host-side scatter writes, never the traced
         # program — that is the whole ledger on/off bit-identity argument.
-        # Direct builder callers (bench, analysis enumeration) keep the
+        # Direct builder callers (the analysis enumeration) keep the
         # legacy 3-tuple default, so COMPILE/COMMS budgets are untouched.
         self._round_has_stats = True
         # whether the round program stops its step loop at the cohort's last
         # real batch (engine.live_steps: the vmap engine, plain or under
-        # GSPMD) or runs every step (shard_map meshes, silo groups, the
-        # fused kernel): what `round_slots` is told at staging
+        # GSPMD) or runs every step (shard_map meshes, silo groups): what
+        # `round_slots` is told at staging
         self._live_steps = False
         # vmap lanes the cohort's clients are packed onto (engine.
         # packed_lanes); None: a lane a client, every program but the plain
@@ -225,11 +225,10 @@ class FedAvgAPI(Checkpointable):
                 slots = min(config.client_num_per_round, dataset.client_num)
                 self.aggregator = wrap_codec(
                     self.aggregator, self.codec, slots)
-            self._live_steps = not config.fused_kernel
+            self._live_steps = True
             # the pipelined drive loop stages a fresh device copy of the
             # cohort every round, so its buffers can be donated into the
-            # round; eager callers (bench.py re-feeds one staged cohort)
-            # keep the non-donating default
+            # round; the eager loop keeps the non-donating default
             if config.personalize:
                 # graft-pfl: the personalized twin — same round shape plus
                 # trailing [C, ...] personal adapter rows in/out, staged
@@ -244,7 +243,7 @@ class FedAvgAPI(Checkpointable):
                     donate_data=config.pipeline_depth > 0,
                     collect_stats=True)
             else:
-                if not (config.fused_kernel or config.buffer_size > 0
+                if not (config.buffer_size > 0
                         or config.rounds_per_dispatch > 1):
                     # derived from the federation, never set. The buffered
                     # drive's client step and the superstep drive's K-round

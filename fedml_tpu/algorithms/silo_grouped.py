@@ -31,13 +31,12 @@ custom batching rule, which fires under `jax.vmap`; inside `shard_map`
 the client axis is a mesh axis, not a vmap axis, so the rule never fires
 and there is nothing to group (each device already holds a single silo's
 conv — exactly the "single silo (no vmap)" rung the r4 ladder measured
-SLOWER than vmap-10, docs/cross_silo_ladder.json). bench.py therefore
-gates `BENCH_SILO_THRESHOLD`'s default-on behind `n_chips == 1`, and the
-multi-chip path (`parallel/sharded.py`) composes `shard_map` with the
-standard engine's `build_local_update` instead. The chunked donated-carry
-dispatch (engine.build_chunked_round_runner) is likewise a vmap-engine
-execution shape and disables silo grouping when both are requested
-(bench.py prints the note).
+SLOWER than vmap-10, docs/cross_silo_ladder.json). The multi-chip path
+(`parallel/sharded.py`) therefore composes `shard_map` with the standard
+engine's `build_local_update` instead (silo x shard_map is an EXCLUSIONS
+row of core/spec.py). The chunked donated-carry dispatch
+(engine.build_chunked_round_runner) is likewise a vmap-engine execution
+shape: it has no silo-grouped twin.
 """
 
 from __future__ import annotations
@@ -52,7 +51,6 @@ import optax
 from fedml_tpu.algorithms.engine import (
     LocalResult,
     _merge_variables,
-    build_multi_round_fn_from_update,
     build_round_fn_from_update,
     make_local_optimizer,
 )
@@ -216,11 +214,3 @@ def build_silo_round_fn(trainer, cfg: FedConfig, aggregator) -> Callable:
     stream and metrics contract cannot drift)."""
     return build_round_fn_from_update(
         build_silo_local_update(trainer, cfg), aggregator)
-
-
-def build_silo_multi_round_fn(trainer, cfg: FedConfig, aggregator,
-                              num_rounds: int) -> Callable:
-    """R silo-grouped rounds as one jitted lax.scan — counterpart of
-    engine.build_multi_round_fn (shared scaffold, same in-graph sampling)."""
-    return build_multi_round_fn_from_update(
-        build_silo_local_update(trainer, cfg), cfg, aggregator, num_rounds)

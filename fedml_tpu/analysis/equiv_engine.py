@@ -94,6 +94,10 @@ def _canon_value(v) -> Any:
                 tuple(v.shape[a] for a in v.axis_names))
     if tn in ("PartitionSpec", "NamedSharding", "GSPMDSharding"):
         return (tn, _ADDR_RE.sub("", str(v)))
+    if isinstance(v, float) and v != v:
+        # jax 0.9 hands a literal's value over as a python float: a NaN
+        # (chaos' fill) has to equal itself for two programs to match
+        return ("nan",)
     if isinstance(v, (bool, int, float, complex, str, bytes, type(None))):
         return v
     try:                              # jnp scalars and other array-likes
@@ -315,8 +319,6 @@ def legacy_round_programs(levels: Mapping[str, str], **extra):
     model, dtype, fam_extra = "lr", "float32", {}
     if fam == "silo":
         model, dtype = "resnet20", "bfloat16"
-    elif fam == "fused":
-        model = "cnn"
     elif fam == "superstep":
         fam_extra["client_num_per_round"] = 2
     fam_extra.update(extra)
@@ -334,7 +336,7 @@ def legacy_round_programs(levels: Mapping[str, str], **extra):
     agg_state = jax.eval_shape(agg.init_state, gv)
     mask = jax.ShapeDtypeStruct((2,), jnp.bool_)
 
-    if fam in ("engine", "fused"):
+    if fam == "engine":
         from fedml_tpu.algorithms.engine import build_round_fn
 
         rule = agg
@@ -343,7 +345,7 @@ def legacy_round_programs(levels: Mapping[str, str], **extra):
 
             rule = CodecAggregator(codec, agg, slots=2)
             agg_state = jax.eval_shape(rule.init_state, gv)
-        if levels.get("personalization") == "on" and fam == "engine":
+        if levels.get("personalization") == "on":
             # the personalized hand assembly: thread the trailing
             # [C, ...] personal adapter rows exactly as the runtime
             # drive does (codec x personalization is table-illegal, so
@@ -363,10 +365,9 @@ def legacy_round_programs(levels: Mapping[str, str], **extra):
         fn = build_round_fn(trainer, cfg, rule, donate_data=donate,
                             collect_stats=stats)
         args = (gv, agg_state, x, y, counts, rng)
-        if chaos and fam == "engine":     # fused x chaos is table-illegal
+        if chaos:
             args = args + (mask,)
-        name = "engine.round[fused]" if fam == "fused" else "engine.round"
-        return (RoundProgram(name, fn, args),)
+        return (RoundProgram("engine.round", fn, args),)
 
     if fam == "superstep":
         from fedml_tpu.algorithms.engine import build_superstep_fn

@@ -35,7 +35,8 @@ Rules (HLO-layer rows of core.RULES):
   a uniform mean where this repo's client aggregation is sample-count
   weighted (aggregators.tree_weighted_mean_psum); uniform means silently
   bias toward small clients.
-- `axis-name-mismatch`: lowering raised jax's "unbound axis name" — a
+- `axis-name-mismatch`: lowering raised jax's "unbound axis name" (or,
+  under check_vma on jax 0.9, the bare assertion of `core.pvary`) — a
   collective names a mesh axis the enclosing shard_map does not bind
   (caught at lower time in analyze_program, reported as a finding instead
   of a stack trace).
@@ -632,6 +633,34 @@ def summarize_inventory(inventory: List[Dict]
             per_op, per_op_bytes)
 
 
+def _unbound_axes(jitted, args) -> Tuple[str, ...]:
+    """The axis names a collective of `jitted` uses and its mesh does not
+    bind, () when its lowering fails for another reason. Under shard_map's
+    check_vma jax 0.9 reports such a name as a bare AssertionError from
+    `core.pvary`, ahead of the NameError that says "unbound axis name", and
+    the default traceback filtering drops that frame: lower once more with
+    the filtering off and read the names where the assertion compared them
+    (the collective's `axes` against the mesh's)."""
+    import traceback
+
+    import jax
+
+    was = jax.config.jax_traceback_filtering
+    jax.config.update("jax_traceback_filtering", "off")
+    try:
+        jitted.lower(*args)
+    except AssertionError as e:
+        frames = [f for f, _ in traceback.walk_tb(e.__traceback__)
+                  if f.f_code.co_name == "pvary"]
+        if frames:
+            seen = frames[-1].f_locals
+            bound = getattr(seen.get("cur_mesh"), "axis_names", ())
+            return tuple(a for a in seen.get("axes", ()) if a not in bound)
+    finally:
+        jax.config.update("jax_traceback_filtering", was)
+    return ()
+
+
 def analyze_program(fn, args, target: str, *, num_devices: int,
                     params_bytes: Optional[int] = None,
                     compile: bool = True,
@@ -639,9 +668,9 @@ def analyze_program(fn, args, target: str, *, num_devices: int,
                     ) -> Tuple[Optional[ProgramComms], List[Finding]]:
     """Lower one program, inventory its collectives, run every HLO rule.
 
-    Returns (ProgramComms or None, findings). An "unbound axis name" error
-    at lower time becomes the axis-name-mismatch finding (with no comms —
-    the program never lowered); any other lowering error propagates.
+    Returns (ProgramComms or None, findings). An unbound axis name at
+    lower time becomes the axis-name-mismatch finding (with no comms — the
+    program never lowered); any other lowering error propagates.
 
     `expect_resharding` marks a GSPMD program (automatic partitioning):
     partitioner-inserted post-opt collectives are expected there and the
@@ -655,12 +684,16 @@ def analyze_program(fn, args, target: str, *, num_devices: int,
         lowered = jitted.lower(*args)
         pre_text = lowered.compiler_ir(dialect="hlo").as_hlo_text()
     except Exception as e:  # jax raises NameError, wrapped variously
-        if "unbound axis name" in str(e):
-            return None, [Finding(
-                "axis-name-mismatch", target,
-                f"lowering failed: {e} — a collective names a mesh axis "
-                f"the program's shard_map does not bind")]
-        raise
+        why = str(e)
+        if isinstance(e, AssertionError) and not why:
+            why = ", ".join(f"unbound axis name: {a}"
+                            for a in _unbound_axes(jitted, args))
+        if "unbound axis name" not in why:
+            raise
+        return None, [Finding(
+            "axis-name-mismatch", target,
+            f"lowering failed: {why} — a collective names a mesh axis "
+            f"the program's shard_map does not bind")]
 
     module = parse_hlo_text(pre_text)
     inventory = collective_inventory(module)

@@ -7,8 +7,7 @@ nothing here raises on a violation; callers (CLI, tests) decide severity.
 The recursive walker descends into scan/while/cond/pjit/custom_vmap
 sub-jaxprs but NOT into pallas kernels: flash attention accumulates in f32
 *inside* the kernel by design (bf16 in/out, f32 accumulate is the
-numerically-correct flash formulation), and Mosaic-facing compare casts in
-ops/fused_sgd.py are likewise deliberate. The dtype knob governs what the
+numerically-correct flash formulation). The dtype knob governs what the
 kernel is *fed*, which the surrounding dots cover.
 """
 

@@ -121,7 +121,7 @@ def trace_point(levels: Mapping[str, str]) -> None:
     """Abstractly trace (jax.eval_shape) the round program(s) one legal
     matrix point builds — composed by core/builder.py from the spec point,
     through the same builders the runtime uses, on the lr/f32 example
-    (resnet20/bf16 for silo, cnn for fused). Raises on any structural
+    (resnet20/bf16 for silo). Raises on any structural
     incompatibility the tables failed to declare. The hand-assembled twin
     this delegation replaced lives on in analysis/equiv_engine.py as
     `legacy_round_programs`, the certification baseline --equiv proves the

@@ -448,25 +448,13 @@ def _trace_buffered_programs(trainer, cfg, agg, gv, agg_state, x, y, counts,
 
 def _trace_engine_round(point, ctx) -> None:
     """Trace one declared engine.round point: the base vmap round, or its
-    masked / federated-LoRA / fused-kernel / codec-wrapped twin, per the
+    masked / federated-LoRA / codec-wrapped twin, per the
     point's spec opts."""
     from fedml_tpu.algorithms.engine import build_round_fn
 
     trainer, cfg, agg = ctx["trainer"], ctx["cfg"], ctx["agg"]
     gv, x, y = ctx["gv"], ctx["x"], ctx["y"]
     counts, rng, agg_state = ctx["counts"], ctx["rng"], ctx["agg_state"]
-    if point.opt("fused"):
-        # fused-kernel twin (a --fused_kernel run reaches it): the
-        # CNN_DropOut epoch kernel replacing the vmap round wholesale
-        model = point.opt("model")
-        ftrainer, fshape, f_dtype = _tiny_trainer(model, "float32")
-        fcfg = FedConfig(model=model, batch_size=2, epochs=1,
-                         dtype="float32", fused_kernel=True, grad_clip=10.0)
-        fgv, fx, fy, fcounts, frng = _abstract_round_args(
-            ftrainer, fshape, f_dtype)
-        round_f = build_round_fn(ftrainer, fcfg, agg)
-        jax.eval_shape(round_f, fgv, agg_state, fx, fy, fcounts, frng)
-        return
     if point.opt("pfl"):
         # personalized twin (a --personalize run reaches it): the
         # federated-LoRA round plus trailing [C, ...] personal adapter

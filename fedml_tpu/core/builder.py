@@ -278,8 +278,6 @@ def _trace_model(fam: str) -> Tuple[str, str, Dict[str, Any]]:
     model, dtype, extra = "lr", "float32", {}
     if fam == "silo":
         model, dtype = "resnet20", "bfloat16"
-    elif fam == "fused":
-        model = "cnn"
     elif fam == "superstep":
         extra["client_num_per_round"] = 2
     return model, dtype, extra
@@ -351,15 +349,15 @@ def build_round_program(levels: Mapping[str, str],
     gv, x, y, counts, rng = _abstract_round_args(trainer, shape, in_dtype)
     cohort = x.shape[0]
 
-    if fam in ("engine", "fused"):
+    if fam == "engine":
         from fedml_tpu.algorithms.engine import build_round_fn
 
         rule = wrap_codec(agg, codec, slots=cohort)
         agg_state = jax.eval_shape(rule.init_state, gv)
-        if eff.get("personalization") == "on" and fam == "engine":
+        if eff.get("personalization") == "on":
             # the personalized twin: trailing [C, ...] personal rows in
             # and out of the SAME round shape (codec x personalization
-            # and fused x personalization are table-illegal)
+            # is table-illegal)
             from fedml_tpu.algorithms.engine import build_personal_round_fn
 
             fn = build_personal_round_fn(trainer, cfg, rule,
@@ -375,10 +373,9 @@ def build_round_program(levels: Mapping[str, str],
         fn = build_round_fn(trainer, cfg, rule, donate_data=donate,
                             collect_stats=stats)
         args = (gv, agg_state, x, y, counts, rng)
-        if chaos and fam == "engine":     # fused x chaos is table-illegal
+        if chaos:
             args = args + (jax.ShapeDtypeStruct((cohort,), jnp.bool_),)
-        name = "engine.round[fused]" if fam == "fused" else "engine.round"
-        return (RoundProgram(name, fn, args),)
+        return (RoundProgram("engine.round", fn, args),)
 
     if fam == "superstep":
         from fedml_tpu.algorithms.engine import build_superstep_fn

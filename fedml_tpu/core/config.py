@@ -137,13 +137,6 @@ class FedConfig:
     # aggregated, codec-compressed, and checkpointed. 0 = structurally
     # off (the trainer is never wrapped; legacy programs bit-identical).
     lora_rank: int = 0
-    # Route the vmap engine's epoch through the fused pallas SGD kernel
-    # (ops/fused_sgd.py) — one kernel per epoch instead of per-op XLA
-    # (ROADMAP item 1a). femnist-CNN-shaped models only; CPU runs the
-    # kernel in interpret mode (correctness-honest, no speed claim —
-    # tools/bench_fused.py). Mutually exclusive with tensor_shards /
-    # update_codec / buffer_size.
-    fused_kernel: bool = False
     # Opt-in O(cohort) stateless cohort sampler (Feistel permutation over
     # client ids). Default off: the default path keeps bit-compat with the
     # seeded rng.choice trajectory of fedavg.client_sampling.
@@ -159,7 +152,7 @@ class FedConfig:
     # superstep builder is never invoked; the legacy eager loop runs).
     # Requires the single-chip vmap engine: mutually exclusive with
     # pipeline_depth / buffer_size / tensor_shards / silo_threshold /
-    # fused_kernel / backend="shard_map".
+    # backend="shard_map".
     rounds_per_dispatch: int = 1
     # >0 enables staleness-aware buffered aggregation (FedBuff): client
     # updates are admitted into a device-resident K-row buffer tagged with
@@ -196,7 +189,7 @@ class FedConfig:
 
     def validate(self, **axes: str) -> "FedConfig":
         """Raise ValueError for the first feature-axis exclusion (or
-        fused-kernel requirement) this config violates — a lookup into the
+        value requirement) this config violates — a lookup into the
         ONE compatibility table in core/spec.py (graft-matrix). Keyword
         args overlay non-config axis levels when the caller knows them,
         e.g. ``cfg.validate(chaos="on")``. Returns self so call sites can

@@ -1,9 +1,9 @@
 """graft-matrix: the declarative round-program spec (ROADMAP item 5).
 
 One table for the whole feature matrix. Every cross-cutting feature axis
-(drive backend, silo grouping, tensor sharding, LoRA, the fused kernel,
-buffered aggregation, the round pipeline, the multi-round superstep, the
-update codec, the aggregator rule, chaos masking, ledger stats) is declared
+(drive backend, silo grouping, tensor sharding, LoRA, buffered aggregation,
+the round pipeline, the multi-round superstep, the update codec, the
+aggregator rule, chaos masking, ledger stats, personalization) is declared
 ONCE here — its legal levels, how a level projects onto `FedConfig`, and a
 single centralized compatibility relation (`EXCLUSIONS` + `REQUIREMENTS`).
 `FedConfig.validate()` and the formerly-scattered per-module `ValueError`s
@@ -67,9 +67,6 @@ AXES: Dict[str, Axis] = {a.name: a for a in (
     Axis("lora", ("off", "on"), "off",
          {"off": {"lora_rank": 0}, "on": {"lora_rank": 8}},
          "federate rank-r adapters only (models/lora.py seam)"),
-    Axis("fused", ("off", "on"), "off",
-         {"off": {"fused_kernel": False}, "on": {"fused_kernel": True}},
-         "the pallas fused-SGD epoch kernel replacing the vmap round"),
     Axis("buffer", ("off", "on"), "off",
          {"off": {"buffer_size": 0}, "on": {"buffer_size": 5}},
          "staleness-aware buffered aggregation (FedBuff admit/commit)"),
@@ -112,8 +109,6 @@ _PROJECTIONS: Dict[str, Callable] = {
     "silo": lambda cfg: "on" if cfg.silo_threshold > 0 else "off",
     "tensor": _tensor_level,
     "lora": lambda cfg: "on" if getattr(cfg, "lora_rank", 0) > 0 else "off",
-    "fused": lambda cfg: "on" if getattr(cfg, "fused_kernel", False)
-             else "off",
     "buffer": lambda cfg: "on" if cfg.buffer_size > 0 else "off",
     "pipeline": lambda cfg: "on" if cfg.pipeline_depth > 0 else "off",
     "superstep": lambda cfg: "on" if cfg.rounds_per_dispatch > 1 else "off",
@@ -175,10 +170,10 @@ _SUPERSTEP_REASON = (
     "rounds_per_dispatch (the multi-round superstep) fuses K "
     "rounds into ONE program on the single-chip vmap engine — "
     "there is no per-round host gap left for the pipeline or "
-    "buffer to exploit, and the sharded/silo/fused lowerings "
+    "buffer to exploit, and the sharded/silo lowerings "
     "have no superstep twin; combine it with none of "
     "pipeline_depth / buffer_size / backend='shard_map' / "
-    "tensor_shards / silo_threshold / fused_kernel")
+    "tensor_shards / silo_threshold")
 _TENSOR_REASON = (
     "tensor_shards already places rounds on its own 2D "
     "('clients', 'tensor') mesh — combine it with neither "
@@ -186,14 +181,14 @@ _TENSOR_REASON = (
 _PFL_REASON = (
     "personalize (per-client adapter rows, models/adapter_bank.py) "
     "drives the single-chip vmap engine's eager or pipelined loop — "
-    "the fused/superstep/buffered/shard_map/tensor/silo lowerings "
+    "the superstep/buffered/shard_map/tensor/silo lowerings "
     "have no personal-row seam; drop personalize or the conflicting "
     "setting")
 
 # Order matters: for a config violating several pairs, the FIRST matching
 # exclusion's reason is raised — the order below mirrors the firing order
-# of the scattered checks this table replaced (fedavg.py, then engine.py's
-# fused gate), so existing tracebacks and test matches are unchanged.
+# of the scattered checks this table replaced (fedavg.py), so existing
+# tracebacks and test matches are unchanged.
 EXCLUSIONS: Tuple[Exclusion, ...] = (
     Exclusion("codec", _CODEC_ON, "silo", ("on",),
               "update_codec has no seam in the silo-grouped lowering "
@@ -208,7 +203,6 @@ EXCLUSIONS: Tuple[Exclusion, ...] = (
               _SUPERSTEP_REASON),
     Exclusion("superstep", ("on",), "tensor", _TENSOR_ON, _SUPERSTEP_REASON),
     Exclusion("superstep", ("on",), "silo", ("on",), _SUPERSTEP_REASON),
-    Exclusion("superstep", ("on",), "fused", ("on",), _SUPERSTEP_REASON),
     Exclusion("silo", ("on",), "backend", ("shard_map",),
               "silo_threshold (the single-chip silo-grouped conv path) "
               "and backend='shard_map' are mutually exclusive — the "
@@ -217,46 +211,18 @@ EXCLUSIONS: Tuple[Exclusion, ...] = (
     Exclusion("tensor", _TENSOR_ON, "silo", ("on",), _TENSOR_REASON),
     Exclusion("tensor", _TENSOR_ON, "backend", ("shard_map",),
               _TENSOR_REASON),
-    Exclusion("fused", ("on",), "tensor", _TENSOR_ON,
-              "--fused_kernel is mutually exclusive with --tensor_shards "
-              "(the kernel owns the whole client step)"),
-    Exclusion("fused", ("on",), "codec", _CODEC_ON,
-              "--fused_kernel is mutually exclusive with --update_codec"),
-    Exclusion("fused", ("on",), "buffer", ("on",),
-              "--fused_kernel is mutually exclusive with --buffer_size "
-              "(buffered admission consumes per-client LocalResults)"),
-    Exclusion("fused", ("on",), "lora", ("on",),
-              "--fused_kernel is mutually exclusive with --lora_rank "
-              "(the kernel trains the raw CNN param layout)"),
-    # The two pairs below were SILENT before graft-matrix: FedAvgAPI's
-    # branch dispatch picked the shard_map / silo round and dropped the
-    # fused flag on the floor — the exact bug class the matrix exists to
-    # surface. They are errors now.
-    Exclusion("fused", ("on",), "backend", ("shard_map",),
-              "--fused_kernel drives the single-chip vmap engine — the "
-              "kernel owns the whole client step and has no shard_map "
-              "lowering; drop one of fused_kernel / backend='shard_map'"),
-    Exclusion("fused", ("on",), "silo", ("on",),
-              "--fused_kernel is mutually exclusive with silo_threshold "
-              "(the kernel owns the whole client step; the silo-grouped "
-              "lowering would repack it)"),
-    # Runtime gates lifted into the table (the matrix's trace probes found
-    # them firing deep inside builders/round bodies — now they are also
-    # config-time answers). Reasons verbatim from the runtime raises.
+    # A runtime gate lifted into the table (the matrix's trace probes found
+    # it firing deep inside a builder — now it is also a config-time
+    # answer). Reason verbatim from the runtime raise.
     Exclusion("codec", _CODEC_ON, "tensor", ("shard_step",),
               "--shard_step runs under GSPMD automatic partitioning — the "
               "codec transports are manual shard_map collectives and do "
               "not compose with it. Drop --shard_step (the storage-sharded "
               "tensor round supports codecs) or --update_codec."),
-    Exclusion("fused", ("on",), "chaos", ("on",),
-              "the fused kernel round has no participation/quarantine "
-              "stage — run without chaos faults or cohort padding, or "
-              "drop --fused_kernel"),
     # graft-pfl: the personalized round is a vmap-engine program (eager or
     # pipelined drive) — the other families have no personal-row seam, and
     # the bank scatter rides the per-round RoundRecordLog flush that the
     # superstep/buffered loops restructure.
-    Exclusion("personalization", ("on",), "fused", ("on",), _PFL_REASON),
     Exclusion("personalization", ("on",), "superstep", ("on",),
               _PFL_REASON),
     Exclusion("personalization", ("on",), "buffer", ("on",), _PFL_REASON),
@@ -321,9 +287,8 @@ CONSTRAINTS: Tuple[Constraint, ...] = (
 
 @dataclass(frozen=True)
 class Requirement:
-    """A value constraint that applies when `axis` sits at `level` —
-    e.g. the fused kernel's sgd/epochs/grad_clip demands. `check` takes
-    the FedConfig and returns True when satisfied."""
+    """A value constraint that applies when `axis` sits at `level`.
+    `check` takes the FedConfig and returns True when satisfied."""
 
     axis: str
     level: str
@@ -332,17 +297,6 @@ class Requirement:
 
 
 REQUIREMENTS: Tuple[Requirement, ...] = (
-    Requirement("fused", "on",
-                lambda cfg: (cfg.client_optimizer == "sgd"
-                             and not cfg.momentum and not cfg.wd
-                             and not cfg.fedprox_mu),
-                "the fused kernel implements plain SGD with global-norm "
-                "clip — sgd, momentum 0, wd 0, fedprox_mu 0 required"),
-    Requirement("fused", "on", lambda cfg: cfg.epochs == 1,
-                "the fused kernel runs exactly one local epoch"),
-    Requirement("fused", "on", lambda cfg: cfg.grad_clip is not None,
-                "the fused kernel clips unconditionally (reference "
-                "semantics) — grad_clip must be set"),
     Requirement("personalization", "on", lambda cfg: cfg.lora_rank > 0,
                 "personalize requires lora_rank > 0 — the personal row "
                 "is a rank-r adapter tree (models/adapter_bank.py)"),
@@ -374,8 +328,8 @@ def validate_config(cfg, axes: Optional[Mapping[str, str]] = None) -> None:
     """Raise ValueError (with the table's reason) for the first exclusion
     or requirement `cfg` violates. `axes` overlays non-config axis levels
     (aggregator/chaos/stats) when the caller knows them. This is the ONE
-    compatibility check — FedConfig.validate(), FedAvgAPI.__init__ and
-    engine.build_round_fn's fused gate all delegate here."""
+    compatibility check — FedConfig.validate() and FedAvgAPI.__init__
+    delegate here."""
     levels = axis_levels(cfg)
     if axes:
         levels.update(axes)
@@ -397,7 +351,6 @@ def validate_config(cfg, axes: Optional[Mapping[str, str]] = None) -> None:
 _FAMILY_TRACE_AXES: Dict[str, Tuple[str, ...]] = {
     "engine": ("aggregator", "codec", "lora", "chaos", "stats", "pipeline",
                "personalization"),
-    "fused": ("aggregator", "stats", "pipeline"),
     "superstep": ("aggregator", "codec", "lora", "chaos", "stats"),
     "buffered": ("aggregator", "codec", "lora", "stats", "pipeline"),
     "sharded": ("aggregator", "codec", "lora", "stats"),
@@ -411,8 +364,6 @@ def point_family(levels: Mapping[str, str]) -> str:
     """The round family FedAvgAPI's dispatch picks for this assignment
     (mirrors the branch order in algorithms/fedavg.py — pinned by
     tests/test_matrix.py::test_point_family_mirrors_fedavg_dispatch_order)."""
-    if levels.get("fused") == "on":
-        return "fused"
     if levels.get("superstep") == "on":
         return "superstep"
     if levels.get("buffer") == "on":
@@ -541,9 +492,6 @@ DRIVE_SPECS: Dict[str, DriveSpec] = {s.drive: s for s in (
                                       "pfl"),
                      axes=(("lora", "on"), ("personalization", "on")),
                      opts=(("lora_rank", 8), ("pfl", True))),
-        ProgramPoint("engine.round", ("cnn", "f32", "fedavg", "fused"),
-                     axes=(("fused", "on"),),
-                     opts=(("fused", True), ("model", "cnn"))),
         ProgramPoint("engine.superstep", ("lr", "f32", "fedavg", "k4"),
                      axes=(("superstep", "on"), ("chaos", "on"),
                            ("stats", "on")),

@@ -92,11 +92,6 @@ def add_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
     parser.add_argument("--lora_rank", type=int, default=0,
                         help="LoRA adapter rank; 0 = full fine-tuning "
                              "(trainer never wrapped, legacy programs)")
-    # fused pallas SGD epoch kernel (ops/fused_sgd.py, ROADMAP item 1a)
-    parser.add_argument("--fused_kernel", type=int, default=0,
-                        help="1 = run the local epoch as ONE fused pallas "
-                             "kernel (femnist-CNN shapes; interpret mode "
-                             "on CPU)")
     # multi-round fused dispatch (engine.build_superstep_fn): K rounds per
     # jitted lax.scan program — in-graph cohort gather from a device-resident
     # store, one deferred metrics fetch per chunk. Bit-identical to K eager
@@ -272,7 +267,6 @@ def config_from_args(args) -> FedConfig:
         d.pop("mesh_shape", None)
     d["fast_sampling"] = bool(d.get("fast_sampling", 0))
     d["shard_step"] = bool(d.get("shard_step", 0))
-    d["fused_kernel"] = bool(d.get("fused_kernel", 0))
     # the superstep subsumes the pipeline (there is no per-round host gap
     # left to overlap) — a fused CLI run drops the pipeline default rather
     # than tripping the library's mutual-exclusion check
@@ -281,35 +275,10 @@ def config_from_args(args) -> FedConfig:
     return FedConfig.from_dict(d)
 
 
-def setup_run(args) -> tuple[FedConfig, FederatedDataset, object]:
-    """Seeds + logging + data + model + task trainer (reference main
-    preamble, main_fedavg.py:262-320: trainer chosen by dataset)."""
-    logging.basicConfig(
-        level=logging.INFO,
-        format="%(asctime)s [%(levelname)s] %(name)s: %(message)s",
-    )
-    # persistent XLA compile cache (repo-local, gitignored): repeat CLI runs
-    # of compile-heavy mains (DARTS/GDAS especially) skip recompilation
-    from fedml_tpu.utils.cache import enable_compile_cache
-
-    enable_compile_cache()
-    random.seed(args.seed)
-    np.random.seed(args.seed)
-    cfg = config_from_args(args)
-    extra_load = {}
-    if args.dataset == "mnist":
-        # reference mnist feeds lr a flat 784 vector and CNN_DropOut 28x28
-        # images (standalone main_fedavg.py:318-325) — flatten by model
-        extra_load["flatten"] = args.model in ("lr", "mlp")
-    ds = load_dataset(
-        args.dataset,
-        data_dir=args.data_dir,
-        client_num_in_total=args.client_num_in_total,
-        partition_method=args.partition_method,
-        partition_alpha=args.partition_alpha,
-        seed=args.seed,
-        **extra_load,
-    )
+def build_trainer(args, cfg: FedConfig, ds):
+    """The model and the task trainer these arguments mean for `ds`, of
+    which only `class_num` and `meta` are read (reference main preamble,
+    main_fedavg.py:262-320: trainer chosen by dataset)."""
     model_kwargs = {"dtype": cfg.dtype}
     if args.dataset in ("shakespeare", "fed_shakespeare"):
         model_kwargs["vocab_size"] = 90
@@ -336,5 +305,35 @@ def setup_run(args) -> tuple[FedConfig, FederatedDataset, object]:
     # seam is task-agnostic; --lora_rank 0 returns the trainer unchanged
     from fedml_tpu.models.lora import maybe_wrap_lora
 
-    trainer = maybe_wrap_lora(trainer, cfg)
-    return cfg, ds, trainer
+    return maybe_wrap_lora(trainer, cfg)
+
+
+def setup_run(args) -> tuple[FedConfig, FederatedDataset, object]:
+    """Seeds + logging + data, then `build_trainer` for what was loaded."""
+    logging.basicConfig(
+        level=logging.INFO,
+        format="%(asctime)s [%(levelname)s] %(name)s: %(message)s",
+    )
+    # persistent XLA compile cache (repo-local, gitignored): repeat CLI runs
+    # of compile-heavy mains (DARTS/GDAS especially) skip recompilation
+    from fedml_tpu.utils.cache import enable_compile_cache
+
+    enable_compile_cache()
+    random.seed(args.seed)
+    np.random.seed(args.seed)
+    cfg = config_from_args(args)
+    extra_load = {}
+    if args.dataset == "mnist":
+        # reference mnist feeds lr a flat 784 vector and CNN_DropOut 28x28
+        # images (standalone main_fedavg.py:318-325) — flatten by model
+        extra_load["flatten"] = args.model in ("lr", "mlp")
+    ds = load_dataset(
+        args.dataset,
+        data_dir=args.data_dir,
+        client_num_in_total=args.client_num_in_total,
+        partition_method=args.partition_method,
+        partition_alpha=args.partition_alpha,
+        seed=args.seed,
+        **extra_load,
+    )
+    return cfg, ds, build_trainer(args, cfg, ds)

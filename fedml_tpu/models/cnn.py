@@ -48,9 +48,7 @@ class CNN_DropOut(nn.Module):
 
     output_dim: int = 10
     dtype: Any = jnp.float32
-    # reference rates; module attrs so the fused-kernel A/B can run a
-    # dropout-free twin through the SAME class (the engine's --fused_kernel
-    # gate keys on this module and mirrors these rates into FusedEpochSpec)
+    # reference rates
     drop1: float = 0.25
     drop2: float = 0.5
 

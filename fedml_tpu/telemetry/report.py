@@ -211,13 +211,12 @@ def fold(records: List[Dict[str, Any]]) -> Dict[str, Any]:
 # the serving scheduler, BENCH_CODEC_* record wire-bytes-per-round and a
 # codec-on/off committed-updates/s A/B, BENCH_LORA_* record the
 # adapter-only wire shrink and a lora-rank rounds/s A/B, BENCH_SUPERSTEP_*
-# record a rounds-per-dispatch K-sweep on a shrunk workload, BENCH_FUSED_*
-# record the fused-kernel flagship A/B (cpu_interpret mode off-TPU),
+# record a rounds-per-dispatch K-sweep on a shrunk workload,
 # BENCH_PFL_* record adapter-bank RSS-vs-rows and gather/scatter rows/s at
 # deliberately tiny round counts. All would poison the rounds/s comparison.
 _GATE_SKIP_PREFIXES = ("BENCH_SCALE_", "BENCH_SHARD_", "BENCH_BUFF_",
                        "BENCH_TENANTS_", "BENCH_CODEC_", "BENCH_LORA_",
-                       "BENCH_SUPERSTEP_", "BENCH_FUSED_", "BENCH_PFL_",
+                       "BENCH_SUPERSTEP_", "BENCH_PFL_",
                        # budget pin files are not benches at all; the glob
                        # below can't match them today, but skip by NAME so a
                        # future BENCH_-style rename can't poison the gate

@@ -42,9 +42,8 @@ def hook_monitoring() -> None:
 def enable_compile_cache(min_compile_secs: float = 1.0,
                          cache_dir: str | None = None) -> bool:
     """Turn on jax's persistent compilation cache so that heavy compiles
-    (the ResNet-56 round, DARTS/GDAS graphs, the fused local-SGD kernel) are
-    paid once and every later process — tests, CLIs, bench, chip_smoke —
-    reuses them.
+    (the ResNet-56 round, DARTS/GDAS graphs) are paid once and every later
+    process — tests, CLIs, the benchmark, chip_smoke — reuses them.
 
     Where the cache lives is the caller's to say, from outside:
     `JAX_COMPILATION_CACHE_DIR` is read by jax itself, and when it is set

@@ -39,11 +39,14 @@ def test_every_reference_baseline_has_a_twin():
 
 @pytest.mark.parametrize("name", REFERENCE_BASELINES)
 def test_baseline_config_resolves(name):
-    """Parse + resolve: dataset loads (surrogate), model constructs at the
-    dataset's class_num, config round-trips through FedConfig."""
+    """Parse + resolve: dataset loads (surrogate), the program's own
+    dispatch (`build_trainer`) constructs the model at the dataset's
+    class_num, config round-trips through FedConfig."""
+    import argparse
+
     from fedml_tpu.core.config import FedConfig
     from fedml_tpu.data.registry import load_dataset
-    from fedml_tpu.models.registry import create_model
+    from fedml_tpu.experiments.common import build_trainer
 
     conf = _load(name)
     assert conf["algorithm"] == "fedavg"
@@ -59,11 +62,7 @@ def test_baseline_config_resolves(name):
                       partition_alpha=args.get("partition_alpha", 0.5),
                       **load_kw)
     assert ds.client_num == args["client_num_in_total"]
-    model_name = args["model"]
-    if model_name == "cnn":  # dataset-contextual, as in the reference
-        model_name = {"har": "har_cnn", "har_subject": "har_cnn",
-                      "cifar10": "cnn_cifar"}.get(args["dataset"], "cnn")
-    module = create_model(model_name, output_dim=ds.class_num)
+    module = build_trainer(argparse.Namespace(**args), cfg, ds).module
     v = module.init({"params": jax.random.PRNGKey(0),
                      "dropout": jax.random.PRNGKey(1)},
                     jnp.asarray(ds.train.x[:1, 0]), train=False)

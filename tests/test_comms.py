@@ -233,6 +233,18 @@ def test_axis_name_mismatch_reported_as_finding():
     assert [f.rule for f in findings] == ["axis-name-mismatch"]
     assert "dz" in findings[0].message
 
+    # any other failure of the lowering, a bare assertion too, propagates,
+    # and the look for the name leaves jax's traceback filtering as it was
+    def broken(x):
+        assert x.ndim == 7
+        return x
+
+    was = jax.config.jax_traceback_filtering
+    with pytest.raises(AssertionError):
+        analyze_program(_sharded1d(broken), (_S,), "fix", num_devices=N,
+                        compile=False)
+    assert jax.config.jax_traceback_filtering == was
+
 
 # ------------------------------------------------------- real round programs
 

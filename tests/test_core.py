@@ -1,6 +1,5 @@
 """Unit tests for core contracts: partitioners, packing, pytree ops."""
 
-import jax
 import numpy as np
 import jax.numpy as jnp
 
@@ -70,50 +69,6 @@ def test_tree_weighted_mean_matches_manual():
     w = jnp.array([1.0, 1.0, 2.0])
     out = tree_weighted_mean(stacked, w)
     np.testing.assert_allclose(out["a"], (1 * np.array([1, 2.0]) + 1 * np.array([3, 4.0]) + 2 * np.array([5, 6.0])) / 4)
-
-
-def test_tree_weighted_mean_flat_equals_per_leaf():
-    """The one-matvec aggregation (aggregators.tree_weighted_mean_flat, the
-    r5 latency probe) must equal the per-leaf weighted mean on a mixed-rank
-    tree, including rank-1 leaves and non-f32 dtypes."""
-    from fedml_tpu.algorithms.aggregators import tree_weighted_mean_flat
-
-    rng = np.random.RandomState(3)
-    stacked = {
-        "k": jnp.asarray(rng.rand(6, 4, 3).astype(np.float32)),
-        "b": jnp.asarray(rng.rand(6, 5).astype(np.float32)),
-        "s": jnp.asarray(rng.rand(6).astype(np.float32)),
-        "h": jnp.asarray(rng.rand(6, 2).astype(np.float16)),
-    }
-    w = jnp.asarray(rng.randint(1, 9, 6).astype(np.float32))
-    want = tree_weighted_mean(stacked, w)
-    got = tree_weighted_mean_flat(stacked, w)
-    for k in stacked:
-        assert got[k].dtype == stacked[k].dtype
-        np.testing.assert_allclose(np.asarray(got[k], np.float32),
-                                   np.asarray(want[k], np.float32),
-                                   rtol=2e-3 if k == "h" else 1e-6,
-                                   atol=1e-6)
-
-
-def test_tree_weighted_mean_flat_budget_guard():
-    """The [C, P] f32 staging copy must be refused — at trace time, with an
-    actionable message — when it exceeds the byte budget; the jitted round
-    aborts before any device allocation instead of OOMing opaquely."""
-    import pytest
-
-    from fedml_tpu.algorithms.aggregators import tree_weighted_mean_flat
-
-    stacked = {"a": jnp.ones((4, 8, 8), jnp.float32)}  # stages 4*64*4 = 1 KiB
-    w = jnp.ones(4)
-    # over budget: raises, names the shape and the escape hatches
-    with pytest.raises(ValueError, match=r"flat_agg.*\[4, 64\].*flat_agg_budget"):
-        tree_weighted_mean_flat(stacked, w, byte_budget=1000)
-    with pytest.raises(ValueError, match="flat_agg"):
-        jax.jit(tree_weighted_mean_flat, static_argnums=2)(stacked, w, 1000)
-    # at budget: runs
-    out = tree_weighted_mean_flat(stacked, w, byte_budget=1024)
-    np.testing.assert_allclose(out["a"], np.ones((8, 8)), rtol=1e-6)
 
 
 def test_tree_where_selects():

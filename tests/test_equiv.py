@@ -40,6 +40,10 @@ def test_perturbed_literal_fails():
     assert not equal(ca, cb)
     div = first_divergence(ca, cb)
     assert div and "eqn[" in div
+    # a NaN literal (chaos' fill) is the same literal in both programs
+    nan_a = _canon(lambda x: jnp.where(x > 0, x, jnp.nan), _sds((3,)))
+    nan_b = _canon(lambda x: jnp.where(x > 0, x, jnp.nan), _sds((3,)))
+    assert equal(nan_a, nan_b) and first_divergence(nan_a, nan_b) is None
 
 
 def test_reordered_tree_keys_pass():

@@ -103,52 +103,6 @@ def test_padding_masks_do_not_leak(mnist10):
     assert max(jax.tree.leaves(d)) < 1e-5
 
 
-def test_multi_round_scan_equals_sequential_rounds(mnist10):
-    """build_multi_round_fn with full participation == sequential
-    build_round_fn calls with rng = fold_in(base, round_idx), exactly."""
-    from fedml_tpu.algorithms.aggregators import make_aggregator
-    from fedml_tpu.algorithms.engine import build_multi_round_fn, build_round_fn
-
-    cfg = FedConfig(batch_size=16, epochs=1, lr=0.1,
-                    client_num_in_total=10, client_num_per_round=10)
-    trainer = ClassificationTrainer(create_model("lr", output_dim=10))
-    agg = make_aggregator("fedavg", cfg)
-    base = jax.random.PRNGKey(5)
-    gv = trainer.init(base, jnp.asarray(mnist10.train.x[:1, 0]))
-    x, y, counts = mnist10.train.select(np.arange(10))
-    x, y, counts = jnp.asarray(x), jnp.asarray(y), jnp.asarray(counts)
-
-    seq_fn = build_round_fn(trainer, cfg, agg)
-    gv_seq = gv
-    for r in range(3):
-        gv_seq, _, _ = seq_fn(gv_seq, (), x, y, counts, jax.random.fold_in(base, r))
-
-    multi = build_multi_round_fn(trainer, cfg, agg, 3)
-    gv_scan, _, metrics = multi(gv, (), x, y, counts, base)
-    d = jax.tree.map(lambda a, b: float(jnp.max(jnp.abs(a - b))), gv_seq, gv_scan)
-    assert max(jax.tree.leaves(d)) < 1e-6
-    assert metrics["total"].shape == (3,)  # per-round metric history
-
-
-def test_multi_round_scan_sampling_subset(mnist10):
-    """With k < C the scan path samples k distinct clients per round and
-    still trains (loss falls)."""
-    from fedml_tpu.algorithms.aggregators import make_aggregator
-    from fedml_tpu.algorithms.engine import build_multi_round_fn
-
-    cfg = FedConfig(batch_size=16, epochs=1, lr=0.1,
-                    client_num_in_total=10, client_num_per_round=4)
-    trainer = ClassificationTrainer(create_model("lr", output_dim=10))
-    agg = make_aggregator("fedavg", cfg)
-    base = jax.random.PRNGKey(6)
-    gv = trainer.init(base, jnp.asarray(mnist10.train.x[:1, 0]))
-    x, y, counts = mnist10.train.select(np.arange(10))
-    multi = build_multi_round_fn(trainer, cfg, agg, 8)
-    gv2, _, metrics = multi(gv, (), jnp.asarray(x), jnp.asarray(y), jnp.asarray(counts), base)
-    losses = np.asarray(metrics["loss_sum"]) / np.maximum(np.asarray(metrics["total"]), 1.0)
-    assert losses[-1] < losses[0]
-
-
 @pytest.mark.skipif(
     "xla_backend_optimization_level=0" in os.environ.get("XLA_FLAGS", ""),
     reason="bit-identity holds only at default XLA codegen: the fast "

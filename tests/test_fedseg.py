@@ -181,7 +181,7 @@ def test_fedseg_checkpoint_resume_exact(tmp_path):
 
 def test_fedseg_default_model_honors_config_dtype():
     """FedSegAPI's default DeepLab build must respect config.dtype (the r5
-    silent-f32 lesson: an absent knob means f32 regardless of BENCH_DTYPE)."""
+    silent-f32 lesson: an absent knob means f32)."""
     from fedml_tpu.algorithms.fedseg import FedSegAPI
     from fedml_tpu.core.config import FedConfig
     from fedml_tpu.data.registry import load_dataset

@@ -7,7 +7,7 @@ trace of legal points through the real builders, the spec<->budget-file
 coverage gate (pass + trip), the axis-drift AST rule on fixtures and on
 the repo itself, and the byte-stability of the --update-budgets path.
 
-The full pairwise-cover trace (29 programs, ~12s) runs in ci_smoke.sh's
+The full pairwise-cover trace (31 programs, ~15s) runs in ci_smoke.sh's
 --matrix step; here only vmap-family points are traced so the module adds
 seconds, not minutes, to tier-1."""
 
@@ -94,6 +94,28 @@ def test_illegal_point_is_rejected_by_fedconfig_validate():
         point_config(point).validate(
             **{n: point[n] for n, a in AXES.items() if a.overrides is None})
     assert str(e.value) == reason
+
+
+def test_the_axes_are_the_twelve_and_the_cli_refuses_a_deleted_one():
+    # the `fused` axis went with the kernel it selected (PR 34): the table
+    # declares twelve axes, and the flag is argparse's unknown argument
+    import argparse
+
+    from fedml_tpu.core.spec import REQUIREMENTS
+    from fedml_tpu.experiments.common import add_args
+
+    assert list(AXES) == [
+        "backend", "silo", "tensor", "lora", "buffer", "pipeline",
+        "superstep", "codec", "aggregator", "chaos", "stats",
+        "personalization"]
+    assert len(EXCLUSIONS) == 20
+    assert [(r.axis, r.level) for r in REQUIREMENTS] == [
+        ("personalization", "on")]
+    parser = add_args(argparse.ArgumentParser())
+    parser.parse_args(["--rounds_per_dispatch", "2"])
+    with pytest.raises(SystemExit) as e:
+        parser.parse_args(["--fused_kernel", "1"])
+    assert e.value.code == 2
 
 
 # ----------------------------------------------- illegal-combination proof
@@ -296,9 +318,8 @@ def test_spec_families_cover_every_drive_program():
 
 
 def test_point_family_mirrors_fedavg_dispatch_order():
-    # fused wins over superstep wins over buffer wins over the parallel
-    # backends — the same if/elif ladder FedAvgAPI uses
-    assert point_family(_full(fused="on", superstep="on")) == "fused"
+    # superstep wins over buffer wins over the parallel backends wins over
+    # silo — the same if/elif ladder FedAvgAPI uses
     assert point_family(_full(superstep="on", buffer="on")) == "superstep"
     assert point_family(_full(buffer="on", backend="shard_map")) == "buffered"
     assert point_family(_full(backend="shard_map")) == "sharded"

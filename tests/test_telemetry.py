@@ -517,17 +517,14 @@ def test_newest_bench_skips_shard_schema_by_name(tmp_path):
     assert newest_bench(str(tmp_path)) is None
 
 
-def test_newest_bench_skips_superstep_and_fused_schemas_by_name(tmp_path):
-    """BENCH_SUPERSTEP_* is a K-sweep on a shrunk dispatch-bound workload
-    and BENCH_FUSED_* is the fused-kernel flagship A/B (cpu_interpret mode
-    off-TPU) — neither is a drive-throughput baseline. Both are skipped by
-    NAME even when their arms carry rounds_per_sec numbers; the gate falls
-    through to the real drive bench."""
+def test_newest_bench_skips_superstep_schema_by_name(tmp_path):
+    """BENCH_SUPERSTEP_* is a K-sweep on a shrunk dispatch-bound workload,
+    not a drive-throughput baseline. It is skipped by NAME even when its
+    arms carry rounds_per_sec numbers; the gate falls through to the real
+    drive bench."""
     with open(tmp_path / "BENCH_SUPERSTEP_r99.json", "w") as f:
         json.dump({"parsed": {"rounds_per_sec": 9999.0,
                               "arms": {"0": {"rounds_per_sec": 9999.0}}}}, f)
-    with open(tmp_path / "BENCH_FUSED_r99.json", "w") as f:
-        json.dump({"parsed": {"rounds_per_sec": 9999.0}}, f)
     assert newest_bench(str(tmp_path)) is None
     with open(tmp_path / "BENCH_r02.json", "w") as f:
         json.dump({"parsed": {"rounds_per_sec": 12.5}}, f)

@@ -32,8 +32,6 @@ import time
 
 import numpy as np
 
-os.environ.setdefault("BENCH_DTYPE", "bfloat16")
-
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 

@@ -1,6 +1,6 @@
 """fednas mini-ladder (VERDICT weak #5): decompose the DARTS search rung.
 
-The headline fednas number (bench.py: one federated search round, 4 silos
+The headline fednas number (one federated search round, 4 silos
 x 256 CIFAR) is a single opaque figure. This ladder times the pieces of
 ONE local search step at the same geometry (channels=8, layers=4, batch
 64, 32x32x3), under both f32 and the PR 1 bf16 knob:

@@ -24,8 +24,6 @@ import time
 
 import numpy as np
 
-os.environ.setdefault("BENCH_DTYPE", "bfloat16")
-
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
