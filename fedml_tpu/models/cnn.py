@@ -8,6 +8,18 @@
 
 Inputs are NHWC [b, 28, 28, 1] / [b, 32, 32, 3] — the TPU-native layout
 (channels-last feeds the MXU without transposes).
+
+Which XLA operation CNN_DropOut's convolutions become is decided by the
+``train`` argument it already receives (`ops/matmul_conv.py`). A training step
+runs under the client vmap with per-client weights, where an `nn.Conv` is a
+grouped convolution that holds most of the flagship's device time at a tenth
+of the MXU; there ``conv2d_1`` / ``conv2d_2`` are written as matrix products,
+which the vmap turns into batched `dot_general`s with the client as the batch
+dimension. Eval (``train=False``) runs shared weights over hundreds of rows a
+client: XLA's ordinary convolution is good there and materialised patches
+would not fit, so it stays `lax.conv_general_dilated`, the jaxpr of `nn.Conv`.
+Same parameters, same precision (the context's) either way. The other models
+here are in no benchmark cell and keep `nn.Conv`.
 """
 
 from __future__ import annotations
@@ -16,6 +28,8 @@ from typing import Any
 
 import flax.linen as nn
 import jax.numpy as jnp
+
+from fedml_tpu.ops.matmul_conv import MatmulConv
 
 
 class CNN_OriginalFedAvg(nn.Module):
@@ -55,8 +69,8 @@ class CNN_DropOut(nn.Module):
     @nn.compact
     def __call__(self, x, train: bool = False):
         x = x.astype(self.dtype)
-        x = nn.relu(nn.Conv(32, (3, 3), padding="VALID", dtype=self.dtype, name="conv2d_1")(x))
-        x = nn.relu(nn.Conv(64, (3, 3), padding="VALID", dtype=self.dtype, name="conv2d_2")(x))
+        x = nn.relu(MatmulConv(32, (3, 3), padding="VALID", dtype=self.dtype, name="conv2d_1")(x, as_matmul=train))
+        x = nn.relu(MatmulConv(64, (3, 3), padding="VALID", dtype=self.dtype, name="conv2d_2")(x, as_matmul=train))
         x = nn.max_pool(x, (2, 2), strides=(2, 2))
         x = nn.Dropout(self.drop1, deterministic=not train)(x)
         x = x.reshape((x.shape[0], -1))
