@@ -471,7 +471,9 @@ def build_local_update(trainer, cfg: FedConfig, pvary_axes: tuple = ()) -> Calla
                          (count * 0).astype(jnp.int32)), erngs
         )
         # summed train metrics from the final local epoch (shape [E, nb] -> last epoch)
-        metrics = {k: v[-1].sum() for k, v in auxs.items()}
+        # (over the steps: a metric that is a vector, the tokens every expert
+        # received, stays one)
+        metrics = {k: v[-1].sum(axis=0) for k, v in auxs.items()}
         # federated LoRA (models/lora.py): the frozen base never trains, so
         # it leaves the client update HERE — inside the vmapped function —
         # and the cohort-stacked result tree never materializes C copies of
@@ -583,7 +585,10 @@ def _packed_update(trainer, cfg: FedConfig, lanes: int) -> Callable:
             # a lane that starts a client takes the global model, a fresh
             # optimizer state and no steps; the metrics are an epoch's
             carry = lane_where(active & (j == 0), fresh, carry)
-            sums = jax.tree.map(lambda v: jnp.where(s == 0, 0, v), sums)
+            sums = jax.tree.map(
+                lambda v: jnp.where(
+                    (s == 0).reshape(s.shape + (1,) * (v.ndim - 1)), 0, v),
+                sums)
             carry, aux = step(carry, batch)
             sums = jax.tree.map(jnp.add, sums, aux)
             j = j + active.astype(jnp.int32)
@@ -700,7 +705,8 @@ from fedml_tpu.core.builder import build_round_core as _round_core  # noqa: E402
 
 def build_round_fn_from_update(batched_update, aggregator,
                                donate_data: bool = False,
-                               collect_stats: bool = False) -> Callable:
+                               collect_stats: bool = False,
+                               base_outside: bool = False) -> Callable:
     """Jitted synchronous round over any batched client update (the vmap
     engine below, or the silo-grouped update in algorithms/silo_grouped.py —
     one definition of the rng stream and metrics contract for both).
@@ -727,13 +733,25 @@ def build_round_fn_from_update(batched_update, aggregator,
     that feed the same buffers to more than one round would hit
     deleted-buffer errors. Donation never changes the traced program, only
     buffer aliasing, so donated and undonated rounds are bit-identical.
+
+    `base_outside=True` (a LoRA-wrapped trainer, `build_round_fn`) keeps the
+    frozen base out of the program's OUTPUTS: a jitted function that hands
+    an input back returns a copy of it, which for a base of several GB is a
+    second base on the device every round in flight. The program returns the
+    adapters-only model and the wrapper re-attaches the caller's own base
+    arrays, by reference. A trainer that is not wrapped gets the jitted
+    function itself, as before.
     """
+    from fedml_tpu.models.lora import attach_lora_base, strip_lora_base
+
     core = _round_core(batched_update, aggregator, collect_stats)
 
     def round_fn(global_variables, agg_state, x, y, counts, rng,
                  participation=None):
         new_global, new_state, metrics, stats = core(
             global_variables, agg_state, x, y, counts, rng, participation)
+        if base_outside:
+            new_global = strip_lora_base(new_global)
         if collect_stats:
             return new_global, new_state, metrics, stats
         return new_global, new_state, metrics
@@ -746,7 +764,19 @@ def build_round_fn_from_update(batched_update, aggregator,
                    donate=donate_data)
 
     from fedml_tpu.core.builder import donating_jit, donation_argnums
-    return donating_jit(round_fn, donation_argnums(donate_data=donate_data))
+    jitted = donating_jit(round_fn, donation_argnums(donate_data=donate_data))
+    if not base_outside:
+        return jitted
+
+    def with_base(global_variables, *args):
+        new_global, *rest = jitted(global_variables, *args)
+        return (attach_lora_base(new_global, global_variables), *rest)
+
+    # what a caller may ask of the jitted round
+    for attr in ("jitted", "lower", "_cache_size"):
+        if hasattr(jitted, attr):
+            setattr(with_base, attr, getattr(jitted, attr))
+    return with_base
 
 
 def build_round_fn(trainer, cfg: FedConfig, aggregator,
@@ -804,12 +834,14 @@ def build_round_fn(trainer, cfg: FedConfig, aggregator,
             donate_data=donate_data, collect_stats=collect_stats,
             codec=codec)
     from fedml_tpu.core.builder import wrap_codec
+    from fedml_tpu.models.lora import LoRATrainer
 
     aggregator = wrap_codec(aggregator, codec, slots=cfg.client_num_per_round)
     return build_round_fn_from_update(
         _vmapped_update(trainer, cfg) if lanes is None
         else _packed_update(trainer, cfg, lanes),
-        aggregator, donate_data=donate_data, collect_stats=collect_stats)
+        aggregator, donate_data=donate_data, collect_stats=collect_stats,
+        base_outside=isinstance(trainer, LoRATrainer))
 
 
 def build_personal_round_fn(trainer, cfg: FedConfig, aggregator,
@@ -941,7 +973,7 @@ def build_chunked_round_runner(trainer, cfg: FedConfig, aggregator,
         new_global, agg_state = aggregator(
             global_variables, result, counts.astype(jnp.float32), rng,
             agg_state)
-        return new_global, agg_state, {k: v.sum() for k, v in metrics.items()}
+        return new_global, agg_state, {k: v.sum(axis=0) for k, v in metrics.items()}
 
     init_fn = jax.jit(_init)
     chunk_fn = jax.jit(_chunk, donate_argnums=(0, 1, 2))

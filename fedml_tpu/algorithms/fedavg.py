@@ -114,6 +114,13 @@ def _eval_chunk(n_max: int, num_clients: int) -> int:
     return max(1, min(num_clients, 64, EVAL_STEP_SAMPLES // max(n_max, 1)))
 
 
+def _host_metrics(train_metrics) -> dict:
+    """A round's metric sums fetched in ONE host round trip: scalars as
+    floats; a vector (a routed-expert model's `moe_load`) stays an array."""
+    return {k: float(v) if np.ndim(v) == 0 else v
+            for k, v in jax.device_get(train_metrics).items()}
+
+
 class FedAvgAPI(Checkpointable):
     """Single-controller federated simulator.
 
@@ -363,7 +370,7 @@ class FedAvgAPI(Checkpointable):
         with tracer.span("metrics_fetch", round_idx):
             # ONE host round trip for the whole metrics dict — per-key float()
             # was one blocking transfer per metric
-            return {k: float(v) for k, v in jax.device_get(train_metrics).items()}
+            return _host_metrics(train_metrics)
 
     def train(self, ckpt_dir: str | None = None, ckpt_every: int = 25,
               metrics_logger=None, chaos=None, guard=None,
@@ -549,6 +556,8 @@ class FedAvgAPI(Checkpointable):
                                 record[k] = train_metrics[k]
                     if guard is not None and retries:
                         record["guard_retries"] = retries
+                    if "moe_load" in train_metrics:
+                        record["_moe_load"] = train_metrics["moe_load"]
                     if round_idx % cfg.frequency_of_the_test == 0 or round_idx == cfg.comm_round - 1:
                         record.update(self.evaluate(round_idx, tracer))
                     records.add(record)
@@ -1068,9 +1077,7 @@ class FedAvgAPI(Checkpointable):
                     is_ckpt = bool(ckpt_dir) and (round_idx + 1) % ckpt_every == 0
                     if guard is not None:
                         with tracer.span("metrics_fetch", round_idx):
-                            train_metrics = {
-                                k: float(v)
-                                for k, v in jax.device_get(train_metrics).items()}
+                            train_metrics = _host_metrics(train_metrics)
                         total = max(train_metrics.get("total", 1.0), 1.0)
                         loss = train_metrics.get("loss_sum", 0.0) / total
                         with tracer.span("guard_verdict", round_idx):
@@ -1114,6 +1121,9 @@ class FedAvgAPI(Checkpointable):
                                 record[k] = train_metrics[k]
                     if guard is not None and retries:
                         record["guard_retries"] = retries
+                    if "moe_load" in train_metrics:
+                        # device-resident until the flush's one fetch
+                        record["_moe_load"] = train_metrics["moe_load"]
                     retries = 0
                     if is_test:
                         # eval reads the post-round model, so these dispatches

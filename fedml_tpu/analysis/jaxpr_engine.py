@@ -74,10 +74,19 @@ def _walk_levels(jaxpr):
 def check_dtype_policy(jaxpr, target: str,
                        policy=jnp.bfloat16) -> List[Finding]:
     """No floating matmul/conv may produce a dtype other than `policy`.
-    Integer dots (e.g. turboaggregate's field arithmetic) pass."""
+    Integer dots (e.g. turboaggregate's field arithmetic) pass, and so does
+    a product that states `precision=HIGHEST` itself: the rule is for the
+    SILENTLY float32 matmul, and a float32 product written out with its
+    precision is a decision (an expert router's logits, which the source
+    computes in float32 because the top-k flips on rounding)."""
     out: List[Finding] = []
     for eqn in walk_eqns(jaxpr):
         if eqn.primitive.name not in MATMUL_PRIMS:
+            continue
+        stated = eqn.params.get("precision")
+        if stated is not None and all(
+                p == jax.lax.Precision.HIGHEST for p in (
+                    stated if isinstance(stated, tuple) else (stated,))):
             continue
         dt = eqn.outvars[0].aval.dtype
         if jnp.issubdtype(dt, jnp.floating) and dt != policy:

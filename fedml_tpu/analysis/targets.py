@@ -33,6 +33,31 @@ from fedml_tpu.core.config import FedConfig
 from fedml_tpu.core.trainer import ClassificationTrainer
 from fedml_tpu.models.registry import available_models, create_model
 
+
+
+def tiny_deepseek_v2(**sizes) -> dict:
+    """DeepSeek-V2-Lite's published configuration with every size cut to a
+    CPU test's (hidden 64, 8 experts top-2, 1 dense + 2 expert layers): the
+    `config` a `deepseek_v2` factory call takes. The vocabulary is 10, the
+    `output_dim` every sweep here builds with; `sizes` override."""
+    import json
+
+    from fedml_tpu.models.deepseek_v2 import PUBLISHED
+
+    with open(PUBLISHED) as f:
+        cfg = json.load(f)
+    cfg.update(hidden_size=64, intermediate_size=160,
+               moe_intermediate_size=48, num_hidden_layers=3,
+               num_attention_heads=4, num_key_value_heads=4, kv_lora_rank=32,
+               qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+               n_routed_experts=8, num_experts_per_tok=2, vocab_size=10,
+               max_position_embeddings=256)
+    cfg["rope_scaling"] = dict(cfg["rope_scaling"],
+                               original_max_position_embeddings=16)
+    cfg.update(sizes)
+    return cfg
+
+
 # model name -> (example input shape, input dtype, extra factory kwargs).
 # Every registered model MUST have a row (enforced by tests/test_lint.py and
 # tests/test_dtype_registry.py) — a new factory that drops the dtype knob
@@ -67,6 +92,7 @@ MODEL_EXAMPLES = {
     "rnn": ((2, 16), jnp.int32, {"vocab_size": 90}),
     "rnn_stackoverflow": ((2, 12), jnp.int32, {}),
     "transformer_nwp": ((2, 16), jnp.int32, {}),
+    "deepseek_v2": ((2, 16), jnp.int32, {"config": tiny_deepseek_v2()}),
 }
 
 

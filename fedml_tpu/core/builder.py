@@ -143,7 +143,7 @@ def build_round_core(batched_update, aggregator,
             # the trainer isn't wrapped)
             new_global = attach_lora_base(new_global, global_variables)
             # per-client metric sums -> federation totals
-            metrics = {k: v.sum() for k, v in result.metrics.items()}
+            metrics = {k: v.sum(axis=0) for k, v in result.metrics.items()}
             return new_global, new_state, metrics, stats
         result, weights, alive, quarantined = quarantine_stage(
             result, weights, participation)
@@ -157,7 +157,7 @@ def build_round_core(batched_update, aggregator,
                                 strip_lora_base(global_variables))
         new_state = tree_where(any_alive, new_state, agg_state)
         new_global = attach_lora_base(new_global, global_variables)
-        metrics = {k: v.sum() for k, v in result.metrics.items()}
+        metrics = {k: v.sum(axis=0) for k, v in result.metrics.items()}
         metrics["participated_count"] = alive.sum().astype(jnp.float32)
         metrics["quarantined_count"] = quarantined.sum().astype(jnp.float32)
         return new_global, new_state, metrics, stats
@@ -204,7 +204,7 @@ def build_personal_round_core(batched_update, aggregator,
                 new_global, new_state = aggregator(
                     global_variables, result, weights, rng, agg_state)
             new_global = attach_lora_base(new_global, global_variables)
-            metrics = {k: v.sum() for k, v in result.metrics.items()}
+            metrics = {k: v.sum(axis=0) for k, v in result.metrics.items()}
             return new_global, new_state, metrics, stats, new_personal
         result, weights, alive, quarantined = quarantine_stage(
             result, weights, participation)
@@ -216,7 +216,7 @@ def build_personal_round_core(batched_update, aggregator,
                                 strip_lora_base(global_variables))
         new_state = tree_where(any_alive, new_state, agg_state)
         new_global = attach_lora_base(new_global, global_variables)
-        metrics = {k: v.sum() for k, v in result.metrics.items()}
+        metrics = {k: v.sum(axis=0) for k, v in result.metrics.items()}
         metrics["participated_count"] = alive.sum().astype(jnp.float32)
         metrics["quarantined_count"] = quarantined.sum().astype(jnp.float32)
         new_personal = _keep_dead_rows(new_personal, personal, alive)

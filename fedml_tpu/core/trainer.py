@@ -133,13 +133,32 @@ class NWPTrainer(ModelTrainer):
     Batch ``y`` has shape [b, seq]; logits [b, seq, vocab]. Tokens equal to
     ``pad_id`` are ignored in both loss and accuracy, in addition to the
     per-sample padding mask.
-    """
+
+    A module that brings ``hidden(tokens, train) -> (states, aux)`` and
+    ``head(states) -> logits`` (models/deepseek_v2.py) owns how its loss is
+    computed: the head and the cross-entropy run over blocks of
+    ``loss_block`` tokens, each block rematerialised in the backward pass,
+    so that no [tokens, vocab] float32 array of the whole batch exists (8,192
+    tokens over a 102,400-word vocabulary are 3.4 GB); the same sums to
+    float32 rounding. What the module's ``aux`` holds (the tokens every
+    expert received) rides the step's metrics. Its eval runs ``eval_rows``
+    sequences at a time with loss blocks of ``eval_block`` tokens (the
+    engine vmaps an eval over up to 64 clients, and every lane holds a block
+    of logits of its own). A module without the two (the LSTMs, the toy
+    transformer) takes the whole batch's logits, as before."""
+
+    #: tokens a block of the training loss; sequences an eval step; tokens a
+    #: block of the eval loss (constants: one value each is in use)
+    loss_block, eval_rows, eval_block = 1024, 1, 128
 
     def __init__(self, module, pad_id: int = 0, id: int = 0):
         super().__init__(module, id)
         self.pad_id = pad_id
+        self.blockwise = hasattr(module, "hidden") and hasattr(module, "head")
 
     def _masked_ce(self, variables, batch, rng, train):
+        if self.blockwise:
+            return self._blockwise_ce(variables, batch, train)
         logits, new_state = self.apply(variables, batch["x"], rng, train)
         y = batch["y"]
         per = optax.softmax_cross_entropy_with_integer_labels(logits, y)
@@ -151,12 +170,48 @@ class NWPTrainer(ModelTrainer):
         correct = ((jnp.argmax(logits, -1) == y) * mask).sum()
         return loss, new_state, {"loss_sum": (per * mask).sum(), "correct": correct, "total": mask.sum()}
 
+    def _blockwise_ce(self, variables, batch, train, block=None):
+        """`_masked_ce` of a module that owns its loss: float32 sums over
+        blocks of `block` (default `loss_block`) tokens."""
+        states, extra = self.module.apply(variables, batch["x"], train=train,
+                                          method="hidden")
+        y = batch["y"]
+        mask = ((y != self.pad_id).astype(jnp.float32)
+                * batch["mask"].astype(jnp.float32)[:, None])
+        n = y.size
+        block = min(block or self.loss_block, n)
+        pad = -n % block
+        flat = [jnp.pad(a.reshape((n,) + a.shape[2:]),
+                        [(0, pad)] + [(0, 0)] * (a.ndim - 2))
+                for a in (states, y, mask)]
+        blocks = [a.reshape((-1, block) + a.shape[1:]) for a in flat]
+
+        @jax.checkpoint
+        def one(sums, inp):
+            hb, yb, mb = inp
+            logits = self.module.apply(variables, hb, method="head")
+            per = optax.softmax_cross_entropy_with_integer_labels(logits, yb)
+            hit = (jnp.argmax(logits, -1) == yb).astype(jnp.float32)
+            return (sums[0] + (per.astype(jnp.float32) * mb).sum(),
+                    sums[1] + (hit * mb).sum()), None
+
+        zero = jnp.zeros((), jnp.float32)
+        with jax.named_scope("lm_loss"):
+            (loss_sum, correct), _ = jax.lax.scan(one, (zero, zero), blocks)
+        total = mask.sum()
+        aux = {"loss_sum": loss_sum, "correct": correct, "total": total}
+        aux.update(extra)
+        return loss_sum / jnp.maximum(total, 1.0), {}, aux
+
     def loss_fn(self, variables, batch, rng, train: bool = True):
         loss, new_state, aux = self._masked_ce(variables, batch, rng, train)
         return loss, (new_state, aux)
 
     def eval_fn(self, variables, batch):
-        _, _, aux = self._masked_ce(variables, batch, None, False)
+        if self.blockwise:
+            aux = self._eval_sums(variables, batch)
+        else:
+            _, _, aux = self._masked_ce(variables, batch, None, False)
         # reported-loss contract matches the reference trainer
         # (my_model_trainer_nwp.py:72-80): each batch contributes
         # meanCE-over-non-pad x batch_size, later divided by test_total
@@ -168,6 +223,22 @@ class NWPTrainer(ModelTrainer):
             "test_loss": aux["loss_sum"] / n_tok * n_samples,
             "test_total": aux["total"],
         }
+
+    def _eval_sums(self, variables, batch):
+        """The batch's loss sums, `eval_rows` sequences at a time: an eval
+        batch is as many sequences as the engine packs (64, or a client's
+        whole split under a vmap over clients), and a forward pass over all
+        of them at once is sized for images, not for 1,024-token rows."""
+        rows = batch["y"].shape[0]
+        step = min(self.eval_rows, rows)
+        pad = -rows % step
+        parts = {k: jnp.pad(v, [(0, pad)] + [(0, 0)] * (v.ndim - 1)).reshape(
+            (-1, step) + v.shape[1:]) for k, v in batch.items()}
+        sums = jax.lax.map(
+            lambda part: {k: v for k, v in self._blockwise_ce(
+                variables, part, False, self.eval_block)[2].items()
+                if k in ("loss_sum", "correct", "total")}, parts)
+        return {k: v.sum() for k, v in sums.items()}
 
 
 class TagPredictionTrainer(ModelTrainer):

@@ -532,6 +532,30 @@ def load_stackoverflow_nwp(data_dir="./data", client_num_in_total=200, seed=0, *
     return _from_client_lists("stackoverflow_nwp", xtr, ytr, xte, yte, 10004, task="nwp")
 
 
+@register_loader("tokens")
+def load_tokens(client_num_in_total=20, seed=0, vocab=102400, seq_len=1024,
+                train_sequences=16, test_sequences=2, zipf_a=1.1, **_):
+    """Seeded token silos for next-word prediction, equal shares a silo: ids
+    drawn from a Zipf law over 1..vocab-1 (id 0 is the pad), `y` the next
+    token with the pad last. No text and no tokenizer are in the repository;
+    this is what a decoder's CLI run trains on (the defaults are the sizes
+    of DeepSeek-V2-Lite's benchmark cell; `setup_run` takes `vocab` from
+    `--model_config`)."""
+    rng = np.random.default_rng(seed)
+    p = np.arange(1, vocab, dtype=np.float64) ** -zipf_a
+    p /= p.sum()
+
+    def split(n):
+        x = (rng.choice(len(p), size=(client_num_in_total, n, seq_len), p=p)
+             + 1).astype(np.int32)
+        y = np.concatenate([x[..., 1:], np.zeros_like(x[..., :1])], axis=-1)
+        return list(x), list(y)
+
+    xtr, ytr = split(train_sequences)
+    xte, yte = split(test_sequences)
+    return _from_client_lists("tokens", xtr, ytr, xte, yte, vocab, task="nwp")
+
+
 @register_loader("stackoverflow_lr")
 def load_stackoverflow_lr(data_dir="./data", client_num_in_total=200, seed=0, **_):
     xtr, ytr, xte, yte = sources.load_stackoverflow_lr_clients(data_dir, client_num_in_total, seed)

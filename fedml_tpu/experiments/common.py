@@ -26,6 +26,11 @@ from fedml_tpu.models.registry import create_model
 def add_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
     """Reference add_args (main_fedavg.py:46-112), TPU-adapted."""
     parser.add_argument("--model", type=str, default="lr")
+    parser.add_argument("--model_config", type=str, default=None,
+                        help="JSON of a model's published configuration "
+                             "keys, for a model whose sizes are a file's "
+                             "and no argument's (deepseek_v2; default: the "
+                             "file kept beside the model)")
     parser.add_argument("--dataset", type=str, default="mnist")
     parser.add_argument("--data_dir", type=str, default="./data")
     parser.add_argument("--partition_method", type=str, default="hetero")
@@ -293,7 +298,12 @@ def build_trainer(args, cfg: FedConfig, ds):
             model_name = "har_cnn"
         elif args.dataset == "cifar10":
             model_name = "cnn_cifar"
+    if getattr(args, "model_config", None):
+        model_kwargs["config"] = args.model_config
     module = create_model(model_name, output_dim=ds.class_num, **model_kwargs)
+    if getattr(module, "frozen_base_only", False) and cfg.lora_rank <= 0:
+        raise SystemExit(f"{model_name} trains as a frozen base only: give "
+                         f"--lora_rank > 0")
     # task trainer by dataset (reference FedAvgAPI.py:33-39)
     if ds.meta.get("task") == "nwp" or args.dataset in ("fed_shakespeare", "stackoverflow_nwp"):
         trainer = NWPTrainer(module, pad_id=0)
@@ -327,6 +337,17 @@ def setup_run(args) -> tuple[FedConfig, FederatedDataset, object]:
         # reference mnist feeds lr a flat 784 vector and CNN_DropOut 28x28
         # images (standalone main_fedavg.py:318-325) — flatten by model
         extra_load["flatten"] = args.model in ("lr", "mlp")
+    if args.dataset == "tokens" and getattr(args, "model_config", None):
+        # the token surrogate draws its ids over the model's vocabulary, in
+        # the shapes the file's `data` group gives, where it has one
+        import json
+
+        with open(args.model_config) as f:
+            spec = json.load(f)
+        extra_load["vocab"] = spec["vocab_size"]
+        extra_load.update({k: v for k, v in spec.get("data", {}).items()
+                           if k in ("seq_len", "zipf_a", "train_sequences",
+                                    "test_sequences")})
     ds = load_dataset(
         args.dataset,
         data_dir=args.data_dir,

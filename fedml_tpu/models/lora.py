@@ -11,7 +11,20 @@ puts ONLY the adapters under ``"params"``:
 
 At apply time the effective kernel is ``base + (A @ B) * (alpha / r)`` —
 ``B`` initializes to zeros, so the wrapped model starts bit-identical to
-the unwrapped one. The engine's grad core differentiates ``"params"`` only
+the unwrapped one.
+
+Dtypes: the base is held in whatever dtype the wrapped module creates its
+parameters in (float32 for most of the zoo; bfloat16 for a module that
+creates its weights in its compute dtype, as `models/deepseek_v2.py` does
+under ``--dtype bfloat16``, where a float32 base would not fit the chip).
+The adapters are float32 WHATEVER the base is: a bfloat16 adapter cannot be
+trained by SGD (lr x gradient falls under its rounding). Where the two
+differ, the effective kernel is summed in float32 and rounded to the base's
+dtype once; where they agree it is the sum above, the same program as
+before. 3-D kernels (a routed-expert stack) get no adapter (``ndim == 2``),
+so they are frozen outright. `init` runs under one `jax.jit`: the compiler
+drops the forward pass a flax `init` makes, which for a base of several GB
+is most of its time. The engine's grad core differentiates ``"params"`` only
 (`jax.value_and_grad` over ``variables["params"]``), so the base is frozen
 *by construction*: no optimizer state, no gradient, no update ever touches
 it, and frozen-base bitwise invariance across rounds is a structural
@@ -108,11 +121,13 @@ def init_lora_adapters(base_params, rank: int, rng,
             if (getattr(leaf, "ndim", 0) == 2
                     and jnp.issubdtype(leaf.dtype, jnp.inexact)
                     and re.search(targets, path)):
+                # float32 whatever the base's dtype: SGD cannot move a
+                # bfloat16 adapter (lr x gradient falls under its rounding)
                 d_in, d_out = leaf.shape
-                a = (jax.random.normal(key, (d_in, rank), leaf.dtype)
-                     / jnp.asarray(d_in, leaf.dtype) ** 0.5)
+                a = (jax.random.normal(key, (d_in, rank), jnp.float32)
+                     / jnp.asarray(d_in, jnp.float32) ** 0.5)
                 return {"lora_A": a,
-                        "lora_B": jnp.zeros((rank, d_out), leaf.dtype)}
+                        "lora_B": jnp.zeros((rank, d_out), jnp.float32)}
             return None
         out = {}
         for k in tree:
@@ -146,8 +161,13 @@ def merge_lora_params(base_params, adapters, scale: float):
 
     def walk(base, adapt):
         if not isinstance(base, Mapping):
-            delta = (adapt["lora_A"] @ adapt["lora_B"]).astype(base.dtype)
-            return base + delta * jnp.asarray(scale, base.dtype)
+            delta = adapt["lora_A"] @ adapt["lora_B"]
+            if base.dtype == delta.dtype:
+                return base + delta * jnp.asarray(scale, base.dtype)
+            # a base narrower than its adapters: summed in the adapters'
+            # float32 and rounded to the base's dtype once
+            return (base.astype(delta.dtype)
+                    + delta * jnp.asarray(scale, delta.dtype)).astype(base.dtype)
         out = {}
         for k in base:
             if isinstance(adapt, Mapping) and k in adapt:
@@ -192,7 +212,9 @@ class LoRATrainer:
 
     # --- pure functional surface -------------------------------------------
     def init(self, rng, example_input):
-        base = _as_dict(self.inner.init(rng, example_input))
+        # jitted: the compiler drops the forward pass a flax `init` runs, and
+        # the base comes out in the module's parameter dtype in one program
+        base = _as_dict(jax.jit(self.inner.init)(rng, example_input))
         base_params = base.pop("params")
         adapters = init_lora_adapters(
             base_params, self.rank, jax.random.fold_in(rng, 0x10A),

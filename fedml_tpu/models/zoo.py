@@ -152,6 +152,25 @@ def _transformer_nwp(output_dim, **kw):
                          dtype=_compute_dtype(kw))
 
 
+@register_model("deepseek_v2")
+def _deepseek_v2(output_dim, **kw):
+    # DeepSeek-V2 decoder (latent attention, routed + shared experts). Its
+    # sizes are a configuration of the published keys, never arguments:
+    # `config` is that dict or the path of a JSON that holds it
+    # (--model_config); None is DeepSeek-V2-Lite as published
+    from fedml_tpu.models.deepseek_v2 import DeepseekV2Config, DeepseekV2LM
+
+    config = kw.get("config")
+    cfg = (DeepseekV2Config.from_dict(config) if isinstance(config, dict)
+           else DeepseekV2Config.from_file(config))
+    if output_dim != cfg.vocab_size:
+        raise ValueError(f"the data has {output_dim} token ids, the model "
+                         f"configuration a vocabulary of {cfg.vocab_size}")
+    import jax.numpy as jnp
+
+    return DeepseekV2LM(cfg, dtype=_compute_dtype(kw) or jnp.float32)
+
+
 @register_model("mobilenet_v3")
 def _mobilenet_v3(output_dim, **kw):
     # reference main_fedavg.py "mobilenet_v3" -> MobileNetV3(model_mode=...)

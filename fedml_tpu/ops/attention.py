@@ -68,10 +68,12 @@ def _masked_scores(qb, kb, qi, ki, q_block, k_block, scale, causal, precision):
     return s
 
 
-def attention_reference(q, k, v, causal: bool = False):
-    """Plain-jnp scaled dot-product attention. q/k/v: [B, T, H, D]."""
+def attention_reference(q, k, v, causal: bool = False, scale=None):
+    """Plain-jnp scaled dot-product attention. q/k: [B, T, H, D], v:
+    [B, T, H, Dv]; `scale` as `flash_attention`'s."""
     d = q.shape[-1]
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / np.sqrt(d)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+    s = s / np.sqrt(d) if scale is None else s * scale
     if causal:
         tq, tk = q.shape[1], k.shape[1]
         mask = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]
@@ -125,12 +127,17 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_scr, m_scr,
         lse_ref[:] = (m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-30)))[:, None]
 
 
+def _softmax_scale(scale, d: int) -> float:
+    """The factor on q k^T: the caller's, or d^-0.5 of the q/k width."""
+    return 1.0 / np.sqrt(d) if scale is None else float(scale)
+
+
 def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
-               interpret: bool, return_lse: bool = False):
+               interpret: bool, return_lse: bool = False, scale=None):
     from jax.experimental import pallas as pl
 
     b, tq, h, d = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[-1]
     block_q = min(block_q, tq)
     block_k = min(block_k, tk)
     if tq % block_q or tk % block_k:
@@ -139,7 +146,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
     # [B, T, H, D] -> [B*H, T, D] program-major layout
     qr = q.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
     kr = k.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
-    vr = v.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
+    vr = v.transpose(0, 2, 1, 3).reshape(b * h, tk, dv)
     # f32 inputs get true-f32 MXU passes (measured: the kernel then matches
     # a HIGHEST-precision dense reference to ~1e-6 while XLA's default-
     # precision einsum drifts ~1e-2); bf16 inputs keep native MXU speed
@@ -149,7 +156,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
     kernel = functools.partial(
         _flash_fwd_kernel, causal=causal, n_kb=n_kb,
         q_block=block_q, k_block=block_k,
-        scale=1.0 / np.sqrt(d), precision=precision)
+        scale=_softmax_scale(scale, d), precision=precision)
     from jax.experimental.pallas import tpu as pltpu
 
     out, lse = pl.pallas_call(
@@ -158,41 +165,46 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda g, i, j: (g, i, 0)),
             pl.BlockSpec((None, block_k, d), lambda g, i, j: (g, j, 0)),
-            pl.BlockSpec((None, block_k, d), lambda g, i, j: (g, j, 0)),
+            pl.BlockSpec((None, block_k, dv), lambda g, i, j: (g, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda g, i, j: (g, i, 0)),
+            pl.BlockSpec((None, block_q, dv), lambda g, i, j: (g, i, 0)),
             # trailing unit lane dim: Mosaic requires the block's last two
             # dims be (8,128)-divisible or equal to the array's
             pl.BlockSpec((None, block_q, 1), lambda g, i, j: (g, i, 0)),
         ],
         out_shape=[
-            _out_struct((b * h, tq, d), q.dtype, qr, kr, vr),
+            _out_struct((b * h, tq, dv), q.dtype, qr, kr, vr),
             _out_struct((b * h, tq, 1), jnp.float32, qr, kr, vr),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qr, kr, vr)
-    out4 = out.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
+    out4 = out.reshape(b, h, tq, dv).transpose(0, 2, 1, 3)
     if return_lse:
         return out4, lse[..., 0]
     return out4
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = False, block_q: int = 128,
-                    block_k: int = 128, interpret: bool | None = None):
-    """Flash attention, pallas kernels both ways. q/k/v: [B, T, H, D].
+                    block_k: int = 128, interpret: bool | None = None,
+                    scale: float | None = None):
+    """Flash attention, pallas kernels both ways. q/k: [B, T, H, D], v:
+    [B, T, H, Dv]; the output has v's width. Dv may differ from D (latent
+    attention trains with 192-wide q/k and 128-wide v): v is never padded to
+    D. `scale` multiplies q k^T; None is D^-0.5.
 
     `interpret=None` auto-selects: compiled on TPU, interpret mode elsewhere
     (the CPU CI path). The backward is BLOCKED too (p recomputed per tile
     from the saved logsumexp) — O(T) memory for training as well."""
     return _flash_fwd(q, k, v, causal, block_q, block_k,
-                      _resolve_interpret(interpret))
+                      _resolve_interpret(interpret), scale=scale)
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
@@ -267,20 +279,21 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
+def _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k, interpret,
+               scale=None):
     """Blocked backward: dq/dk/dv without materializing [T, T]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, tq, h, d = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[-1]
     block_q = min(block_q, tq)
     block_k = min(block_k, tk)
     qr = q.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
     kr = k.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
-    vr = v.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
-    orr = out.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
-    gr = g.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
+    vr = v.transpose(0, 2, 1, 3).reshape(b * h, tk, dv)
+    orr = out.transpose(0, 2, 1, 3).reshape(b * h, tq, dv)
+    gr = g.transpose(0, 2, 1, 3).reshape(b * h, tq, dv)
     # delta_i = rowsum(dO * O) — the softmax-jacobian diagonal term.
     # lse/delta ride as [B*H, Tq, 1] (unit lane dim for Mosaic block rules)
     delta = jnp.sum(gr.astype(jnp.float32) * orr.astype(jnp.float32),
@@ -288,7 +301,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
     lse3 = lse[..., None]
     precision = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
                  else jax.lax.Precision.DEFAULT)
-    scale = 1.0 / np.sqrt(d)
+    scale = _softmax_scale(scale, d)
     n_qb, n_kb = tq // block_q, tk // block_k
     bwd_in = (qr, kr, vr, gr, lse3, delta)
 
@@ -300,8 +313,8 @@ def _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda g_, i, j: (g_, i, 0)),
             pl.BlockSpec((None, block_k, d), lambda g_, i, j: (g_, j, 0)),
-            pl.BlockSpec((None, block_k, d), lambda g_, i, j: (g_, j, 0)),
-            pl.BlockSpec((None, block_q, d), lambda g_, i, j: (g_, i, 0)),
+            pl.BlockSpec((None, block_k, dv), lambda g_, i, j: (g_, j, 0)),
+            pl.BlockSpec((None, block_q, dv), lambda g_, i, j: (g_, i, 0)),
             pl.BlockSpec((None, block_q, 1), lambda g_, i, j: (g_, i, 0)),
             pl.BlockSpec((None, block_q, 1), lambda g_, i, j: (g_, i, 0)),
         ],
@@ -309,9 +322,10 @@ def _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
         out_shape=_out_struct((b * h, tq, d), q.dtype, *bwd_in),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dq",
     )(*bwd_in)
 
-    dk, dv = pl.pallas_call(
+    dk, dv_ = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, causal=causal, n_qb=n_qb,
                           q_block=block_q, k_block=block_k, scale=scale,
                           precision=precision),
@@ -319,40 +333,42 @@ def _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda g_, j, i: (g_, i, 0)),
             pl.BlockSpec((None, block_k, d), lambda g_, j, i: (g_, j, 0)),
-            pl.BlockSpec((None, block_k, d), lambda g_, j, i: (g_, j, 0)),
-            pl.BlockSpec((None, block_q, d), lambda g_, j, i: (g_, i, 0)),
+            pl.BlockSpec((None, block_k, dv), lambda g_, j, i: (g_, j, 0)),
+            pl.BlockSpec((None, block_q, dv), lambda g_, j, i: (g_, i, 0)),
             pl.BlockSpec((None, block_q, 1), lambda g_, j, i: (g_, i, 0)),
             pl.BlockSpec((None, block_q, 1), lambda g_, j, i: (g_, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, block_k, d), lambda g_, j, i: (g_, j, 0)),
-            pl.BlockSpec((None, block_k, d), lambda g_, j, i: (g_, j, 0)),
+            pl.BlockSpec((None, block_k, dv), lambda g_, j, i: (g_, j, 0)),
         ],
         out_shape=[
             _out_struct((b * h, tk, d), k.dtype, *bwd_in),
-            _out_struct((b * h, tk, d), v.dtype, *bwd_in),
+            _out_struct((b * h, tk, dv), v.dtype, *bwd_in),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, dv), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dkv",
     )(*bwd_in)
 
     def back4(t, tlen):
-        return t.reshape(b, h, tlen, d).transpose(0, 2, 1, 3)
+        return t.reshape(b, h, tlen, t.shape[-1]).transpose(0, 2, 1, 3)
 
-    return back4(dq, tq), back4(dk, tk), back4(dv, tk)
+    return back4(dq, tq), back4(dk, tk), back4(dv_, tk)
 
 
-def _fa_fwd(q, k, v, causal, block_q, block_k, interpret):
+def _fa_fwd(q, k, v, causal, block_q, block_k, interpret, scale):
     out, lse = _flash_fwd(q, k, v, causal, block_q, block_k,
-                          _resolve_interpret(interpret), return_lse=True)
+                          _resolve_interpret(interpret), return_lse=True,
+                          scale=scale)
     return out, (q, k, v, out, lse)
 
 
-def _fa_bwd(causal, block_q, block_k, interpret, res, g):
+def _fa_bwd(causal, block_q, block_k, interpret, scale, res, g):
     q, k, v, out, lse = res
     return _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k,
-                      _resolve_interpret(interpret))
+                      _resolve_interpret(interpret), scale)
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)

@@ -36,6 +36,17 @@ _LEDGER_KEYS = ("participated_count", "quarantined_count", "guard_retries",
                 "chaos_dropped", "chaos_nan", "chaos_corrupt")
 
 
+def moe_load_summary(load) -> Dict[str, float]:
+    """The `moe_load` event's fields from a round's [expert layers, experts]
+    counts of the tokens every routed expert received (summed over steps and
+    lanes): the busiest expert's, the mean, and how many got none."""
+    import numpy as np
+
+    load = np.asarray(load, np.float64)
+    return {"max": float(load.max()), "mean": float(load.mean()),
+            "empty": int((load == 0).sum())}
+
+
 def _scalar(v: Any) -> Any:
     """Device/numpy scalars -> python floats; host ints/strs unchanged."""
     return float(v) if hasattr(v, "dtype") else v
@@ -93,6 +104,13 @@ class RoundRecordLog:
                                       blocks=len(bank_blocks)):
                     for block in bank_blocks:
                         self.bank.apply(block)
+            # the reserved _moe_load key carries a routed-expert model's
+            # per-expert token counts (a vector: history takes scalars); it
+            # rides the same fetch and leaves as a `moe_load` event
+            load = rec.pop("_moe_load", None)
+            if load is not None:
+                self.tracer.event("moe_load", round=rec["round"],
+                                  **moe_load_summary(load))
             rec = {k: _scalar(v) for k, v in rec.items()}
             self.history.append(rec)
             if self.metrics_logger is not None:

@@ -67,6 +67,10 @@ EVENT_SCHEMAS: Dict[str, set] = {
     "guard_exhausted": {"round"},
     # unified record path (telemetry/records.py): the history record landed
     "round_committed": {"round"},
+    # the same path, for a model with routed experts: the tokens its experts
+    # received in the round, over every (layer, expert): the busiest one's,
+    # the mean and the number that got none
+    "moe_load": {"round", "max", "mean", "empty"},
     # superstep drive (algorithms/fedavg.py): one fused K-round dispatch
     # committed — `round` is the chunk's first round, `rounds` how many it
     # fused (k_eff after cadence clamping), `k` the configured ceiling

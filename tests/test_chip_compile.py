@@ -90,6 +90,50 @@ def test_flash_attention_compiles(one_chip, no_compile_cache, shape, dtype,
     _fits(compiled)
 
 
+def test_flash_attention_compiles_at_latent_attention_widths(
+        one_chip, no_compile_cache):
+    """q/k 192 wide, v 128 wide, an explicit scale: the three kernels as
+    DeepSeek-V2-Lite's training step calls them (4 sequences x 16 heads,
+    T 1024, bfloat16)."""
+    from fedml_tpu.ops.attention import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, True, 128, 128, False,
+                                       0.1147).astype(jnp.float32))
+
+    qk = jax.ShapeDtypeStruct((4, 1024, 16, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((4, 1024, 16, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(qk, qk, v).compile()
+    text = compiled.as_text()
+    assert all(f"flash_attention_{k}" in text for k in ("fwd", "dq", "dkv"))
+    _fits(compiled)
+
+
+@pytest.mark.parametrize("trans_rhs", [False, True])
+def test_grouped_matmul_compiles_at_the_cells_widths(one_chip,
+                                                     no_compile_cache,
+                                                     trans_rhs):
+    """`ops/moe.py`'s grouped product at DeepSeek-V2-Lite's widths: 64
+    experts of 2048 x 1408 bfloat16, both lanes' 2 x 4096 x 6 pairs in 448
+    tiles of 128 rows, one expert's whole matrix a block (11.5 MB of VMEM
+    double-buffered)."""
+    from fedml_tpu.ops.moe import grouped_matmul
+
+    rows, tiles = 57344, 448
+    width = 1408 if trans_rhs else 2048
+    args = (jax.ShapeDtypeStruct((rows, width), jnp.bfloat16),
+            jax.ShapeDtypeStruct((64, 2048, 1408), jnp.bfloat16),
+            jax.ShapeDtypeStruct((tiles,), jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.int32))
+    compiled = jax.jit(lambda lhs, rhs, grp, nt: grouped_matmul(
+        lhs, rhs, grp, nt, trans_rhs=trans_rhs, interpret=False)).lower(
+            *_on(one_chip, args)).compile()
+    assert "moe_grouped_matmul" in compiled.as_text()
+    _fits(compiled)
+
+
 def _round_program(one_chip, model, output_dim, sample_shape, clients,
                    samples, lanes=None, **cfg_kw):
     """The round program `FedAvgAPI` builds for the CLI's default drive
@@ -168,3 +212,46 @@ def test_cross_silo_round_compiles(one_chip, no_compile_cache):
         one_chip, "resnet56", 10, (32, 32, 3), clients=10, samples=500,
         batch_size=64, dtype="bfloat16")
     _fits(compiled)
+
+
+@pytest.mark.slow  # ~60 s of TPU compile
+def test_dsv2lite_lora_round_compiles_and_never_returns_its_base(
+        one_chip, no_compile_cache):
+    """engine.round for `benchmarks/configs/dsv2lite_lora.json` (5 layers of
+    DeepSeek-V2-Lite, bfloat16 base, rank-16 adapters, 2 lanes x 4 x 1,024
+    tokens a step): fits beside its 5.7 GB base, and its outputs hold no
+    base (a second one would not fit with two rounds in flight)."""
+    from fedml_tpu.algorithms.aggregators import make_aggregator
+    from fedml_tpu.algorithms.engine import build_round_fn
+    from fedml_tpu.core.config import FedConfig
+    from fedml_tpu.core.trainer import NWPTrainer
+    from fedml_tpu.models.lora import LoRATrainer
+    from fedml_tpu.models.registry import create_model
+    from fedml_tpu.ops import attention, moe
+
+    cfg = FedConfig(model="deepseek_v2", client_num_in_total=20,
+                    client_num_per_round=2, epochs=1, batch_size=4, lr=0.03,
+                    lora_rank=16, dtype="bfloat16")
+    trainer = LoRATrainer(NWPTrainer(create_model(
+        "deepseek_v2", output_dim=102400, dtype="bfloat16",
+        config="benchmarks/configs/dsv2lite_lora.json")), rank=16)
+    agg = make_aggregator("fedavg", cfg)
+    gv = jax.eval_shape(lambda: trainer.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 1024), jnp.int32)))
+    tokens = jax.ShapeDtypeStruct((2, 16, 1024), jnp.int32)
+    args = _on(one_chip, (
+        gv, jax.eval_shape(agg.init_state, gv), tokens, tokens,
+        jax.ShapeDtypeStruct((2,), jnp.int32),
+        jax.eval_shape(lambda: jax.random.PRNGKey(0))))
+    # the test steers the kernels' CPU branch (the guide's section 2)
+    was = moe.interpret_off_chip, attention.interpret_off_chip
+    moe.interpret_off_chip = attention.interpret_off_chip = lambda k: False
+    try:
+        compiled = build_round_fn(
+            trainer, cfg, agg, donate_data=True,
+            collect_stats=True).jitted.lower(*args).compile()
+    finally:
+        moe.interpret_off_chip, attention.interpret_off_chip = was
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 5.6e9 > 1e8 > mem.output_size_in_bytes
+    assert _fits(compiled) < 10e9

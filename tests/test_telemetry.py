@@ -101,6 +101,7 @@ _SAMPLE_EVENTS = {
     "guard_rollback": dict(round=1, retry=1),
     "guard_exhausted": dict(round=2),
     "round_committed": dict(round=0, participated_count=6.0),
+    "moe_load": dict(round=0, max=431.0, mean=384.0, empty=0),
     "superstep_committed": dict(round=4, rounds=4, k=4),
     "checkpoint_save": dict(step=5),
     "mqtt_reconnect": dict(client_id="c0", ok=True, attempts=2),
