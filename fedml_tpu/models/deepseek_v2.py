@@ -243,8 +243,7 @@ class MLA(nn.Module):
         q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope, (b, t, h, rope))], axis=-1)
-        blk = next(bb for bb in (128, 64, 32, 16, 8, 4, 2, 1) if t % bb == 0)
-        o = flash_attention(q, k, v, True, blk, blk, None, softmax_scale(c))
+        o = flash_attention(q, k, v, True, scale=softmax_scale(c))
         return _dense(c.hidden_size, self.dtype, "o_proj")(
             o.reshape(b, t, h * vd))
 

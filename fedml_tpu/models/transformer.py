@@ -40,10 +40,7 @@ class _Block(nn.Module):
         qkv = constrain(qkv, "attn_qkv")
         q, k, v = jnp.split(qkv.reshape(b, t, 3 * self.heads, hd),
                             3, axis=2)  # each [B, T, H, hd]
-        # flash kernel wants block-divisible T: pick the largest power-of-two
-        # divisor of T up to 128 (any T works; odd T degenerates to blk=1)
-        blk = next(bb for bb in (128, 64, 32, 16, 8, 4, 2, 1) if t % bb == 0)
-        attn = flash_attention(q, k, v, True, blk, blk)
+        attn = flash_attention(q, k, v, True)
         attn = constrain(attn.reshape(b, t, dm), "attn_ctx")
         x = x + nn.Dense(dm, use_bias=False, dtype=self.dtype, name="proj")(attn)
         h = nn.LayerNorm(dtype=self.dtype, name="ln2")(x)

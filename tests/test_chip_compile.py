@@ -73,12 +73,13 @@ def _fits(compiled) -> float:
     ((2, 2048, 8, 64), jnp.float32),
     ((2, 8192, 8, 64), jnp.bfloat16),
 ], ids=["T2048-f32", "T8192-bf16"])
+@pytest.mark.parametrize("block", [128, None], ids=["128x128", "own-tiles"])
 def test_flash_attention_compiles(one_chip, no_compile_cache, shape, dtype,
-                                  direction):
+                                  direction, block):
     from fedml_tpu.ops.attention import flash_attention
 
     def fwd(q, k, v):
-        return flash_attention(q, k, v, True, 128, 128, False)
+        return flash_attention(q, k, v, True, block, block, False)
 
     def loss(q, k, v):
         return jnp.sum(fwd(q, k, v).astype(jnp.float32))
@@ -90,15 +91,18 @@ def test_flash_attention_compiles(one_chip, no_compile_cache, shape, dtype,
     _fits(compiled)
 
 
+@pytest.mark.parametrize("block", [128, None], ids=["128x128", "own-tiles"])
 def test_flash_attention_compiles_at_latent_attention_widths(
-        one_chip, no_compile_cache):
+        one_chip, no_compile_cache, block):
     """q/k 192 wide, v 128 wide, an explicit scale: the three kernels as
     DeepSeek-V2-Lite's training step calls them (4 sequences x 16 heads,
-    T 1024, bfloat16)."""
+    T 1024, bfloat16), on explicit tiles and on the kernel's own choice
+    (one 1024 x 1024 tile a head), which is what the model runs. The
+    benchmark's roofline reader finds the kernels by these names."""
     from fedml_tpu.ops.attention import flash_attention
 
     def loss(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, True, 128, 128, False,
+        return jnp.sum(flash_attention(q, k, v, True, block, block, False,
                                        0.1147).astype(jnp.float32))
 
     qk = jax.ShapeDtypeStruct((4, 1024, 16, 192), jnp.bfloat16,
