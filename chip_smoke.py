@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """chip_smoke.py — the quickest proof that fedml_tpu still starts on the chip.
 
-    python chip_smoke.py              one chip: flagship, cross_silo, kernel
+    python chip_smoke.py              one chip: flagship, cross_silo, kernel, kda
     python chip_smoke.py --multichip  four chips: the two mesh paths, each
                                       against the one-chip vmap engine,
                                       and nothing else
@@ -265,6 +265,56 @@ def kernel_phase(shape: tuple[int, int, int, int] = (2, 2048, 8, 64),
             "peak_bytes_in_use": peak_bytes()}
 
 
+def kda_phase(shape: tuple[int, int, int, int] = (2, 512, 4, 128),
+              interpret: bool = False, seed: int = 0) -> dict:
+    """The Pallas KDA kernels (`ops/kda.py`), forward and jax.grad, against
+    the token-by-token recurrence (`kda_reference`, float32 at `highest`) at
+    a small shape: four chunks of 128 a head. Both sides are float32, so what
+    is left is the order of the sums: 2e-5 absolute on outputs of magnitude
+    ~0.1, and 1e-4 of a gradient's largest entry. A wrong decay, mask or
+    chunk state moves either by a tenth or more."""
+    import jax.numpy as jnp
+
+    from fedml_tpu.ops.kda import kda, kda_reference
+
+    fwd_tol, grad_tol = 2e-5, 1e-4
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q, k, v, cot = (jax.nn.silu(jax.random.normal(ks[i], shape))
+                    for i in range(4))
+    g = -4.0 * jax.nn.softplus(jax.random.normal(ks[4], shape) - 3.0)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[5], shape[:-1]))
+    args = (q, k, v, g, beta)
+    fwd = jax.jit(lambda *a: kda(*a, interpret=interpret))
+    grad = jax.jit(jax.grad(
+        lambda *a: jnp.sum(kda(*a, interpret=interpret) * cot),
+        argnums=(0, 1, 2, 3, 4)))
+    custom_calls = {"fwd": fwd.lower(*args).as_text().count("tpu_custom_call"),
+                    "grad": grad.lower(*args).as_text().count(
+                        "tpu_custom_call")}
+    if not interpret and not all(custom_calls.values()):
+        raise AssertionError(f"kda: no tpu_custom_call in the lowered text: "
+                             f"{custom_calls}")
+    t0 = time.perf_counter()
+    out = jax.block_until_ready(fwd(*args))
+    grads = jax.block_until_ready(grad(*args))
+    first_call_s = time.perf_counter() - t0
+    ref = kda_reference(*args)
+    ref_grads = jax.grad(lambda *a: jnp.sum(kda_reference(*a) * cot),
+                         argnums=(0, 1, 2, 3, 4))(*args)
+    fwd_err = float(jnp.max(jnp.abs(out - ref)))
+    grad_err = max(float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+                   for a, b in zip(grads, ref_grads))
+    if out.shape != shape or not (fwd_err < fwd_tol and grad_err < grad_tol):
+        raise AssertionError(
+            f"kda: shape {out.shape}, max|out-ref| {fwd_err} (tol {fwd_tol}), "
+            f"max|grad-ref| / max|ref| {grad_err} (tol {grad_tol})")
+    return {"phase": "kda", "ok": True, "shape": list(shape),
+            "interpret": interpret, "tpu_custom_calls": custom_calls,
+            "fwd_max_abs_err": fwd_err, "grad_max_abs_err": grad_err,
+            "smoke_timing": {"compile_and_first_call_s": first_call_s},
+            "peak_bytes_in_use": peak_bytes()}
+
+
 def max_abs_diff(a, b) -> float:
     """Largest |a - b| over two pytrees of arrays, compared on the host
     (the two sides may live on different devices)."""
@@ -500,6 +550,7 @@ def main(argv=None) -> int:
         emit(flagship_phase(os.path.join(OUT_DIR, "flagship")))
         emit(cross_silo_phase(os.path.join(OUT_DIR, "cross_silo")))
         emit(kernel_phase())
+        emit(kda_phase())
     print(json.dumps({"ok": True, "device": dev}), flush=True)
     return 0
 

@@ -104,14 +104,36 @@ def fast_client_sampling(round_idx: int, client_num_in_total: int,
 #: that: 64 clients x 480 samples of CNN_DropOut (the 3400-writer FEMNIST
 #: split) is 8.7 GB of conv outputs on a 16 GB chip, 4096 samples ~1.2 GB.
 EVAL_STEP_SAMPLES = 4096
+#: tokens a federation-eval step may run at once over the clients it vmaps,
+#: a sequence a client: 20 silos of 1,024-token rows stay one step; at 4,096
+#: tokens 20 lanes of Kimi Linear's forward pass asked the TPU compiler for
+#: 11.7 GB beside a 4.6 GB base, 8 lanes fit
+EVAL_STEP_TOKENS = 32768
 
 
-def _eval_chunk(n_max: int, num_clients: int) -> int:
-    """Clients per eval step for a split packed to `n_max` samples a client:
-    at most 64, and no more than keeps the step under EVAL_STEP_SAMPLES. The
-    resident and the streaming eval share it, so they walk identical chunk
-    geometry."""
-    return max(1, min(num_clients, 64, EVAL_STEP_SAMPLES // max(n_max, 1)))
+def _eval_chunk(x, num_clients: int) -> int:
+    """Clients per eval step for a split packed as `x` [clients, n_max, ...]:
+    at most 64, and no more than keeps the step under EVAL_STEP_SAMPLES
+    samples. A sample that is a sequence of token ids (integers, [T]) counts
+    its tokens too: no more clients than keep one sequence a client under
+    EVAL_STEP_TOKENS. The resident and the streaming eval share it, so they
+    walk identical chunk geometry."""
+    n_max = x.shape[1]
+    chunk = max(1, min(num_clients, 64, EVAL_STEP_SAMPLES // max(n_max, 1)))
+    if len(x.shape) == 3 and np.issubdtype(x.dtype, np.integer):
+        chunk = max(1, min(chunk, EVAL_STEP_TOKENS // x.shape[2]))
+    return chunk
+
+
+def _experts_held(trainer):
+    """(first, count) of the router's experts the trainer's model holds, where
+    its `describe()` says it holds a share of them (the `moe_load` event's
+    `held*`); else None."""
+    module = getattr(trainer, "module", None)
+    said = module.describe() if hasattr(module, "describe") else {}
+    if said.get("experts_held") == said.get("experts_routed"):
+        return None
+    return said["experts_first"], said["experts_held"]
 
 
 def _host_metrics(train_metrics) -> dict:
@@ -487,7 +509,8 @@ class FedAvgAPI(Checkpointable):
         the same `RoundRecordLog` path as the pipelined loop (one code path
         for history/metrics/ledger), flushed every round."""
         records = RoundRecordLog(tracer, self.history, metrics_logger,
-                                 ledger=ledger, bank=self.bank)
+                                 ledger=ledger, bank=self.bank,
+                                 experts_held=_experts_held(self.trainer))
         round_idx = start_round
         while round_idx < self.cfg.comm_round:
             round_idx = self._eager_round(round_idx, records, chaos=chaos,
@@ -658,7 +681,8 @@ class FedAvgAPI(Checkpointable):
                               metrics_logger, chaos, guard, tracer, ledger)
             return
         records = RoundRecordLog(tracer, self.history, metrics_logger,
-                                 ledger=ledger)
+                                 ledger=ledger,
+                                 experts_held=_experts_held(self.trainer))
         round_idx = start_round
         while round_idx < cfg.comm_round:
             k = self._superstep_k(round_idx, ckpt_dir, ckpt_every)
@@ -1004,7 +1028,8 @@ class FedAvgAPI(Checkpointable):
         # shared RoundRecordLog; structured events (chaos, rollback) hit the
         # ledger the moment they occur, so a crash mid-flush cannot lose them
         records = RoundRecordLog(tracer, self.history, metrics_logger,
-                                 ledger=ledger, bank=self.bank)
+                                 ledger=ledger, bank=self.bank,
+                                 experts_held=_experts_held(self.trainer))
         self._last_records = records  # test/ops introspection (max_pending)
         inflight: deque = deque()
 
@@ -1246,7 +1271,7 @@ class FedAvgAPI(Checkpointable):
         resident = (not self.cfg.ci) and self._resident_eval_data(
             splits, round_idx)
         for split_name, packed in splits:
-            chunk = _eval_chunk(packed.x.shape[1], num)
+            chunk = _eval_chunk(packed.x, num)
             sums: dict[str, float] = {}
             if resident:
                 m = self._fed_eval_fn(self.global_variables, *resident[split_name])
@@ -1298,7 +1323,7 @@ class FedAvgAPI(Checkpointable):
         def staged_bytes(p):
             # what stage() actually device_puts: padded to a chunk multiple
             # same chunk geometry as the streaming path
-            chunk = _eval_chunk(p.x.shape[1], num)
+            chunk = _eval_chunk(p.x, num)
             ratio = (-(-p.num_clients // chunk) * chunk) / p.num_clients
             return (p.x.nbytes + p.y.nbytes + p.counts.nbytes) * ratio
 
@@ -1323,7 +1348,7 @@ class FedAvgAPI(Checkpointable):
             return None
 
         def stage(packed):
-            chunk = _eval_chunk(packed.x.shape[1], num)
+            chunk = _eval_chunk(packed.x, num)
             if isinstance(packed, MmapPackedStore):
                 # the ONE sanctioned whole-store read; in-budget (checked
                 # above) and bit-identical to an in-RAM split of the same rows

@@ -58,6 +58,31 @@ def tiny_deepseek_v2(**sizes) -> dict:
     return cfg
 
 
+def tiny_kimi_linear(**sizes) -> dict:
+    """Kimi-Linear-48B-A3B's published configuration with every size cut to
+    a CPU test's (hidden 64, a KDA layer of 2 heads of 16 over a dense MLP,
+    then a NoPE MLA layer over 8 experts top-2): the `config` a `kimi_linear`
+    factory call takes. Vocabulary 10, as `tiny_deepseek_v2`; `sizes`
+    override (`linear_attn_config` whole)."""
+    import json
+
+    from fedml_tpu.models.kimi_linear import PUBLISHED
+
+    with open(PUBLISHED) as f:
+        cfg = json.load(f)
+    cfg.update(hidden_size=64, intermediate_size=160,
+               moe_intermediate_size=48, num_hidden_layers=2,
+               num_attention_heads=4, num_key_value_heads=4, kv_lora_rank=32,
+               qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+               num_experts=8, num_experts_per_token=2, vocab_size=10,
+               model_max_length=256)
+    cfg["linear_attn_config"] = dict(
+        cfg["linear_attn_config"], kda_layers=[1], full_attn_layers=[2],
+        num_heads=2, head_dim=16)
+    cfg.update(sizes)
+    return cfg
+
+
 # model name -> (example input shape, input dtype, extra factory kwargs).
 # Every registered model MUST have a row (enforced by tests/test_lint.py and
 # tests/test_dtype_registry.py) — a new factory that drops the dtype knob
@@ -93,6 +118,7 @@ MODEL_EXAMPLES = {
     "rnn_stackoverflow": ((2, 12), jnp.int32, {}),
     "transformer_nwp": ((2, 16), jnp.int32, {}),
     "deepseek_v2": ((2, 16), jnp.int32, {"config": tiny_deepseek_v2()}),
+    "kimi_linear": ((2, 16), jnp.int32, {"config": tiny_kimi_linear()}),
 }
 
 

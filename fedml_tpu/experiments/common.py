@@ -29,7 +29,7 @@ def add_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
     parser.add_argument("--model_config", type=str, default=None,
                         help="JSON of a model's published configuration "
                              "keys, for a model whose sizes are a file's "
-                             "and no argument's (deepseek_v2; default: the "
+                             "and no argument's (deepseek_v2, kimi_linear; default: the "
                              "file kept beside the model)")
     parser.add_argument("--dataset", type=str, default="mnist")
     parser.add_argument("--data_dir", type=str, default="./data")
@@ -301,6 +301,12 @@ def build_trainer(args, cfg: FedConfig, ds):
     if getattr(args, "model_config", None):
         model_kwargs["config"] = args.model_config
     module = create_model(model_name, output_dim=ds.class_num, **model_kwargs)
+    from fedml_tpu import telemetry
+
+    said = module.describe() if hasattr(module, "describe") else {}
+    telemetry.emit("model_built", model=model_name, **{
+        k: said.get(k) for k in ("layers", "mixers", "experts_held",
+                                 "experts_routed")})
     if getattr(module, "frozen_base_only", False) and cfg.lora_rank <= 0:
         raise SystemExit(f"{model_name} trains as a frozen base only: give "
                          f"--lora_rank > 0")

@@ -36,6 +36,16 @@ Departures from the source, each for a reason:
     (`ops/moe.py`): the model trains as a frozen base under `--lora_rank`.
   - each layer is rematerialised in the backward pass (`nn.remat`).
 
+`RMSNorm`, `SwiGLU`, `MLA`, `MoE` and `_Router` are shared with
+`models/kimi_linear.py`. What differs between the two decoders they read as
+VALUES of the configuration object, under these names: `rotary` (false: the
+rope part of q and k is used as it comes, NoPE), `scoring_func` ("softmax" |
+"sigmoid"), `selection_bias` (the k experts are the largest of score + a
+stored bias, which selects and does not weigh), `norm_topk_prob`,
+`routed_scaling_factor`, and `experts_held` (None: all; else (first, count) of
+the router's `n_routed_experts` outputs: this chip's share of an
+expert-parallel layer, the pairs on absent experts contribute nothing).
+
 The module has three entry points: `hidden` (tokens -> final-norm states and
 the tokens every expert received), `head` (states -> float32 logits) and
 `__call__` (both, the whole batch's logits). A trainer that finds `hidden`
@@ -138,17 +148,24 @@ class DeepseekV2Config:
         return (i >= self.first_k_dense_replace
                 and i % self.moe_layer_freq == 0)
 
+    # what `MLA` and `MoE` read besides the published keys (module docstring)
+    rotary = True
+    scoring_func = "softmax"
+    selection_bias = False
+    experts_held = None
+
 
 def _yarn_mscale(factor: float, mscale: float) -> float:
     return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def softmax_scale(cfg: DeepseekV2Config) -> float:
-    m = _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+def softmax_scale(cfg) -> float:
+    m = (_yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+         if cfg.rotary else 1.0)
     return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
 
 
-def yarn_inv_freq(cfg: DeepseekV2Config) -> np.ndarray:
+def yarn_inv_freq(cfg) -> np.ndarray:
     """The rotary inverse frequencies [rope / 2]: extrapolated (as trained)
     above the `beta_fast` correction, interpolated by `factor` below the
     `beta_slow` one, a linear ramp between."""
@@ -214,7 +231,7 @@ class SwiGLU(nn.Module):
 
 
 class MLA(nn.Module):
-    cfg: DeepseekV2Config
+    cfg: Any
     dtype: Any
 
     @nn.compact
@@ -232,15 +249,18 @@ class MLA(nn.Module):
         kv = kv.reshape(b, t, h, nope + vd)
         k_nope, v = kv[..., :nope], kv[..., nope:]
 
-        freqs = np.outer(np.arange(t, dtype=np.float32), yarn_inv_freq(c))
-        emb = np.concatenate([freqs, freqs], axis=-1)
-        # the cos/sin factor mscale / mscale_all_dim of the source
-        ratio = (_yarn_mscale(c.rope_factor, c.rope_mscale)
-                 / _yarn_mscale(c.rope_factor, c.rope_mscale_all_dim))
-        cos, sin = np.cos(emb) * ratio, np.sin(emb) * ratio
-        q_rope = rotary(q[..., nope:], cos, sin)
-        k_rope = rotary(k_rope[:, :, None, :], cos, sin)
-        q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+        if c.rotary:
+            freqs = np.outer(np.arange(t, dtype=np.float32), yarn_inv_freq(c))
+            emb = np.concatenate([freqs, freqs], axis=-1)
+            # the cos/sin factor mscale / mscale_all_dim of the source
+            ratio = (_yarn_mscale(c.rope_factor, c.rope_mscale)
+                     / _yarn_mscale(c.rope_factor, c.rope_mscale_all_dim))
+            cos, sin = np.cos(emb) * ratio, np.sin(emb) * ratio
+            q_rope = rotary(q[..., nope:], cos, sin)
+            k_rope = rotary(k_rope[:, :, None, :], cos, sin)
+            q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+        else:
+            k_rope = k_rope[:, :, None, :]
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope, (b, t, h, rope))], axis=-1)
         o = flash_attention(q, k, v, True, scale=softmax_scale(c))
@@ -249,32 +269,52 @@ class MLA(nn.Module):
 
 
 class MoE(nn.Module):
-    cfg: DeepseekV2Config
+    cfg: Any
     dtype: Any
 
     @nn.compact
     def __call__(self, x):
-        """-> (y, tokens each expert received [n_routed_experts] f32)."""
+        """-> (y, tokens each of the router's experts received
+        [n_routed_experts] f32)."""
         c = self.cfg
         b, t, d = x.shape
         e, f = c.n_routed_experts, c.moe_intermediate_size
         flat = x.reshape(b * t, d)
         # router logits in float32, as the source computes them
         logits = _Router(e, self.dtype, name="router")(flat)
-        scores = jax.nn.softmax(logits, axis=-1)
-        gate, idx = moe.top_k_route(scores, c.num_experts_per_tok)
+        if c.scoring_func == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+        else:
+            scores = jax.nn.softmax(logits, axis=-1)
+        if c.selection_bias:
+            bias = self.param("selection_bias", nn.initializers.zeros, (e,),
+                              self.dtype)
+            gate, idx = biased_route(scores, bias.astype(jnp.float32),
+                                     c.num_experts_per_tok)
+        else:
+            gate, idx = moe.top_k_route(scores, c.num_experts_per_tok)
         if c.norm_topk_prob:
             gate = gate / (gate.sum(axis=-1, keepdims=True) + 1e-20)
         gate = gate * c.routed_scaling_factor
+        held = c.experts_held
+        n_held = e if held is None else held[1]
         init = nn.initializers.variance_scaling(
             1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=(0,))
-        w_gate = self.param("experts_gate", init, (e, d, f), self.dtype)
-        w_up = self.param("experts_up", init, (e, d, f), self.dtype)
-        w_down = self.param("experts_down", init, (e, f, d), self.dtype)
+        w_gate = self.param("experts_gate", init, (n_held, d, f), self.dtype)
+        w_up = self.param("experts_up", init, (n_held, d, f), self.dtype)
+        w_down = self.param("experts_down", init, (n_held, f, d), self.dtype)
         with jax.named_scope("experts"):
-            y = moe.routed_experts(flat, idx, gate, w_gate, w_up, w_down)
+            y = moe.routed_experts(flat, idx, gate, w_gate, w_up, w_down,
+                                   moe.TILE, held and held[0])
         shared = SwiGLU(c.n_shared_experts * f, self.dtype, name="shared")(flat)
         return (y + shared).reshape(b, t, d), moe.expert_load(idx, e)
+
+
+def biased_route(scores, bias, k: int):
+    """The k experts with the largest score + bias, weighed by their SCORES:
+    the bias selects and does not weigh. -> (gate [.., k], idx [.., k])."""
+    _, idx = moe.top_k_route(scores + bias, k)
+    return jnp.take_along_axis(scores, idx, axis=-1), idx
 
 
 def router_logits(x, kernel):
@@ -350,6 +390,14 @@ class DeepseekV2LM(nn.Module):
     def head(self, h):
         """states [.., hidden] -> float32 logits [.., vocab]."""
         return self.lm_head(h).astype(jnp.float32)
+
+    def describe(self) -> dict:
+        """The `model_built` event's fields (`telemetry/tracer.py`)."""
+        c = self.cfg
+        return {"layers": c.num_hidden_layers,
+                "mixers": {"mla": c.num_hidden_layers},
+                "experts_held": c.n_routed_experts,
+                "experts_routed": c.n_routed_experts}
 
     def __call__(self, tokens, train: bool = False):
         return self.head(self.hidden(tokens, train)[0])

@@ -171,6 +171,25 @@ def _deepseek_v2(output_dim, **kw):
     return DeepseekV2LM(cfg, dtype=_compute_dtype(kw) or jnp.float32)
 
 
+@register_model("kimi_linear")
+def _kimi_linear(output_dim, **kw):
+    # Kimi Linear decoder (KDA layers beside NoPE latent attention, sigmoid-
+    # routed experts, optionally a share of them). Sizes as `deepseek_v2`'s:
+    # `config` is the published keys' dict or the path of a JSON that holds
+    # them (--model_config); None is Kimi-Linear-48B-A3B as published
+    from fedml_tpu.models.kimi_linear import KimiLinearConfig, KimiLinearLM
+
+    config = kw.get("config")
+    cfg = (KimiLinearConfig.from_dict(config) if isinstance(config, dict)
+           else KimiLinearConfig.from_file(config))
+    if output_dim != cfg.vocab_size:
+        raise ValueError(f"the data has {output_dim} token ids, the model "
+                         f"configuration a vocabulary of {cfg.vocab_size}")
+    import jax.numpy as jnp
+
+    return KimiLinearLM(cfg, dtype=_compute_dtype(kw) or jnp.float32)
+
+
 @register_model("mobilenet_v3")
 def _mobilenet_v3(output_dim, **kw):
     # reference main_fedavg.py "mobilenet_v3" -> MobileNetV3(model_mode=...)

@@ -24,6 +24,19 @@ adapter), which is the one way the model that uses this op is trained
 (it says `frozen_base_only`, and `experiments/common.py::build_trainer`
 refuses such a module without `--lora_rank`).
 
+**A share of the experts (`first`).** An expert-parallel layer routes over
+more experts than a chip holds. With `first` given, `idx` names the router's
+experts and the matrices are those of experts first .. first + E - 1: a pair
+whose expert is absent leaves the dispatch before the grouped product (it
+gets no row, adds nothing to `y` and takes no gradient; `gate` keeps what
+the router gave it, renormalised over all k). How many pairs stay is data;
+the buffers are static and sized for the WORST case, every pair on a held
+expert (M = N k + E tile rows, as when all are held): no bound under it can
+never drop a pair. The grouped product skips the unused tiles (`n_tiles`);
+the elementwise work and the gathers around it run over all M rows, which at
+a quarter of the experts is about three times what the held pairs need
+(PERF.md section 7). With `first` None it is the path it was.
+
 Under `vmap` (the engine's client axis) with matrices that are the same for
 every lane, as a frozen base is, the lanes' tokens are dispatched TOGETHER:
 one sort, one grouped product over the cohort's tokens
@@ -56,27 +69,42 @@ def expert_load(idx, n_experts: int):
     return jnp.zeros((n_experts,), jnp.float32).at[idx.reshape(-1)].add(1.0)
 
 
-def _layout(idx, n_experts: int, tile: int):
+def _layout(idx, n_experts: int, tile: int, first=None):
     """Where each (token, slot) pair sits among the tiled rows.
 
     idx [N, k] -> (src [M] the pair that feeds each row, P = N * k for a
     filler row; row_of_pair [P]; tile_group [M // tile] the expert of each
     tile; n_tiles [1] the tiles that hold rows), M = P + n_experts * tile
-    rounded up to the tile."""
+    rounded up to the tile. With `first`, `idx` counts the router's experts,
+    `n_experts` are held from there, and a pair on an absent one gets no row
+    (its `row_of_pair` is M, out of range)."""
     n, k = idx.shape
     p = n * k
     m = -(-(p + n_experts * tile) // tile) * tile
     flat = idx.reshape(p)
-    sizes = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
+    groups = n_experts
+    if first is not None:
+        # absent pairs sort behind every held group, as one group of size 0
+        flat = flat - first
+        flat = jnp.where((flat >= 0) & (flat < n_experts), flat, n_experts)
+        groups += 1
+    sizes = jnp.zeros((groups,), jnp.int32).at[flat].add(1)
+    if first is not None:
+        sizes = sizes.at[-1].set(0)
     padded = -(-sizes // tile) * tile
     ends = jnp.cumsum(padded)
     starts = ends - padded
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)
     sorted_e = flat[order]
-    first = jnp.cumsum(sizes) - sizes            # unpadded start of a group
-    rank = jnp.arange(p, dtype=jnp.int32) - first[sorted_e]
+    begin = jnp.cumsum(sizes) - sizes            # unpadded start of a group
+    rank = jnp.arange(p, dtype=jnp.int32) - begin[sorted_e]
     dest = starts[sorted_e] + rank               # row of the sorted pair
-    src = jnp.full((m,), p, jnp.int32).at[dest].set(order)
+    if first is None:
+        src = jnp.full((m,), p, jnp.int32).at[dest].set(order)
+    else:
+        dest = jnp.where(sorted_e == n_experts, m, dest)
+        src = jnp.full((m,), p, jnp.int32).at[dest].set(order, mode="drop")
+        ends = ends[:n_experts]
     row_of_pair = jnp.zeros((p,), jnp.int32).at[order].set(dest)
     tile_start = jnp.arange(m // tile, dtype=jnp.int32) * tile
     tile_group = jnp.minimum(
@@ -147,31 +175,35 @@ def _rows(x, src, k: int):
     return jnp.take(x, src // k, axis=0, mode="fill", fill_value=0)
 
 
-def _pairs(rows, row_of_pair, n: int, k: int):
-    """Tiled rows back to [N, k, width]."""
+def _pairs(rows, row_of_pair, n: int, k: int, share: bool = False):
+    """Tiled rows back to [N, k, width]; of a share, zeros for the pairs
+    that got no row."""
+    if share:
+        return jnp.take(rows, row_of_pair, axis=0, mode="fill",
+                        fill_value=0).reshape(n, k, rows.shape[-1])
     return jnp.take(rows, row_of_pair, axis=0).reshape(n, k, rows.shape[-1])
 
 
-def _forward(x, idx, gate, wg, wu, wd, tile):
+def _forward(x, idx, gate, wg, wu, wd, tile, first=None):
     """-> (y [N, d], residuals (yk [N, k, d], g, u [M, f]))."""
     n, k = idx.shape
-    src, row_of_pair, grp, nt = _layout(idx, wg.shape[0], tile)
+    src, row_of_pair, grp, nt = _layout(idx, wg.shape[0], tile, first)
     mm = functools.partial(grouped_matmul, tile_group=grp, n_tiles=nt,
                            tile=tile)
     xs = _rows(x, src, k)
     g, u = mm(xs, wg), mm(xs, wu)
     h = (_silu(g.astype(jnp.float32)) * u.astype(jnp.float32)).astype(x.dtype)
-    yk = _pairs(mm(h, wd), row_of_pair, n, k)
+    yk = _pairs(mm(h, wd), row_of_pair, n, k, first is not None)
     # the k-term sums are elementwise (no float32 matrix product)
     y = (gate.astype(jnp.float32)[:, :, None]
          * yk.astype(jnp.float32)).sum(axis=1).astype(x.dtype)
     return y, (yk, g, u)
 
 
-def _backward(idx, gate, wg, wu, wd, yk, g, u, dy, tile):
+def _backward(idx, gate, wg, wu, wd, yk, g, u, dy, tile, first=None):
     """-> (dx [N, d], dgate [N, k])."""
     n, k = idx.shape
-    src, row_of_pair, grp, nt = _layout(idx, wg.shape[0], tile)
+    src, row_of_pair, grp, nt = _layout(idx, wg.shape[0], tile, first)
     mm_t = functools.partial(grouped_matmul, tile_group=grp, n_tiles=nt,
                              tile=tile, trans_rhs=True)
     dy32 = dy.astype(jnp.float32)
@@ -189,7 +221,8 @@ def _backward(idx, gate, wg, wu, wd, yk, g, u, dy, tile):
     # filler rows past a group's real ones carry what an unvisited tile left
     # there: they are never gathered back
     dxs = mm_t(dg, wg).astype(jnp.float32) + mm_t(du, wu).astype(jnp.float32)
-    dx = _pairs(dxs, row_of_pair, n, k).sum(axis=1).astype(dy.dtype)
+    dx = _pairs(dxs, row_of_pair, n, k, first is not None).sum(
+        axis=1).astype(dy.dtype)
     return dx, dgate.astype(gate.dtype)
 
 
@@ -233,31 +266,32 @@ def _lanes_together(fn, n_lane_args: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _ops(tile: int):
+def _ops(tile: int, first=None):
     def fwd(x, idx, gate, wg, wu, wd):
-        return _forward(x, idx, gate, wg, wu, wd, tile)
+        return _forward(x, idx, gate, wg, wu, wd, tile, first)
 
     def bwd(idx, gate, yk, g, u, dy, wg, wu, wd):
-        return _backward(idx, gate, wg, wu, wd, yk, g, u, dy, tile)
+        return _backward(idx, gate, wg, wu, wd, yk, g, u, dy, tile, first)
 
     return _lanes_together(fwd, 3), _lanes_together(bwd, 6)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def routed_experts(x, idx, gate, wg, wu, wd, tile: int = TILE):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def routed_experts(x, idx, gate, wg, wu, wd, tile: int = TILE, first=None):
     """x [N, d], idx [N, k] int32, gate [N, k], wg/wu [E, d, f], wd [E, f, d]
-    -> y [N, d] in x's dtype (module docstring)."""
-    return _ops(tile)[0](x, idx, gate, wg, wu, wd)[0]
+    -> y [N, d] in x's dtype (module docstring). `first`: the matrices are a
+    share, experts first .. first + E - 1 of those `idx` counts."""
+    return _ops(tile, first)[0](x, idx, gate, wg, wu, wd)[0]
 
 
-def _re_fwd(x, idx, gate, wg, wu, wd, tile):
-    y, (yk, g, u) = _ops(tile)[0](x, idx, gate, wg, wu, wd)
+def _re_fwd(x, idx, gate, wg, wu, wd, tile, first):
+    y, (yk, g, u) = _ops(tile, first)[0](x, idx, gate, wg, wu, wd)
     return y, (idx, gate, yk, g, u, wg, wu, wd)
 
 
-def _re_bwd(tile, res, dy):
+def _re_bwd(tile, first, res, dy):
     idx, gate, yk, g, u, wg, wu, wd = res
-    dx, dgate = _ops(tile)[1](idx, gate, yk, g, u, dy, wg, wu, wd)
+    dx, dgate = _ops(tile, first)[1](idx, gate, yk, g, u, dy, wg, wu, wd)
     return dx, None, dgate, None, None, None
 
 

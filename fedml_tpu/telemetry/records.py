@@ -36,15 +36,25 @@ _LEDGER_KEYS = ("participated_count", "quarantined_count", "guard_retries",
                 "chaos_dropped", "chaos_nan", "chaos_corrupt")
 
 
-def moe_load_summary(load) -> Dict[str, float]:
+def moe_load_summary(load, held=None) -> Dict[str, float]:
     """The `moe_load` event's fields from a round's [expert layers, experts]
     counts of the tokens every routed expert received (summed over steps and
-    lanes): the busiest expert's, the mean, and how many got none."""
+    lanes): the busiest expert's, the mean, and how many got none. `held`
+    ((first, count) of the experts this chip holds, for a model that computes
+    a share of an expert-parallel layer) adds the same of the held columns:
+    `held` (their pairs), `held_max`, `held_mean`, `held_empty`."""
     import numpy as np
 
     load = np.asarray(load, np.float64)
-    return {"max": float(load.max()), "mean": float(load.mean()),
-            "empty": int((load == 0).sum())}
+    out = {"max": float(load.max()), "mean": float(load.mean()),
+           "empty": int((load == 0).sum())}
+    if held is not None:
+        first, count = held
+        mine = load[:, first:first + count]
+        out.update(held=float(mine.sum()), held_max=float(mine.max()),
+                   held_mean=float(mine.mean()),
+                   held_empty=int((mine == 0).sum()))
+    return out
 
 
 def _scalar(v: Any) -> Any:
@@ -57,8 +67,12 @@ class RoundRecordLog:
     to history + metrics logger + the telemetry ledger."""
 
     def __init__(self, tracer=None, history: Optional[List[Dict]] = None,
-                 metrics_logger=None, ledger=None, bank=None):
+                 metrics_logger=None, ledger=None, bank=None,
+                 experts_held=None):
         self.tracer = tracer or NULL_TRACER
+        #: (first, count) of the router's experts the model holds, where it
+        #: holds a share (`moe_load_summary`); a fact of the build, not data
+        self.experts_held = experts_held
         self.history = history if history is not None else []
         self.metrics_logger = metrics_logger
         self.ledger = ledger
@@ -110,7 +124,7 @@ class RoundRecordLog:
             load = rec.pop("_moe_load", None)
             if load is not None:
                 self.tracer.event("moe_load", round=rec["round"],
-                                  **moe_load_summary(load))
+                                  **moe_load_summary(load, self.experts_held))
             rec = {k: _scalar(v) for k, v in rec.items()}
             self.history.append(rec)
             if self.metrics_logger is not None:

@@ -194,6 +194,18 @@ def fold(records: List[Dict[str, Any]]) -> Dict[str, Any]:
             [s for s in spans if s["name"] == "dispatch"]))
     if compile_counts is not None:
         report["compile"] = compile_counts
+    built = [e for e in events if e["kind"] == "model_built"]
+    if built:
+        report["model"] = {k: built[-1].get(k) for k in (
+            "model", "layers", "mixers", "experts_held", "experts_routed")}
+    loads = [e for e in events if e["kind"] == "moe_load"]
+    if loads:
+        # routed experts: the means over the rounds of every attribute the
+        # events carry (`held*` where the model holds a share of them)
+        report["moe_load"] = {
+            k: round(sum(e[k] for e in loads) / len(loads), 4)
+            for k in ("max", "mean", "empty", "held", "held_max",
+                      "held_mean", "held_empty") if k in loads[0]}
     for k in ("platform", "cpu_cores", "cpu_capped", *_WORKLOAD_KEYS):
         if k in meta:
             report[k] = meta[k]

@@ -69,8 +69,18 @@ EVENT_SCHEMAS: Dict[str, set] = {
     "round_committed": {"round"},
     # the same path, for a model with routed experts: the tokens its experts
     # received in the round, over every (layer, expert): the busiest one's,
-    # the mean and the number that got none
+    # the mean and the number that got none. A model that computes a share
+    # of an expert-parallel layer (`models/kimi_linear.py`) counts every
+    # expert the router has and adds, of the experts this chip holds,
+    # `held` (their pairs), `held_max`, `held_mean`, `held_empty`; a model
+    # that holds them all (DeepSeek-V2) adds nothing
     "moe_load": {"round", "max", "mean", "empty"},
+    # which program a trace is of, once, from the model's `describe()`
+    # (`experiments/common.py::build_trainer`): registry name, layers,
+    # mixers ({"kda": 4, "mla": 1}), the experts a layer holds and routes
+    # over; None where the model does not say
+    "model_built": {"model", "layers", "mixers", "experts_held",
+                    "experts_routed"},
     # superstep drive (algorithms/fedavg.py): one fused K-round dispatch
     # committed — `round` is the chunk's first round, `rounds` how many it
     # fused (k_eff after cadence clamping), `k` the configured ceiling

@@ -193,7 +193,8 @@ def test_flagship_federation_eval_fits_beside_its_split(one_chip,
     from fedml_tpu.models.registry import create_model
 
     clients, n_max = 3400, 480
-    chunk = _eval_chunk(n_max, clients)
+    chunk = _eval_chunk(jax.ShapeDtypeStruct(
+        (clients, n_max, 28, 28, 1), jnp.float32), clients)
     nc = -(-clients // chunk)
     trainer = ClassificationTrainer(create_model("cnn", output_dim=62))
     gv = jax.eval_shape(lambda: trainer.init(
@@ -259,3 +260,71 @@ def test_dsv2lite_lora_round_compiles_and_never_returns_its_base(
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 5.6e9 > 1e8 > mem.output_size_in_bytes
     assert _fits(compiled) < 10e9
+
+
+def test_kda_kernels_compile_at_the_cells_shapes(one_chip, no_compile_cache):
+    """`ops/kda.py`'s two Pallas calls as Kimi Linear's training step runs
+    them: 2 lanes x 2 sequences x 4,096 tokens x 32 heads of 128, bfloat16
+    q, k, v, float32 g and beta, forward and backward under the lanes' vmap,
+    on the kernel's own chunk. The benchmark's readers find them by name."""
+    from fedml_tpu.ops.kda import kda
+
+    def loss(q, k, v, g, beta):
+        o = jax.vmap(lambda *a: kda(*a, interpret=False))(q, k, v, g, beta)
+        return jnp.sum(o.astype(jnp.float32))
+
+    wide = (2, 2, 4096, 32, 128)
+    args = _on(one_chip, (
+        *[jax.ShapeDtypeStruct(wide, jnp.bfloat16)] * 3,
+        jax.ShapeDtypeStruct(wide, jnp.float32),
+        jax.ShapeDtypeStruct(wide[:-1], jnp.float32)))
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(*args).compile()
+    text = compiled.as_text()
+    assert "kda_fwd" in text and "kda_bwd" in text
+    _fits(compiled)
+
+
+@pytest.mark.slow  # ~2 min of TPU compile
+def test_kimi_linear_lora_round_compiles_beside_its_base(one_chip,
+                                                         no_compile_cache):
+    """engine.round for `benchmarks/configs/kimi_linear_lora.json` (5 layers
+    of Kimi-Linear-48B-A3B, 64 of 256 experts held, bfloat16 base, rank-16
+    adapters, 2 lanes x 2 x 4,096 tokens a step): fits beside its 4.6 GB
+    base and returns none of it."""
+    from fedml_tpu.algorithms.aggregators import make_aggregator
+    from fedml_tpu.algorithms.engine import build_round_fn
+    from fedml_tpu.core.config import FedConfig
+    from fedml_tpu.core.trainer import NWPTrainer
+    from fedml_tpu.models.lora import LoRATrainer
+    from fedml_tpu.models.registry import create_model
+    from fedml_tpu.ops import attention, kda, moe
+
+    cfg = FedConfig(model="kimi_linear", client_num_in_total=20,
+                    client_num_per_round=2, epochs=1, batch_size=2, lr=0.03,
+                    lora_rank=16, dtype="bfloat16")
+    trainer = LoRATrainer(NWPTrainer(create_model(
+        "kimi_linear", output_dim=40960, dtype="bfloat16",
+        config="benchmarks/configs/kimi_linear_lora.json")), rank=16)
+    agg = make_aggregator("fedavg", cfg)
+    gv = jax.eval_shape(lambda: trainer.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32)))
+    tokens = jax.ShapeDtypeStruct((2, 8, 4096), jnp.int32)
+    args = _on(one_chip, (
+        gv, jax.eval_shape(agg.init_state, gv), tokens, tokens,
+        jax.ShapeDtypeStruct((2,), jnp.int32),
+        jax.eval_shape(lambda: jax.random.PRNGKey(0))))
+    mods = (moe, attention, kda)
+    was = [m.interpret_off_chip for m in mods]
+    for m in mods:
+        m.interpret_off_chip = lambda k: False
+    try:
+        compiled = build_round_fn(
+            trainer, cfg, agg, donate_data=True,
+            collect_stats=True).jitted.lower(*args).compile()
+    finally:
+        for m, f in zip(mods, was):
+            m.interpret_off_chip = f
+    mem = compiled.memory_analysis()
+    print(mem)
+    assert mem.argument_size_in_bytes > 4.5e9 > 1e8 > mem.output_size_in_bytes
+    assert _fits(compiled) < 13e9

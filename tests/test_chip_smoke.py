@@ -165,3 +165,11 @@ def test_script_alone_fails_without_the_program(tmp_path):
                           timeout=120)
     assert proc.returncode != 0
     assert '"ok": true' not in proc.stdout
+
+
+def test_kda_phase_interpreted_by_the_test():
+    """The KDA phase as the chip runs it, at a smaller shape and with the
+    kernels interpreted: the same comparison, the same tolerances."""
+    out = chip_smoke.kda_phase(shape=(1, 256, 2, 32), interpret=True)
+    assert out["ok"] and out["phase"] == "kda" and out["interpret"]
+    assert out["fwd_max_abs_err"] < 2e-5 and out["grad_max_abs_err"] < 1e-4

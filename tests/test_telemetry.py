@@ -102,6 +102,9 @@ _SAMPLE_EVENTS = {
     "guard_exhausted": dict(round=2),
     "round_committed": dict(round=0, participated_count=6.0),
     "moe_load": dict(round=0, max=431.0, mean=384.0, empty=0),
+    "model_built": dict(model="kimi_linear", layers=5,
+                        mixers={"kda": 4, "mla": 1}, experts_held=64,
+                        experts_routed=256),
     "superstep_committed": dict(round=4, rounds=4, k=4),
     "checkpoint_save": dict(step=5),
     "mqtt_reconnect": dict(client_id="c0", ok=True, attempts=2),
