@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
 """chip_smoke.py — the quickest proof that fedml_tpu still starts on the chip.
 
-    python chip_smoke.py              one chip: flagship, cross_silo, kernel, kda
+    python chip_smoke.py              one chip: flagship, cross_silo, kernel, kda,
+                                      moe_share
     python chip_smoke.py --multichip  four chips: the two mesh paths, each
                                       against the one-chip vmap engine,
                                       and nothing else
@@ -315,6 +316,89 @@ def kda_phase(shape: tuple[int, int, int, int] = (2, 512, 4, 128),
             "peak_bytes_in_use": peak_bytes()}
 
 
+def moe_share_phase(tokens: int = 2048, d: int = 256, f: int = 128,
+                    held: int = 8, routed: int = 32, k: int = 4,
+                    tile: int = 128, seed: int = 0) -> dict:
+    """A share's routed-expert dispatch (`ops/moe.py::routed_experts_share`:
+    `held` of `routed` experts on this chip), forward and jax.grad, against
+    every held expert computed on every token and masked, in float32 at
+    `highest` from the same bfloat16 values (the dispatch runs in bfloat16,
+    as the cells run it). Twice: the router's own top-k, whose held rows fit the
+    bounded buffer, and a routing with every pair on a held expert, which no
+    bound under the worst case holds and which has to take the exact
+    worst-case path: the path no benchmark cell takes is seen on the
+    hardware here. The errors are bfloat16's rounding of g, u, h and the rows
+    (under a hundredth of the largest entry); a dropped pair or a row read
+    from the wrong tile moves them by a tenth or more."""
+    import jax.numpy as jnp
+
+    from fedml_tpu.ops import moe
+
+    tol, first = 3e-2, held        # the share after the first
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    x, cot, *w = (
+        (jax.random.normal(key, shape) * shape[-2] ** -0.5 * scale).astype(
+            jnp.bfloat16)
+        for key, shape, scale in zip(ks, (
+            (tokens, d), (tokens, d), (held, d, f), (held, d, f),
+            (held, f, d)), (tokens ** 0.5, tokens ** 0.5, 1.0, 1.0, 1.0)))
+    x32, cot32, w32 = (jax.tree.map(lambda a: a.astype(jnp.float32), t)
+                       for t in (x, cot, w))
+    scores = jax.nn.sigmoid(jax.random.normal(ks[5], (tokens, routed)))
+    routings = {"bounded": moe.top_k_route(scores, k),
+                "fallback": moe.top_k_route(
+                    scores[:, first:first + held], k)}
+    routings["fallback"] = (routings["fallback"][0],
+                            routings["fallback"][1] + first)
+
+    def dense(x, gate, idx):
+        y = 0
+        for e in range(held):
+            m = ((idx == e + first) * gate).sum(-1)
+            y = y + m[:, None] * ((jax.nn.silu(x @ w32[0][e])
+                                   * (x @ w32[1][e])) @ w32[2][e])
+        return y
+
+    def step(fn):
+        def loss(x, gate, idx):
+            y, worst = fn(x, gate, idx)
+            return jnp.sum(y.astype(jnp.float32) * cot32), (y, worst)
+        return jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True))
+
+    share = step(lambda x, gate, idx: moe.routed_experts_share(
+        x, idx, gate, *w, first, routed, tile))
+    plain = step(lambda x, gate, idx: (dense(x, gate, idx), jnp.zeros(())))
+    m_b = moe.share_rows(tokens * k, held, routed, tile)
+    out = {"phase": "moe_share", "ok": True, "tokens": tokens,
+           "held_of_routed": [held, routed], "rows_bounded": m_b,
+           "rows_worst": moe._worst_rows(tokens * k, held, tile), "paths": {}}
+    t0 = time.perf_counter()
+    for name, (gate, idx) in routings.items():
+        (_, (y, worst)), (dx, dgate) = jax.block_until_ready(
+            share(x, gate, idx))
+        # (the reference alone: a bfloat16 operand is no float32 product's,
+        # Mosaic refuses the kernel under `highest`)
+        with jax.default_matmul_precision("highest"):
+            (_, (ry, _)), (rdx, rdgate) = plain(x32, gate, idx)
+        errs = {n: float(jnp.max(jnp.abs(a.astype(jnp.float32) - b))
+                         / jnp.max(jnp.abs(b)))
+                for n, a, b in (("y", y, ry), ("dx", dx, rdx),
+                                ("dgate", dgate, rdgate))}
+        took = "fallback" if float(worst[0]) else "bounded"
+        out["paths"][name] = {
+            "took": took, "max_abs_err": errs,
+            "held_rows": int(moe._held_rows(idx, held, tile, first))}
+        if took != name or not all(e < tol for e in errs.values()):
+            raise AssertionError(
+                f"moe_share: a routing meant for the {name} path took the "
+                f"{took} one with errors {errs} (tol {tol} of the largest "
+                f"entry)")
+    out["smoke_timing"] = {
+        "compile_and_first_calls_s": time.perf_counter() - t0}
+    out["peak_bytes_in_use"] = peak_bytes()
+    return out
+
+
 def max_abs_diff(a, b) -> float:
     """Largest |a - b| over two pytrees of arrays, compared on the host
     (the two sides may live on different devices)."""
@@ -551,6 +635,7 @@ def main(argv=None) -> int:
         emit(cross_silo_phase(os.path.join(OUT_DIR, "cross_silo")))
         emit(kernel_phase())
         emit(kda_phase())
+        emit(moe_share_phase())
     print(json.dumps({"ok": True, "device": dev}), flush=True)
     return 0
 

@@ -136,6 +136,14 @@ def _experts_held(trainer):
     return said["experts_first"], said["experts_held"]
 
 
+def _moe_record(train_metrics) -> dict:
+    """A routed-expert model's vectors among a round's metrics (`moe_load`,
+    and a share's `moe_path`) under the record's reserved keys: they leave
+    in the `moe_load` event (`telemetry/records.py`); history takes scalars."""
+    return {"_" + k: train_metrics[k] for k in ("moe_load", "moe_path")
+            if k in train_metrics}
+
+
 def _host_metrics(train_metrics) -> dict:
     """A round's metric sums fetched in ONE host round trip: scalars as
     floats; a vector (a routed-expert model's `moe_load`) stays an array."""
@@ -579,8 +587,7 @@ class FedAvgAPI(Checkpointable):
                                 record[k] = train_metrics[k]
                     if guard is not None and retries:
                         record["guard_retries"] = retries
-                    if "moe_load" in train_metrics:
-                        record["_moe_load"] = train_metrics["moe_load"]
+                    record.update(_moe_record(train_metrics))
                     if round_idx % cfg.frequency_of_the_test == 0 or round_idx == cfg.comm_round - 1:
                         record.update(self.evaluate(round_idx, tracer))
                     records.add(record)
@@ -1146,9 +1153,8 @@ class FedAvgAPI(Checkpointable):
                                 record[k] = train_metrics[k]
                     if guard is not None and retries:
                         record["guard_retries"] = retries
-                    if "moe_load" in train_metrics:
-                        # device-resident until the flush's one fetch
-                        record["_moe_load"] = train_metrics["moe_load"]
+                    # device-resident until the flush's one fetch
+                    record.update(_moe_record(train_metrics))
                     retries = 0
                     if is_test:
                         # eval reads the post-round model, so these dispatches

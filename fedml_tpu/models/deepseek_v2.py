@@ -44,7 +44,8 @@ rope part of q and k is used as it comes, NoPE), `scoring_func` ("softmax" |
 stored bias, which selects and does not weigh), `norm_topk_prob`,
 `routed_scaling_factor`, and `experts_held` (None: all; else (first, count) of
 the router's `n_routed_experts` outputs: this chip's share of an
-expert-parallel layer, the pairs on absent experts contribute nothing).
+expert-parallel layer, the pairs on absent experts contribute nothing, and
+`MoE` hands back which path the share's dispatch took beside the load).
 
 The module has three entry points: `hidden` (tokens -> final-norm states and
 the tokens every expert received), `head` (states -> float32 logits) and
@@ -275,7 +276,12 @@ class MoE(nn.Module):
     @nn.compact
     def __call__(self, x):
         """-> (y, tokens each of the router's experts received
-        [n_routed_experts] f32)."""
+        [n_routed_experts] f32, path). `path` is None where every expert is
+        held. A share (`cfg.experts_held`) hands `ops/moe.py` the router's
+        width beside its first expert: the dispatch sizes its row buffers by
+        the share and says whether this call fit them, which leaves here as
+        [2] f32, (1, 0) for the bounded path and (0, 1) for the worst-case
+        one (under the engine's `vmap` a lane's value is its joint call's)."""
         c = self.cfg
         b, t, d = x.shape
         e, f = c.n_routed_experts, c.moe_intermediate_size
@@ -303,11 +309,17 @@ class MoE(nn.Module):
         w_gate = self.param("experts_gate", init, (n_held, d, f), self.dtype)
         w_up = self.param("experts_up", init, (n_held, d, f), self.dtype)
         w_down = self.param("experts_down", init, (n_held, f, d), self.dtype)
+        path = None
         with jax.named_scope("experts"):
-            y = moe.routed_experts(flat, idx, gate, w_gate, w_up, w_down,
-                                   moe.TILE, held and held[0])
+            if held is None:
+                y = moe.routed_experts(flat, idx, gate, w_gate, w_up, w_down,
+                                       moe.TILE)
+            else:
+                y, worst = moe.routed_experts_share(
+                    flat, idx, gate, w_gate, w_up, w_down, held[0], e)
+                path = jnp.stack([1.0 - worst[0], worst[0]])
         shared = SwiGLU(c.n_shared_experts * f, self.dtype, name="shared")(flat)
-        return (y + shared).reshape(b, t, d), moe.expert_load(idx, e)
+        return (y + shared).reshape(b, t, d), moe.expert_load(idx, e), path
 
 
 def biased_route(scores, bias, k: int):
@@ -347,7 +359,7 @@ class Block(nn.Module):
         h = x + MLA(c, self.dtype, name="attn")(norm("input_norm")(x))
         z = norm("post_norm")(h)
         if self.is_moe:
-            y, load = MoE(c, self.dtype, name="moe")(z)
+            y, load, _ = MoE(c, self.dtype, name="moe")(z)
         else:
             y = SwiGLU(c.intermediate_size, self.dtype, name="mlp")(z)
             load = jnp.zeros((c.n_routed_experts,), jnp.float32)

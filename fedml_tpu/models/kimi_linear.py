@@ -34,7 +34,8 @@ a benchmark configuration's `assumed`: the two gates' rank is the linear
 i}` beside the published keys says that `num_experts` counts the experts
 this chip HOLDS, the i-th of n equal shares: the router keeps `num_experts` x
 n outputs, top-k and the renormalisation run over all of them, and the pairs
-on absent experts contribute nothing (`ops/moe.py`). Their exchange with the
+on absent experts contribute nothing (`ops/moe.py`, whose row buffers are
+sized by the share: `num_experts` of `n_routed_experts`). Their exchange with the
 other chips is not built (ROADMAP B5): on one chip the layer's output lacks
 what the absent experts would add, and goes on to the next layer so.
 
@@ -284,12 +285,15 @@ class Block(nn.Module):
         else:
             h = x + MLA(c, self.dtype, name="attn")(z)
         z = norm("post_norm")(h)
+        path = None
         if self.is_moe:
-            y, load = MoE(c, self.dtype, name="moe")(z)
+            y, load, path = MoE(c, self.dtype, name="moe")(z)
         else:
             y = SwiGLU(c.intermediate_size, self.dtype, name="mlp")(z)
             load = jnp.zeros((c.n_routed_experts,), jnp.float32)
-        return h + y, load
+        if path is None:        # no experts here, or every one of them held
+            path = jnp.zeros((2,), jnp.float32)
+        return h + y, load, path
 
 
 class KimiLinearLM(nn.Module):
@@ -312,18 +316,23 @@ class KimiLinearLM(nn.Module):
     def hidden(self, tokens, train: bool = False):
         """tokens [B, T] -> (final-norm states [B, T, hidden], {"moe_load":
         [expert layers, n_routed_experts] tokens each of the router's experts
-        received, held here or not})."""
+        received, held here or not; and where this chip holds a share of
+        them "moe_path": [2] the expert layers whose dispatch fit the share's
+        row buffer and those that took the worst-case path, `ops/moe.py`})."""
         c = self.cfg
         if tokens.shape[1] > c.model_max_length:
             raise ValueError(f"sequence length {tokens.shape[1]} exceeds "
                              f"model_max_length")
         x = self.embed(tokens)
-        loads = []
+        loads, paths = [], jnp.zeros((2,), jnp.float32)
         for i, layer in enumerate(self.layers):
-            x, load = layer(x)
+            x, load, path = layer(x)
             if c.is_moe_layer(i):
                 loads.append(load)
+                paths = paths + path
         aux = {"moe_load": jnp.stack(loads)} if loads else {}
+        if loads and c.experts_held is not None:
+            aux["moe_path"] = paths
         return self.final_norm(x), aux
 
     def head(self, h):

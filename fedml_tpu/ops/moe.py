@@ -24,29 +24,61 @@ adapter), which is the one way the model that uses this op is trained
 (it says `frozen_base_only`, and `experiments/common.py::build_trainer`
 refuses such a module without `--lora_rank`).
 
-**A share of the experts (`first`).** An expert-parallel layer routes over
-more experts than a chip holds. With `first` given, `idx` names the router's
-experts and the matrices are those of experts first .. first + E - 1: a pair
-whose expert is absent leaves the dispatch before the grouped product (it
-gets no row, adds nothing to `y` and takes no gradient; `gate` keeps what
-the router gave it, renormalised over all k). How many pairs stay is data;
-the buffers are static and sized for the WORST case, every pair on a held
-expert (M = N k + E tile rows, as when all are held): no bound under it can
-never drop a pair. The grouped product skips the unused tiles (`n_tiles`);
-the elementwise work and the gathers around it run over all M rows, which at
-a quarter of the experts is about three times what the held pairs need
-(PERF.md section 7). With `first` None it is the path it was.
+**A share of the experts (`routed_experts_share`).** An expert-parallel
+layer routes over more experts than a chip holds. `idx` names the router's
+`routed` experts and the matrices are those of experts first .. first + E - 1:
+a pair whose expert is absent leaves the dispatch before the grouped product
+(it gets no row, adds nothing to `y` and takes no gradient; `gate` keeps what
+the router gave it, renormalised over all k). How many pairs stay is data,
+and the buffers are static. Sized for the worst case, every pair on a held
+expert (M = N k + E tile rows, as when all are held), the gathers and the
+elementwise passes around the grouped product run over three to four times
+the rows a quarter share's pairs need (the product itself skips unused
+tiles, `n_tiles`; XLA's passes do not). So a share's buffers hold
+
+    M_b = round_up(min(P, c P E / routed), tile) + E tile     (`share_rows`)
+
+rows: c = `SHARE_ROOM` times the FAIR share of a call's P = N k pairs (what a
+router that spreads its pairs evenly sends E of `routed` experts) and a tile
+of filler an expert. c is 2: over four seeds of the benchmark cell a joint
+call's held rows, filler counted, were 0.21-0.34 of P where the fair share
+is a quarter (PERF.md section 6, PR 39: Zipf token ids put a tenth of all
+tokens on one id, and whether its experts are held is the seed's), so the
+buffer, 0.56 P, has 1.66 times the largest call seen; at c = 1.5 it would
+have 1.29 times, too little for a path that is meant never to be left. No bound
+under the worst case can hold every routing, so nothing is ever dropped on
+its account: before anything is gathered the call COUNTS its held rows
+(`_held_rows`: `n_tiles` x tile), and one `lax.cond` on that scalar takes
+the bounded path where they fit M_b and the worst-case path, over M rows,
+where they do not. Both compute the same products in the same order for every
+pair, so their results are equal to the bit; the backward counts the same
+`idx` again, so both sides of the `custom_vjp` agree. Residuals have ONE
+shape, the bounded one (g, u [M_b, f] and the layout's four index vectors,
+which the two `cond`s would otherwise each compute): the worst-case forward
+hands back zeros of it and its backward lays its rows out and makes g, u
+again from x (two more grouped products, on that path only). The call also
+says which path it took (a [N] float32 flag, 1.0 the worst case), which
+`models/deepseek_v2.py::MoE` reduces and the `moe_load` event carries as
+`bounded` / `fallback`. Where c E / routed is 1 or more the bound IS the
+worst case and there is one path.
+Nothing of a share's path is pair-sized with a width: the backward works in
+row space (dy gathered to the rows and weighted by the row's gate there,
+dgate from the rows' g, u and the gate-free dh, no [N, k, d] residual), and
+rows go back to tokens one gather of [N, d] a slot (`_to_tokens`).
 
 Under `vmap` (the engine's client axis) with matrices that are the same for
 every lane, as a frozen base is, the lanes' tokens are dispatched TOGETHER:
 one sort, one grouped product over the cohort's tokens
 (`jax.custom_batching.custom_vmap`; a token's result does not depend on
-which other tokens share the call).
+which other tokens share the call). A share's count, bound and `cond` are
+then the joint call's, one scalar for all lanes (M_b of a joint call is at
+most lanes x M_b of a lane, so a lane's residual rows are a slice of it).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +87,11 @@ from fedml_tpu.ops.interpret import interpret_off_chip
 
 #: rows of one grouped-product tile (the MXU's 128 on a v5e)
 TILE = 128
+
+#: c of the module docstring: a share's row buffer holds this many times the
+#: fair share of a call's pairs (pairs x held / routed) before the call takes
+#: the worst-case path
+SHARE_ROOM = 2.0
 
 
 def top_k_route(scores, k: int):
@@ -69,18 +106,44 @@ def expert_load(idx, n_experts: int):
     return jnp.zeros((n_experts,), jnp.float32).at[idx.reshape(-1)].add(1.0)
 
 
-def _layout(idx, n_experts: int, tile: int, first=None):
+def _worst_rows(p: int, n_experts: int, tile: int) -> int:
+    """Rows that hold `p` pairs however they fall on `n_experts` groups."""
+    return -(-(p + n_experts * tile) // tile) * tile
+
+
+def share_rows(p: int, n_held: int, routed: int, tile: int = TILE,
+               room: float | None = None) -> int:
+    """M_b, the rows of a share's bounded buffer: `room` (`SHARE_ROOM`) times
+    the fair share of a call's `p` pairs (never more than all of them), and
+    a tile of filler a held expert; the worst case where that is no less."""
+    room = SHARE_ROOM if room is None else room
+    fair = math.ceil(room * p * n_held / routed)
+    return min(-(-min(p, fair) // tile) * tile + n_held * tile,
+               _worst_rows(p, n_held, tile))
+
+
+def _held_rows(idx, n_experts: int, tile: int, first: int):
+    """The rows a call's held pairs take, every group's tile filler counted
+    (`_layout`'s `n_tiles` x tile, before anything is sorted): a scalar."""
+    flat = idx.reshape(-1, 1) - first
+    sizes = (flat == jnp.arange(n_experts, dtype=jnp.int32)).sum(
+        axis=0, dtype=jnp.int32)
+    return (-(-sizes // tile) * tile).sum()
+
+
+def _layout(idx, n_experts: int, tile: int, first=None, m=None):
     """Where each (token, slot) pair sits among the tiled rows.
 
     idx [N, k] -> (src [M] the pair that feeds each row, P = N * k for a
     filler row; row_of_pair [P]; tile_group [M // tile] the expert of each
     tile; n_tiles [1] the tiles that hold rows), M = P + n_experts * tile
     rounded up to the tile. With `first`, `idx` counts the router's experts,
-    `n_experts` are held from there, and a pair on an absent one gets no row
-    (its `row_of_pair` is M, out of range)."""
+    `n_experts` are held from there, a pair on an absent one gets no row
+    (its `row_of_pair` is M, out of range), and M is `m` where that is given
+    (the caller has counted that the held rows fit: `_held_rows`)."""
     n, k = idx.shape
     p = n * k
-    m = -(-(p + n_experts * tile) // tile) * tile
+    m = m or _worst_rows(p, n_experts, tile)
     flat = idx.reshape(p)
     groups = n_experts
     if first is not None:
@@ -102,7 +165,8 @@ def _layout(idx, n_experts: int, tile: int, first=None):
     if first is None:
         src = jnp.full((m,), p, jnp.int32).at[dest].set(order)
     else:
-        dest = jnp.where(sorted_e == n_experts, m, dest)
+        # (rows past `m` exist only in the branch a `cond` does not take)
+        dest = jnp.where(sorted_e == n_experts, m, jnp.minimum(dest, m))
         src = jnp.full((m,), p, jnp.int32).at[dest].set(order, mode="drop")
         ends = ends[:n_experts]
     row_of_pair = jnp.zeros((p,), jnp.int32).at[order].set(dest)
@@ -175,35 +239,31 @@ def _rows(x, src, k: int):
     return jnp.take(x, src // k, axis=0, mode="fill", fill_value=0)
 
 
-def _pairs(rows, row_of_pair, n: int, k: int, share: bool = False):
-    """Tiled rows back to [N, k, width]; of a share, zeros for the pairs
-    that got no row."""
-    if share:
-        return jnp.take(rows, row_of_pair, axis=0, mode="fill",
-                        fill_value=0).reshape(n, k, rows.shape[-1])
+def _pairs(rows, row_of_pair, n: int, k: int):
+    """Tiled rows back to [N, k, width]."""
     return jnp.take(rows, row_of_pair, axis=0).reshape(n, k, rows.shape[-1])
 
 
-def _forward(x, idx, gate, wg, wu, wd, tile, first=None):
+def _forward(x, idx, gate, wg, wu, wd, tile):
     """-> (y [N, d], residuals (yk [N, k, d], g, u [M, f]))."""
     n, k = idx.shape
-    src, row_of_pair, grp, nt = _layout(idx, wg.shape[0], tile, first)
+    src, row_of_pair, grp, nt = _layout(idx, wg.shape[0], tile)
     mm = functools.partial(grouped_matmul, tile_group=grp, n_tiles=nt,
                            tile=tile)
     xs = _rows(x, src, k)
     g, u = mm(xs, wg), mm(xs, wu)
     h = (_silu(g.astype(jnp.float32)) * u.astype(jnp.float32)).astype(x.dtype)
-    yk = _pairs(mm(h, wd), row_of_pair, n, k, first is not None)
+    yk = _pairs(mm(h, wd), row_of_pair, n, k)
     # the k-term sums are elementwise (no float32 matrix product)
     y = (gate.astype(jnp.float32)[:, :, None]
          * yk.astype(jnp.float32)).sum(axis=1).astype(x.dtype)
     return y, (yk, g, u)
 
 
-def _backward(idx, gate, wg, wu, wd, yk, g, u, dy, tile, first=None):
+def _backward(idx, gate, wg, wu, wd, yk, g, u, dy, tile):
     """-> (dx [N, d], dgate [N, k])."""
     n, k = idx.shape
-    src, row_of_pair, grp, nt = _layout(idx, wg.shape[0], tile, first)
+    src, row_of_pair, grp, nt = _layout(idx, wg.shape[0], tile)
     mm_t = functools.partial(grouped_matmul, tile_group=grp, n_tiles=nt,
                              tile=tile, trans_rhs=True)
     dy32 = dy.astype(jnp.float32)
@@ -221,9 +281,76 @@ def _backward(idx, gate, wg, wu, wd, yk, g, u, dy, tile, first=None):
     # filler rows past a group's real ones carry what an unvisited tile left
     # there: they are never gathered back
     dxs = mm_t(dg, wg).astype(jnp.float32) + mm_t(du, wu).astype(jnp.float32)
-    dx = _pairs(dxs, row_of_pair, n, k, first is not None).sum(
-        axis=1).astype(dy.dtype)
+    dx = _pairs(dxs, row_of_pair, n, k).sum(axis=1).astype(dy.dtype)
     return dx, dgate.astype(gate.dtype)
+
+
+def _to_tokens(rows, at, gate, dtype):
+    """Rows back to tokens: out[n] = sum_j gate[n, j] * rows[at[n, j]] in
+    float32, a pair without a row (`at` out of range) adding nothing. rows
+    [m, d]; at [N, k]; gate [N, k] or None (weights of 1) -> [N, d] `dtype`.
+    One gather of [N, d] a slot, accumulated: no [N, k, d] array exists
+    (step 0 of PR 39: the fastest of four forms, PERF.md section 6)."""
+    out = jnp.zeros((at.shape[0], rows.shape[-1]), jnp.float32)
+    for j in range(at.shape[1]):
+        term = jnp.take(rows, at[:, j], axis=0, mode="fill",
+                        fill_value=0).astype(jnp.float32)
+        if gate is not None:
+            term = gate[:, j, None].astype(jnp.float32) * term
+        out = out + term
+    return out.astype(dtype)
+
+
+def _share_forward(x, idx, gate, wg, wu, wd, tile, first, m, keep):
+    """A share's forward over `m` tiled rows -> y [N, d], and with `keep`
+    the residuals (g, u [m, f], then the layout: src [m], row_of_pair [P],
+    tile_group [m // tile], n_tiles [1])."""
+    n, k = idx.shape
+    layout = _layout(idx, wg.shape[0], tile, first, m)
+    src, row_of_pair, grp, nt = layout
+    mm = functools.partial(grouped_matmul, tile_group=grp, n_tiles=nt,
+                           tile=tile)
+    xs = _rows(x, src, k)
+    g, u = mm(xs, wg), mm(xs, wu)
+    h = (_silu(g.astype(jnp.float32)) * u.astype(jnp.float32)).astype(x.dtype)
+    y = _to_tokens(mm(h, wd), row_of_pair.reshape(n, k), gate, x.dtype)
+    return (y, (g, u) + layout) if keep else y
+
+
+def _share_backward(x, idx, gate, wg, wu, wd, res, dy, tile, first, m):
+    """A share's backward over `m` tiled rows, every array in row space
+    -> (dx [N, d], dgate [N, k]). `res`: the forward's residuals, or None on
+    the worst-case path, which kept none: it lays the rows out and makes
+    g, u from x again."""
+    n, k = idx.shape
+    if res is None:
+        src, row_of_pair, grp, nt = _layout(idx, wg.shape[0], tile, first, m)
+    else:
+        # (lanes dispatched together hand back lanes x their own sizes of
+        # tiled rows, tiles and counts: the tails are filler)
+        g, u, src, row_of_pair, grp, nt = res
+        g, u, src, grp, nt = g[:m], u[:m], src[:m], grp[:m // tile], nt[:1]
+    mm = functools.partial(grouped_matmul, tile_group=grp, n_tiles=nt,
+                           tile=tile)
+    mm_t = functools.partial(mm, trans_rhs=True)
+    if res is None:
+        xs = _rows(x, src, k)
+        g, u = mm(xs, wg), mm(xs, wu)
+    g32, u32 = g.astype(jnp.float32), u.astype(jnp.float32)
+    gate_row = jnp.take(gate.reshape(-1).astype(jnp.float32), src,
+                        mode="fill", fill_value=0)
+    dh = mm_t(_rows(dy, src, k), wd).astype(jnp.float32)  # before the gate
+    sig = jax.nn.sigmoid(g32)
+    act = g32 * sig
+    # filler rows carry what an unvisited tile left there: no pair reads them
+    dgate = jnp.take((dh * (act * u32)).sum(axis=-1), row_of_pair,
+                     mode="fill", fill_value=0).reshape(n, k)
+    dh = dh * gate_row[:, None]
+    dg = (dh * u32 * sig * (1.0 + g32 * (1.0 - sig))).astype(dy.dtype)
+    du = (dh * act).astype(dy.dtype)
+    dxs = mm_t(dg, wg).astype(jnp.float32) + mm_t(du, wu).astype(jnp.float32)
+    return (_to_tokens(dxs, row_of_pair.reshape(n, k), None, dy.dtype),
+            dgate.astype(gate.dtype))
 
 
 def _lanes_together(fn, n_lane_args: int):
@@ -266,33 +393,128 @@ def _lanes_together(fn, n_lane_args: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _ops(tile: int, first=None):
+def _ops(tile: int):
     def fwd(x, idx, gate, wg, wu, wd):
-        return _forward(x, idx, gate, wg, wu, wd, tile, first)
+        return _forward(x, idx, gate, wg, wu, wd, tile)
 
     def bwd(idx, gate, yk, g, u, dy, wg, wu, wd):
-        return _backward(idx, gate, wg, wu, wd, yk, g, u, dy, tile, first)
+        return _backward(idx, gate, wg, wu, wd, yk, g, u, dy, tile)
 
     return _lanes_together(fwd, 3), _lanes_together(bwd, 6)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def routed_experts(x, idx, gate, wg, wu, wd, tile: int = TILE, first=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def routed_experts(x, idx, gate, wg, wu, wd, tile: int = TILE):
     """x [N, d], idx [N, k] int32, gate [N, k], wg/wu [E, d, f], wd [E, f, d]
-    -> y [N, d] in x's dtype (module docstring). `first`: the matrices are a
-    share, experts first .. first + E - 1 of those `idx` counts."""
-    return _ops(tile, first)[0](x, idx, gate, wg, wu, wd)[0]
+    -> y [N, d] in x's dtype (module docstring): every expert `idx` counts
+    is held."""
+    return _ops(tile)[0](x, idx, gate, wg, wu, wd)[0]
 
 
-def _re_fwd(x, idx, gate, wg, wu, wd, tile, first):
-    y, (yk, g, u) = _ops(tile, first)[0](x, idx, gate, wg, wu, wd)
+def _re_fwd(x, idx, gate, wg, wu, wd, tile):
+    y, (yk, g, u) = _ops(tile)[0](x, idx, gate, wg, wu, wd)
     return y, (idx, gate, yk, g, u, wg, wu, wd)
 
 
-def _re_bwd(tile, first, res, dy):
+def _re_bwd(tile, res, dy):
     idx, gate, yk, g, u, wg, wu, wd = res
-    dx, dgate = _ops(tile, first)[1](idx, gate, yk, g, u, dy, wg, wu, wd)
+    dx, dgate = _ops(tile)[1](idx, gate, yk, g, u, dy, wg, wu, wd)
     return dx, None, dgate, None, None, None
 
 
 routed_experts.defvjp(_re_fwd, _re_bwd)
+
+
+@functools.lru_cache(maxsize=None)
+def _share_ops(tile: int, first: int, routed: int, room: float):
+    """(forward, forward that keeps residuals, backward) of a share, each a
+    joint call over the lanes: the bounded path where the call's held rows
+    fit `share_rows`, else the worst-case path, by one `lax.cond` a call.
+    Every forward also says which it took: [N] float32, 1.0 the worst case
+    (per token, so that a lane's part of a joint call is a slice of it).
+    Each is a `jax.jit` of its own: a model's expert layers have one shape,
+    and a layer is traced for its forward, its rematerialised forward and
+    its backward, so both branches of every `cond` would be traced and
+    lowered a dozen times over; jitted, once a shape (XLA inlines the
+    calls)."""
+
+    def sizes(idx, wg):
+        p, n_held = idx.size, wg.shape[0]
+        return (share_rows(p, n_held, routed, tile, room),
+                _worst_rows(p, n_held, tile))
+
+    def fits(idx, wg, m_b):
+        return _held_rows(idx, wg.shape[0], tile, first) <= m_b
+
+    def forward(keep, x, idx, gate, wg, wu, wd):
+        m_b, m = sizes(idx, wg)
+        run = functools.partial(_share_forward, tile=tile, first=first)
+        bounded = functools.partial(run, m=m_b, keep=keep)
+        if m_b == m:        # the bound is the worst case: one path
+            return (bounded(x, idx, gate, wg, wu, wd),
+                    jnp.zeros((idx.shape[0],), jnp.float32))
+
+        def worst(*a):
+            y = run(*a, m=m, keep=False)
+            if not keep:
+                return y
+            # residuals have ONE shape, the bounded path's (`_share_forward`):
+            # zeros of it here, and the backward makes this path's own
+            return y, tuple(
+                [jnp.zeros((m_b, wg.shape[-1]), x.dtype) for _ in "gu"]
+                + [jnp.zeros((size,), jnp.int32)
+                   for size in (m_b, idx.size, m_b // tile, 1)])
+
+        fit = fits(idx, wg, m_b)
+        out = jax.lax.cond(fit, bounded, worst, x, idx, gate, wg, wu, wd)
+        return out, jnp.broadcast_to(1.0 - fit.astype(jnp.float32),
+                                     (idx.shape[0],))
+
+    def backward(x, idx, gate, g, u, src, row_of_pair, grp, nt, dy,
+                 wg, wu, wd):
+        res = (g, u, src, row_of_pair, grp, nt)
+        m_b, m = sizes(idx, wg)
+        run = functools.partial(_share_backward, tile=tile, first=first)
+        if m_b == m:
+            return run(x, idx, gate, wg, wu, wd, res, dy, m=m)
+
+        def bounded(x, idx, gate, res, dy, wg, wu, wd):
+            return run(x, idx, gate, wg, wu, wd, res, dy, m=m_b)
+
+        def worst(x, idx, gate, res, dy, wg, wu, wd):
+            return run(x, idx, gate, wg, wu, wd, None, dy, m=m)
+
+        # the same count of the same idx as the forward's: both sides agree
+        return jax.lax.cond(fits(idx, wg, m_b), bounded, worst,
+                            x, idx, gate, res, dy, wg, wu, wd)
+
+    return (_lanes_together(jax.jit(functools.partial(forward, False)), 3),
+            _lanes_together(jax.jit(functools.partial(forward, True)), 3),
+            _lanes_together(jax.jit(backward), 10))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def routed_experts_share(x, idx, gate, wg, wu, wd, first: int, routed: int,
+                         tile: int = TILE):
+    """`routed_experts` where the matrices are a SHARE of the router's
+    `routed` experts, first .. first + E - 1 of those `idx` counts (module
+    docstring) -> (y [N, d], worst [N] float32: 1.0 where the call's held
+    rows did not fit the bounded buffer and it took the worst-case path)."""
+    return _share_ops(tile, first, routed, SHARE_ROOM)[0](
+        x, idx, gate, wg, wu, wd)
+
+
+def _res_fwd(x, idx, gate, wg, wu, wd, first, routed, tile):
+    (y, res), worst = _share_ops(tile, first, routed, SHARE_ROOM)[1](
+        x, idx, gate, wg, wu, wd)
+    return (y, worst), (x, idx, gate, res, wg, wu, wd)
+
+
+def _res_bwd(first, routed, tile, saved, cot):
+    x, idx, gate, res, wg, wu, wd = saved
+    dx, dgate = _share_ops(tile, first, routed, SHARE_ROOM)[2](
+        x, idx, gate, *res, cot[0], wg, wu, wd)
+    return dx, None, dgate, None, None, None
+
+
+routed_experts_share.defvjp(_res_fwd, _res_bwd)

@@ -36,13 +36,17 @@ _LEDGER_KEYS = ("participated_count", "quarantined_count", "guard_retries",
                 "chaos_dropped", "chaos_nan", "chaos_corrupt")
 
 
-def moe_load_summary(load, held=None) -> Dict[str, float]:
+def moe_load_summary(load, held=None, path=None) -> Dict[str, float]:
     """The `moe_load` event's fields from a round's [expert layers, experts]
     counts of the tokens every routed expert received (summed over steps and
     lanes): the busiest expert's, the mean, and how many got none. `held`
     ((first, count) of the experts this chip holds, for a model that computes
     a share of an expert-parallel layer) adds the same of the held columns:
-    `held` (their pairs), `held_max`, `held_mean`, `held_empty`."""
+    `held` (their pairs), `held_max`, `held_mean`, `held_empty`. `path` (the
+    round's `moe_path` sums, such a model's too) adds `bounded` and
+    `fallback`: the expert-layer calls, a lane and step each, that fit the
+    share's row buffer and those that took the worst-case path
+    (`ops/moe.py`)."""
     import numpy as np
 
     load = np.asarray(load, np.float64)
@@ -54,6 +58,8 @@ def moe_load_summary(load, held=None) -> Dict[str, float]:
         out.update(held=float(mine.sum()), held_max=float(mine.max()),
                    held_mean=float(mine.mean()),
                    held_empty=int((mine == 0).sum()))
+    if path is not None:
+        out.update(bounded=float(path[0]), fallback=float(path[1]))
     return out
 
 
@@ -120,11 +126,13 @@ class RoundRecordLog:
                         self.bank.apply(block)
             # the reserved _moe_load key carries a routed-expert model's
             # per-expert token counts (a vector: history takes scalars); it
-            # rides the same fetch and leaves as a `moe_load` event
-            load = rec.pop("_moe_load", None)
+            # rides the same fetch and leaves as a `moe_load` event, as do
+            # the paths a share's dispatch took (_moe_path)
+            load, path = rec.pop("_moe_load", None), rec.pop("_moe_path", None)
             if load is not None:
                 self.tracer.event("moe_load", round=rec["round"],
-                                  **moe_load_summary(load, self.experts_held))
+                                  **moe_load_summary(load, self.experts_held,
+                                                     path))
             rec = {k: _scalar(v) for k, v in rec.items()}
             self.history.append(rec)
             if self.metrics_logger is not None:

@@ -205,7 +205,8 @@ def fold(records: List[Dict[str, Any]]) -> Dict[str, Any]:
         report["moe_load"] = {
             k: round(sum(e[k] for e in loads) / len(loads), 4)
             for k in ("max", "mean", "empty", "held", "held_max",
-                      "held_mean", "held_empty") if k in loads[0]}
+                      "held_mean", "held_empty", "bounded", "fallback")
+            if k in loads[0]}
     for k in ("platform", "cpu_cores", "cpu_capped", *_WORKLOAD_KEYS):
         if k in meta:
             report[k] = meta[k]

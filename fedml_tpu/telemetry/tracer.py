@@ -72,8 +72,11 @@ EVENT_SCHEMAS: Dict[str, set] = {
     # the mean and the number that got none. A model that computes a share
     # of an expert-parallel layer (`models/kimi_linear.py`) counts every
     # expert the router has and adds, of the experts this chip holds,
-    # `held` (their pairs), `held_max`, `held_mean`, `held_empty`; a model
-    # that holds them all (DeepSeek-V2) adds nothing
+    # `held` (their pairs), `held_max`, `held_mean`, `held_empty`, and how
+    # its dispatch ran: `bounded` / `fallback`, the expert-layer calls (a
+    # lane and step each) whose held rows fit the share's row buffer / took
+    # the exact worst-case path (`ops/moe.py`); a model that holds them all
+    # (DeepSeek-V2) adds nothing
     "moe_load": {"round", "max", "mean", "empty"},
     # which program a trace is of, once, from the model's `describe()`
     # (`experiments/common.py::build_trainer`): registry name, layers,

@@ -262,6 +262,45 @@ def test_dsv2lite_lora_round_compiles_and_never_returns_its_base(
     assert _fits(compiled) < 10e9
 
 
+def test_share_dispatch_compiles_with_both_paths_at_the_cells_shapes(
+        one_chip, no_compile_cache):
+    """`ops/moe.py::routed_experts_share` as Kimi Linear's training step runs
+    it: 64 of 256 experts of 2304 x 1024 bfloat16 held, 2 lanes x 8,192
+    tokens x top-8 in one joint call, forward and backward. The bounded path
+    (73,728 rows) and the worst-case one (139,264) are both in the program,
+    each behind one conditional a direction: 3 + 3 grouped products forward,
+    3 + 5 backward (the worst case makes g, u again)."""
+    from fedml_tpu.ops import moe
+
+    def step(x, idx, gate, wg, wu, wd):
+        def loss(x, gate):
+            y, worst = moe.routed_experts_share(x, idx, gate, wg, wu, wd,
+                                                0, 256)
+            return jnp.sum(y.astype(jnp.float32) ** 2), worst
+        return jax.value_and_grad(loss, (0, 1), has_aux=True)(x, gate)
+
+    assert moe.share_rows(2 * 8192 * 8, 64, 256) == 73728
+    args = _on(one_chip, (
+        jax.ShapeDtypeStruct((2, 8192, 2304), jnp.bfloat16),
+        jax.ShapeDtypeStruct((2, 8192, 8), jnp.int32),
+        jax.ShapeDtypeStruct((2, 8192, 8), jnp.float32),
+        jax.ShapeDtypeStruct((64, 2304, 1024), jnp.bfloat16),
+        jax.ShapeDtypeStruct((64, 2304, 1024), jnp.bfloat16),
+        jax.ShapeDtypeStruct((64, 1024, 2304), jnp.bfloat16)))
+    was = moe.interpret_off_chip
+    moe.interpret_off_chip = lambda k: False    # the guide's section 2
+    try:
+        compiled = jax.jit(jax.vmap(
+            step, in_axes=(0, 0, 0, None, None, None))).lower(*args).compile()
+    finally:
+        moe.interpret_off_chip = was
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 14
+    assert text.count(" conditional(") == 2
+    assert "bf16[73728,1024]" in text and "bf16[139264,1024]" in text
+    assert _fits(compiled) < 6e9
+
+
 def test_kda_kernels_compile_at_the_cells_shapes(one_chip, no_compile_cache):
     """`ops/kda.py`'s two Pallas calls as Kimi Linear's training step runs
     them: 2 lanes x 2 sequences x 4,096 tokens x 32 heads of 128, bfloat16

@@ -173,3 +173,20 @@ def test_kda_phase_interpreted_by_the_test():
     out = chip_smoke.kda_phase(shape=(1, 256, 2, 32), interpret=True)
     assert out["ok"] and out["phase"] == "kda" and out["interpret"]
     assert out["fwd_max_abs_err"] < 2e-5 and out["grad_max_abs_err"] < 1e-4
+
+
+def test_moe_share_phase_interpreted_on_the_cpu():
+    """The share-dispatch phase as the chip runs it, smaller (off the chip
+    the grouped product is interpreted): the router's own routing fits the
+    bounded buffer, every pair on held experts takes the worst-case path,
+    and both equal the dense computation."""
+    out = chip_smoke.moe_share_phase(tokens=128, d=32, f=16, held=2,
+                                     routed=8, k=2, tile=8)
+    assert out["ok"] and out["phase"] == "moe_share"
+    assert out["rows_bounded"] == 144 < out["rows_worst"] == 272
+    assert {n: p["took"] for n, p in out["paths"].items()} == {
+        "bounded": "bounded", "fallback": "fallback"}
+    assert out["paths"]["bounded"]["held_rows"] <= 144
+    assert out["paths"]["fallback"]["held_rows"] == 256
+    for path in out["paths"].values():
+        assert max(path["max_abs_err"].values()) < 3e-2

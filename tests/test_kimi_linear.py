@@ -140,7 +140,8 @@ UNCUT = {**SPEC, "num_experts": 8, "expert_share": {"of": 1, "index": 0}}
 def test_sigmoid_scores_select_by_score_plus_bias_renormalise_and_scale():
     p = _moe_params(jax.random.PRNGKey(1))
     x = jax.random.normal(jax.random.PRNGKey(2), (1, 24, 64))
-    y, load = _moe(UNCUT, x, p)
+    y, load, path = _moe(UNCUT, x, p)
+    assert path is None              # every expert held: no share's path
     flat = x.reshape(-1, 64)
     s = np.asarray(jax.nn.sigmoid(flat @ p["router"]["kernel"]), np.float64)
     sel = s + np.asarray(p["selection_bias"], np.float64)
@@ -163,21 +164,40 @@ def test_sigmoid_scores_select_by_score_plus_bias_renormalise_and_scale():
     assert np.asarray(load).tolist() == counts.tolist()
 
 
-def test_the_four_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("tokens,room,fallbacks", [
+    (16, 2.0, 0),       # 64 pairs: the bound IS the worst case
+    (512, 2.0, 0),      # 2,048 pairs: the bounded buffer, 1,280 rows
+    (512, 0.01, 3)])    # the same with no room (384 rows): the worst case
+def test_the_four_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
+        monkeypatch, tokens, room, fallbacks):
+    from fedml_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "SHARE_ROOM", room)
     p = _moe_params(jax.random.PRNGKey(4))
-    x = jax.random.normal(jax.random.PRNGKey(5), (2, 16, 64))
-    whole, load = _moe(UNCUT, x, p)
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, tokens, 64))
+    whole, load, _ = _moe(UNCUT, x, p)
     once = _swiglu(x, *[p["shared"][k]["kernel"] for k in (
         "gate_proj", "up_proj", "down_proj")])
-    parts = []
+    parts, took_worst = [], 0
+    pairs = 2 * tokens * 2
+    assert (moe.share_rows(pairs, 2, 8) < moe._worst_rows(pairs, 2, 128)) == (
+        tokens == 512)
     for i in range(4):
         mine = {**p, **{k: p[k][2 * i:2 * i + 2] for k in (
             "experts_gate", "experts_up", "experts_down")}}
-        y, share_load = _moe({**SPEC, "expert_share": {"of": 4, "index": i}},
-                             x, mine)
+        y, share_load, took = _moe(
+            {**SPEC, "expert_share": {"of": 4, "index": i}}, x, mine)
         assert np.asarray(share_load).tolist() == np.asarray(load).tolist()
+        # the path is the one the held experts' rows (tile filler counted)
+        # call for, and the sum is the uncut layer's on either
+        rows = sum(-(-int(c) // 128) * 128
+                   for c in np.asarray(load)[2 * i:2 * i + 2])
+        fell = float(rows > moe.share_rows(pairs, 2, 8))
+        assert np.asarray(took).tolist() == [1.0 - fell, fell]
+        took_worst += fell
         parts.append(y - once)
-    assert np.abs(np.asarray(sum(parts) + once - whole)).max() < 1e-4
+    assert np.abs(np.asarray(sum(parts) + once - whole)).max() < 2e-4
+    assert took_worst == fallbacks
     # and a share's gradient reaches x and the router through held pairs only
     g = jax.grad(lambda x: _moe({**SPEC, "expert_share": {"of": 4, "index": 3}},
                                 x, mine)[0].sum())(x)
@@ -210,13 +230,18 @@ def test_moe_load_counts_every_router_output_and_held_the_share(seeded):
     from fedml_tpu.algorithms.fedavg import _experts_held
 
     load, held = np.asarray(aux["moe_load"]), _experts_held(trainer)
-    assert set(aux) == {"moe_load"}    # which experts are held is no data
+    # which experts are held is no data; which path their dispatch took is
+    assert set(aux) == {"moe_load", "moe_path"}
+    assert np.asarray(aux["moe_path"]).tolist() == [2.0, 0.0]  # 2 expert layers
     assert load.shape == (2, 8) and held == (0, 2)
     assert load.sum(-1).tolist() == [3 * T * 2] * 2    # tokens x top-2 a layer
     said = moe_load_summary(load, held)
     assert said["held"] == load[:, :2].sum() and said["held_max"] == load[:, :2].max()
     assert said["held_mean"] == load[:, :2].mean() and said["max"] == load.max()
     assert "held" not in moe_load_summary(load)
+    with_path = moe_load_summary(load, held, aux["moe_path"])
+    assert (with_path["bounded"], with_path["fallback"]) == (2.0, 0.0)
+    assert not {"bounded", "fallback"} & set(said)
 
 
 def test_the_record_flush_says_held_and_build_trainer_says_the_model():
@@ -229,11 +254,22 @@ def test_the_record_flush_says_held_and_build_trainer_says_the_model():
     tracer = telemetry.Tracer()
     log = RoundRecordLog(tracer, [], experts_held=(0, 2))
     log.add({"round": 2, "round_time": 0.1,
-             "_moe_load": jnp.array([[3.0, 0.0, 5.0, 4.0]])})
+             "_moe_load": jnp.array([[3.0, 0.0, 5.0, 4.0]]),
+             "_moe_path": jnp.array([31.0, 1.0])})
     log.flush(2)
     (event,) = tracer.find_events("moe_load")
     assert (event["held"], event["held_max"], event["held_mean"],
             event["held_empty"], event["max"]) == (3.0, 3.0, 1.5, 1, 5.0)
+    assert (event["bounded"], event["fallback"]) == (31.0, 1.0)
+    assert "_moe_path" not in log.history[0]
+    # a model that holds every expert says neither
+    whole = telemetry.Tracer()
+    log = RoundRecordLog(whole, [])
+    log.add({"round": 0, "round_time": 0.1,
+             "_moe_load": jnp.array([[3.0, 0.0, 5.0, 4.0]])})
+    log.flush(0)
+    assert set(whole.find_events("moe_load")[0]) & {
+        "held", "bounded", "fallback"} == set()
 
     args = common.add_args(argparse.ArgumentParser()).parse_args([
         "--dataset", "tokens", "--model", "kimi_linear", "--lora_rank", "4",
@@ -252,6 +288,8 @@ def test_the_record_flush_says_held_and_build_trainer_says_the_model():
     report = fold([{"type": "event", **e} for e in tracer.events])
     assert report["model"]["mixers"] == {"kda": 2, "mla": 1}
     assert report["moe_load"]["held"] == 3.0
+    assert (report["moe_load"]["bounded"],
+            report["moe_load"]["fallback"]) == (31.0, 1.0)
 
 
 def test_a_model_that_holds_every_expert_says_no_share():
