@@ -373,10 +373,6 @@ def build_buffer_admit(donate_buffer: bool = False, codec=None):
             "fill": buf["fill"] + 1,
         }
 
-    from fedml_tpu import telemetry
-    telemetry.emit("round_fn_built", program="buffered.admit",
-                   donate=donate_buffer,
-                   codec=(codec.name if codec is not None else "none"))
     if not donate_buffer:
         return jax.jit(admit)
     jitted = jax.jit(admit, donate_argnums=(0,))
@@ -437,8 +433,6 @@ def build_buffer_commit(aggregator, discount_fn):
             jnp.where(alive, staleness, jnp.zeros((), jnp.float32)))
         return new_global, new_state, metrics
 
-    from fedml_tpu import telemetry
-    telemetry.emit("round_fn_built", program="buffered.commit", donate=False)
     return jax.jit(commit)
 
 

@@ -77,8 +77,6 @@ def build_client_step_fn(trainer, cfg, donate_data: bool = False,
             return result, cohort_stats(global_variables, result)
         return result
 
-    telemetry.emit("round_fn_built", program="buffered.client_step",
-                   donate=donate_data)
     from fedml_tpu.core.builder import donating_jit
 
     # x/y are staged fresh per round (and re-staged on a guard retry), so
@@ -454,7 +452,6 @@ def train_buffered(api, start_round: int, ckpt_dir, ckpt_every,
                     if not verdict.ok:
                         log.warning("guard: %s — retries exhausted, "
                                     "accepting the round", verdict.reason)
-                        tracer.event("guard_exhausted", round=round_idx)
                 record = {"round": round_idx, "round_time": rspan.elapsed(),
                           "buffer_commits": n_commits,
                           "committed_updates": host.committed_updates,

@@ -756,13 +756,6 @@ def build_round_fn_from_update(batched_update, aggregator,
             return new_global, new_state, metrics, stats
         return new_global, new_state, metrics
 
-    # ledger breadcrumb for multi-program debugging (async aggregation /
-    # multi-tenant scheduling build many round programs per process); no-op
-    # without an installed tracer, and never inside the traced function
-    from fedml_tpu import telemetry
-    telemetry.emit("round_fn_built", program="engine.round",
-                   donate=donate_data)
-
     from fedml_tpu.core.builder import donating_jit, donation_argnums
     jitted = donating_jit(round_fn, donation_argnums(donate_data=donate_data))
     if not base_outside:
@@ -883,9 +876,6 @@ def build_personal_round_fn(trainer, cfg: FedConfig, aggregator,
             return new_global, new_state, metrics, stats, new_personal
         return new_global, new_state, metrics, new_personal
 
-    from fedml_tpu import telemetry
-    telemetry.emit("round_fn_built", program="engine.round[pfl]",
-                   donate=donate_data)
 
     # donation covers agg state (0-1) and cohort data (2-4) exactly as the
     # shared round: `personal` is NOT donated — the drive loop's staged row
@@ -1079,9 +1069,6 @@ def build_superstep_fn_from_update(batched_update, cfg: FedConfig,
             return gv, st, metrics, stats
         return gv, st, metrics
 
-    from fedml_tpu import telemetry
-    telemetry.emit("round_fn_built", program=f"engine.superstep[k{num_rounds}]",
-                   donate=False, k=num_rounds)
     return jax.jit(superstep)
 
 

@@ -296,6 +296,12 @@ class FedAvgAPI(Checkpointable):
                     donate_data=config.pipeline_depth > 0,
                     collect_stats=True, lanes=self._lanes)
         self._personalized = bool(config.personalize)
+        # what `program_scopes` lowers: the jitted object built here, never
+        # what `round_fn` holds later (a caller may wrap it); the avals of
+        # its arguments are taken at the first dispatch
+        self._round_program = getattr(self.round_fn, "jitted", self.round_fn)
+        self._round_avals = None
+        self._scopes = None
         #: the attached personal adapter bank (models/adapter_bank.py) —
         #: set by train(bank=...) or directly; required when personalizing
         self.bank = None
@@ -373,23 +379,7 @@ class FedAvgAPI(Checkpointable):
             rng = jax.random.fold_in(jax.random.PRNGKey(cfg.seed), round_idx)
             if rng_salt:
                 rng = jax.random.fold_in(rng, rng_salt)
-            args = [self.global_variables, self.agg_state, staged.x,
-                    staged.y, staged.counts, rng]
-            if staged.personal is not None:
-                args.append(staged.personal["tree"])
-            if staged.participation is not None:
-                args.append(staged.participation)
-            new_personal = None
-            if self._personalized:
-                (self.global_variables, self.agg_state, train_metrics,
-                 stats, new_personal) = self.round_fn(*args)
-            elif self._round_has_stats:
-                (self.global_variables, self.agg_state, train_metrics,
-                 stats) = self.round_fn(*args)
-            else:
-                self.global_variables, self.agg_state, train_metrics = \
-                    self.round_fn(*args)
-                stats = None
+            train_metrics, stats, new_personal = self._dispatch(staged, rng)
         # the drive loops pick the cohort's ledger stats up from here; the
         # stats arrays stay device-resident until RoundRecordLog's deferred
         # flush fetch — train_one_round itself never syncs on them. The
@@ -401,6 +391,50 @@ class FedAvgAPI(Checkpointable):
             # ONE host round trip for the whole metrics dict — per-key float()
             # was one blocking transfer per metric
             return _host_metrics(train_metrics)
+
+    def _dispatch(self, staged, rng) -> tuple:
+        """One call of `round_fn` on a staged cohort: the new global model
+        and aggregator state land on the API -> (train_metrics, stats or
+        None, new personal rows or None). The first call keeps its
+        arguments' avals for `program_scopes` (taken before the call: the
+        program donates them)."""
+        args = [self.global_variables, self.agg_state, staged.x, staged.y,
+                staged.counts, rng]
+        if staged.personal is not None:
+            args.append(staged.personal["tree"])
+        if staged.participation is not None:
+            args.append(staged.participation)
+        if self._round_avals is None:
+            self._round_avals = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
+        out = self.round_fn(*args)
+        self.global_variables, self.agg_state, train_metrics = out[:3]
+        stats = out[3] if self._round_has_stats else None
+        return train_metrics, stats, out[4] if self._personalized else None
+
+    def program_scopes(self, tracer=None) -> dict[str, str]:
+        """{HLO instruction name: op_name} of the round program's compiled
+        text, for the instructions under a declared scope
+        (`telemetry/scopes.py`): what joins a device trace's ops to the
+        program's phases. Lowers and compiles the jitted object this API
+        built, on the avals of its first dispatch (the executable that ran,
+        from memory: no second backend compile), once; emits one
+        `program_scopes` event to `tracer` (the installed one, else the last
+        drive's). {} before any round ran, and where the compiled text lacks
+        this tree's scopes (`stale`: a compile cache another tree filled).
+        Nothing calls it in a run that is not traced."""
+        if self._scopes is not None or self._round_avals is None:
+            return self._scopes or {}
+        from fedml_tpu.telemetry.scopes import program_map
+
+        self._scopes, event = program_map(
+            self._round_program, self._round_avals,
+            getattr(self._round_program, "__name__", "round_fn"))
+        tracer = (tracer or telemetry.get_tracer()
+                  or getattr(self, "_last_tracer", None))
+        if tracer is not None:
+            tracer.event("program_scopes", **event)
+        return self._scopes
 
     def train(self, ckpt_dir: str | None = None, ckpt_every: int = 25,
               metrics_logger=None, chaos=None, guard=None,
@@ -571,7 +605,6 @@ class FedAvgAPI(Checkpointable):
                     elif not verdict.ok:
                         log.warning("guard: %s — retries exhausted, accepting "
                                     "the round", verdict.reason)
-                        tracer.event("guard_exhausted", round=round_idx)
                 if not rejected:
                     record = {"round": round_idx, "round_time": rspan.elapsed()}
                     block = self._ledger_block(round_idx, *self._last_dispatch)
@@ -1079,24 +1112,8 @@ class FedAvgAPI(Checkpointable):
                                                  round_idx)
                         if retries:
                             rng = jax.random.fold_in(rng, retries)
-                        args = [self.global_variables, self.agg_state, staged.x,
-                                staged.y, staged.counts, rng]
-                        if staged.personal is not None:
-                            args.append(staged.personal["tree"])
-                        if staged.participation is not None:
-                            args.append(staged.participation)
-                        new_personal = None
-                        if self._personalized:
-                            (self.global_variables, self.agg_state,
-                             train_metrics, stats,
-                             new_personal) = self.round_fn(*args)
-                        elif self._round_has_stats:
-                            (self.global_variables, self.agg_state,
-                             train_metrics, stats) = self.round_fn(*args)
-                        else:
-                            self.global_variables, self.agg_state, \
-                                train_metrics = self.round_fn(*args)
-                            stats = None
+                        train_metrics, stats, new_personal = self._dispatch(
+                            staged, rng)
                     inflight.append(train_metrics)
                     if len(inflight) > cfg.pipeline_depth:
                         # rounds are serialized on device by the global-variables
@@ -1131,7 +1148,6 @@ class FedAvgAPI(Checkpointable):
                         if not verdict.ok:
                             log.warning("guard: %s — retries exhausted, "
                                         "accepting the round", verdict.reason)
-                            tracer.event("guard_exhausted", round=round_idx)
                     record = {"round": round_idx, "round_time": rspan.elapsed()}
                     block = self._ledger_block(round_idx, staged, stats)
                     if block is not None:

@@ -164,6 +164,8 @@ class HloModule:
 _COMP_HEADER_RE = re.compile(r"^(ENTRY\s+)?%?([\w\.\-]+)[^=]*\{\s*$")
 _INST_RE = re.compile(r"^\s+(ROOT\s+)?%?([\w\.\-]+)\s*=\s*(.+)$")
 _OPCODE_RE = re.compile(r"([\w\-]+)\(")
+#: optimised text numbers long tuples (`/*index=5*/`), headers included
+_COMMENT_RE = re.compile(r"/\*.*?\*/")
 
 
 def _balanced(s: str, open_ch: str, close_ch: str, start: int = 0) -> int:
@@ -235,7 +237,7 @@ def parse_hlo_text(text: str) -> HloModule:
             continue
         stripped = line.strip()
         if comp is None:
-            m = _COMP_HEADER_RE.match(line)
+            m = _COMP_HEADER_RE.match(_COMMENT_RE.sub("", line))
             if m:
                 comp = HloComputation(name=m.group(2))
                 if m.group(1):

@@ -24,7 +24,6 @@ import struct
 import threading
 from typing import Callable
 
-from fedml_tpu import telemetry
 from fedml_tpu.comm.message import Message
 from fedml_tpu.robustness.retry import RetryError, RetryPolicy, call_with_retry
 
@@ -254,15 +253,13 @@ class MqttClient:
                 on_retry=on_retry,
             )
         except (RetryError, OSError):
-            telemetry.emit("mqtt_reconnect", client_id=self._client_id,
-                           ok=False, attempts=attempts[0])
+            log.warning("mqtt %s: reconnect gave up after %d attempt(s)",
+                        self._client_id, attempts[0])
             return False
         with self._send_lock:
             n_topics = len(self._cbs)
         log.info("mqtt %s: reconnected and resubscribed %d topic(s)",
                  self._client_id, n_topics)
-        telemetry.emit("mqtt_reconnect", client_id=self._client_id,
-                       ok=True, attempts=attempts[0])
         return True
 
     def _loop(self):

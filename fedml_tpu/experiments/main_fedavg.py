@@ -45,6 +45,10 @@ def run(args, aggregator_name: str = "fedavg"):
         history = api.train(ckpt_dir=args.ckpt_dir, metrics_logger=logger,
                             chaos=chaos, guard=guard, tracer=tracer,
                             ledger=ledger, bank=bank)
+        if getattr(args, "profile_rounds", None):
+            # the round program's scope map, for tools/trace_report.py
+            # --profile to join the profile's device ops with
+            api.program_scopes(tracer)
     finally:
         telemetry.uninstall(tracer)
         tracer.close()

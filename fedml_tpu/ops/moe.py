@@ -73,6 +73,14 @@ one sort, one grouped product over the cohort's tokens
 which other tokens share the call). A share's count, bound and `cond` are
 then the joint call's, one scalar for all lanes (M_b of a joint call is at
 most lanes x M_b of a lane, so a lane's residual rows are a slice of it).
+
+Both paths name their phases with `jax.named_scope`, which adds no
+operation: `moe_layout` (`_layout`, `_held_rows`: the sort, scatters and
+count), `moe_gather` (tokens into expert order; in the backward dy's and
+the gates' rows) and `moe_combine` (rows back to tokens, the gate weighting
+and the k-sum, and the gates' gradient), in the forward, the rematerialised
+forward and the backward alike. A device trace's ops are joined to them
+through the round program's op metadata (`telemetry/scopes.py`).
 """
 
 from __future__ import annotations
@@ -122,6 +130,7 @@ def share_rows(p: int, n_held: int, routed: int, tile: int = TILE,
                _worst_rows(p, n_held, tile))
 
 
+@jax.named_scope("moe_layout")
 def _held_rows(idx, n_experts: int, tile: int, first: int):
     """The rows a call's held pairs take, every group's tile filler counted
     (`_layout`'s `n_tiles` x tile, before anything is sorted): a scalar."""
@@ -131,6 +140,7 @@ def _held_rows(idx, n_experts: int, tile: int, first: int):
     return (-(-sizes // tile) * tile).sum()
 
 
+@jax.named_scope("moe_layout")
 def _layout(idx, n_experts: int, tile: int, first=None, m=None):
     """Where each (token, slot) pair sits among the tiled rows.
 
@@ -234,6 +244,7 @@ def _silu(x):
     return x * jax.nn.sigmoid(x)
 
 
+@jax.named_scope("moe_gather")
 def _rows(x, src, k: int):
     """The tiled rows' inputs: x[src // k], zeros for filler rows."""
     return jnp.take(x, src // k, axis=0, mode="fill", fill_value=0)
@@ -253,10 +264,12 @@ def _forward(x, idx, gate, wg, wu, wd, tile):
     xs = _rows(x, src, k)
     g, u = mm(xs, wg), mm(xs, wu)
     h = (_silu(g.astype(jnp.float32)) * u.astype(jnp.float32)).astype(x.dtype)
-    yk = _pairs(mm(h, wd), row_of_pair, n, k)
-    # the k-term sums are elementwise (no float32 matrix product)
-    y = (gate.astype(jnp.float32)[:, :, None]
-         * yk.astype(jnp.float32)).sum(axis=1).astype(x.dtype)
+    yd = mm(h, wd)
+    with jax.named_scope("moe_combine"):
+        yk = _pairs(yd, row_of_pair, n, k)
+        # the k-term sums are elementwise (no float32 matrix product)
+        y = (gate.astype(jnp.float32)[:, :, None]
+             * yk.astype(jnp.float32)).sum(axis=1).astype(x.dtype)
     return y, (yk, g, u)
 
 
@@ -267,10 +280,12 @@ def _backward(idx, gate, wg, wu, wd, yk, g, u, dy, tile):
     mm_t = functools.partial(grouped_matmul, tile_group=grp, n_tiles=nt,
                              tile=tile, trans_rhs=True)
     dy32 = dy.astype(jnp.float32)
-    dgate = (dy32[:, None, :] * yk.astype(jnp.float32)).sum(axis=-1)
-    dyk = (gate.astype(jnp.float32)[:, :, None] * dy32[:, None, :]).astype(
-        dy.dtype).reshape(n * k, -1)
-    dys = jnp.take(dyk, src, axis=0, mode="fill", fill_value=0)
+    with jax.named_scope("moe_combine"):
+        dgate = (dy32[:, None, :] * yk.astype(jnp.float32)).sum(axis=-1)
+    with jax.named_scope("moe_gather"):
+        dyk = (gate.astype(jnp.float32)[:, :, None] * dy32[:, None, :]).astype(
+            dy.dtype).reshape(n * k, -1)
+        dys = jnp.take(dyk, src, axis=0, mode="fill", fill_value=0)
     dh = mm_t(dys, wd).astype(jnp.float32)
     # (lanes dispatched together hand back lanes x M rows: the tail is filler)
     g32 = g[:src.shape[0]].astype(jnp.float32)
@@ -281,10 +296,12 @@ def _backward(idx, gate, wg, wu, wd, yk, g, u, dy, tile):
     # filler rows past a group's real ones carry what an unvisited tile left
     # there: they are never gathered back
     dxs = mm_t(dg, wg).astype(jnp.float32) + mm_t(du, wu).astype(jnp.float32)
-    dx = _pairs(dxs, row_of_pair, n, k).sum(axis=1).astype(dy.dtype)
+    with jax.named_scope("moe_combine"):
+        dx = _pairs(dxs, row_of_pair, n, k).sum(axis=1).astype(dy.dtype)
     return dx, dgate.astype(gate.dtype)
 
 
+@jax.named_scope("moe_combine")
 def _to_tokens(rows, at, gate, dtype):
     """Rows back to tokens: out[n] = sum_j gate[n, j] * rows[at[n, j]] in
     float32, a pair without a row (`at` out of range) adding nothing. rows
@@ -337,14 +354,16 @@ def _share_backward(x, idx, gate, wg, wu, wd, res, dy, tile, first, m):
         xs = _rows(x, src, k)
         g, u = mm(xs, wg), mm(xs, wu)
     g32, u32 = g.astype(jnp.float32), u.astype(jnp.float32)
-    gate_row = jnp.take(gate.reshape(-1).astype(jnp.float32), src,
-                        mode="fill", fill_value=0)
+    with jax.named_scope("moe_gather"):
+        gate_row = jnp.take(gate.reshape(-1).astype(jnp.float32), src,
+                            mode="fill", fill_value=0)
     dh = mm_t(_rows(dy, src, k), wd).astype(jnp.float32)  # before the gate
     sig = jax.nn.sigmoid(g32)
     act = g32 * sig
     # filler rows carry what an unvisited tile left there: no pair reads them
-    dgate = jnp.take((dh * (act * u32)).sum(axis=-1), row_of_pair,
-                     mode="fill", fill_value=0).reshape(n, k)
+    with jax.named_scope("moe_combine"):
+        dgate = jnp.take((dh * (act * u32)).sum(axis=-1), row_of_pair,
+                         mode="fill", fill_value=0).reshape(n, k)
     dh = dh * gate_row[:, None]
     dg = (dh * u32 * sig * (1.0 + g32 * (1.0 - sig))).astype(dy.dtype)
     du = (dh * act).astype(dy.dtype)

@@ -282,9 +282,4 @@ def build_sharded_buffer_fns(
         return sharded(global_variables, agg_state, buf, fill, commit_round,
                        rng)
 
-    from fedml_tpu import telemetry
-    telemetry.emit("round_fn_built", program="buffered.admit.sharded",
-                   donate=False)
-    telemetry.emit("round_fn_built", program="buffered.commit.sharded",
-                   donate=False)
     return jax.jit(admit_fn), jax.jit(commit_fn)

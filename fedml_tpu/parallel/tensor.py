@@ -630,12 +630,6 @@ def build_tensor_round_fn(trainer, cfg: FedConfig, aggregator,
     round_fn.lower = lower
     round_fn.sharding = sharding
     round_fn.donate_state = donate_state
-
-    from fedml_tpu import telemetry
-    telemetry.emit("round_fn_built", program="tensor.round",
-                   donate=donate_state,
-                   mesh=f"{n_cl}x{t_sz}",
-                   codec=(codec.name if codec is not None else "none"))
     return round_fn
 
 
@@ -802,8 +796,4 @@ def build_tensor_step_round_fn(trainer, cfg: FedConfig, aggregator,
     round_fn.lower = lower
     round_fn.sharding = sharding
     round_fn.donate_state = donate_state
-
-    from fedml_tpu import telemetry
-    telemetry.emit("round_fn_built", program="tensor.step",
-                   donate=donate_state, mesh=f"{n_cl}x{t_sz}")
     return round_fn

@@ -420,8 +420,6 @@ class Job:
                                      retry=retries)
                         self.api._ckpt_load(*snapshot)
                         rejected = True  # new attempt, new round span
-                    elif not verdict.ok:
-                        tracer.event("guard_exhausted", round=r)
                 if not rejected:
                     record = {"round": r, "round_time": rspan.elapsed()}
                     staged_used, stats = self.api._last_dispatch
@@ -488,8 +486,6 @@ class Job:
                                      retry=retries)
                         runner.restore(snapshot)
                         rejected = True
-                    elif not verdict.ok:
-                        tracer.event("guard_exhausted", round=r)
                 if not rejected:
                     record = {"round": r, "round_time": rspan.elapsed(),
                               "buffer_commits": out["n_commits"],

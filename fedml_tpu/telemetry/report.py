@@ -213,6 +213,41 @@ def fold(records: List[Dict[str, Any]]) -> Dict[str, Any]:
     return report
 
 
+def device_by_scope(event: Dict[str, Any], ops: List[list],
+                    program: list) -> Dict[str, Any]:
+    """The round program's device seconds by declared scope: a profile's
+    device ops ([short name, self seconds, executions], the instruction
+    name first) joined through a `program_scopes` event's `op_scopes`
+    (`telemetry/scopes.py`; a fusion counts under the op_name XLA gave it,
+    an op under every declared scope its path holds). `program`: [name,
+    executions, device seconds] of the round program. Coverage: the shares
+    of its device seconds whose instruction has an op_name at all
+    (`named_pct`), one under a declared scope (`scoped_pct`), and those of
+    the Pallas kernels' calls, which have none and are known by their
+    kernel's name (`kernel_pct`)."""
+    op_scopes = event.get("op_scopes", {})
+    kernels = set(event.get("kernels", ()))
+    by_scope: Dict[str, float] = {}
+    named = scoped = kernel = 0.0
+    for name, seconds, _ in ops:
+        held = op_scopes.get(name.split(" ")[0])
+        kernel += seconds if name.split(" ")[0] in kernels else 0.0
+        if held is None:
+            continue
+        named += seconds
+        scoped += seconds if held else 0.0
+        for scope in filter(None, held.split("/")):
+            by_scope[scope] = by_scope.get(scope, 0.0) + seconds
+    total = program[2] or float("nan")
+    return {"program": program[0], "executions": program[1],
+            "device_s": program[2],
+            "named_pct": round(100.0 * named / total, 3),
+            "scoped_pct": round(100.0 * scoped / total, 3),
+            "kernel_pct": round(100.0 * kernel / total, 3),
+            "by_scope_s": {k: round(v, 6) for k, v in sorted(
+                by_scope.items(), key=lambda kv: -kv[1])}}
+
+
 # ------------------------------------------------------------------- gate
 
 # Bench families that are NOT drive-throughput baselines and must never be

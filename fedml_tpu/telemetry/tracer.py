@@ -20,8 +20,7 @@ This module is the replacement: a zero-dependency `Tracer` that records
   span as attributes. While a `jax.profiler` session runs, every span is
   also a `TraceAnnotation("host:<name>")` on the profiler's clock.
 - **events**: schema-checked ledger entries (chaos injections, guard
-  verdicts/rollbacks, MQTT reconnects, compile-cache activity, committed
-  round records). Events are flushed to the JSONL sink the moment they
+  verdicts/rollbacks, compile-cache activity, committed round records). Events are flushed to the JSONL sink the moment they
   occur, so a crash mid-run (or mid-flush of the pipelined loop's deferred
   metrics) cannot lose what already happened.
 - **gauges**: free-form instantaneous measurements (pipeline occupancy,
@@ -64,7 +63,6 @@ EVENT_SCHEMAS: Dict[str, set] = {
     # round guard (robustness/guard.py + drive loop)
     "guard_verdict": {"round", "ok", "reason"},
     "guard_rollback": {"round", "retry"},
-    "guard_exhausted": {"round"},
     # unified record path (telemetry/records.py): the history record landed
     "round_committed": {"round"},
     # the same path, for a model with routed experts: the tokens its experts
@@ -90,22 +88,31 @@ EVENT_SCHEMAS: Dict[str, set] = {
     "superstep_committed": {"round", "rounds", "k"},
     # checkpointing (utils/checkpoint.py)
     "checkpoint_save": {"step"},
-    # self-healing comms (comm/mqtt.py)
-    "mqtt_reconnect": {"client_id", "ok", "attempts"},
     # persistent compile cache (utils/cache.py via jax.monitoring)
     "compile_cache": {"name"},
     # every backend compile, cache-served or not (utils/cache.py via
     # jax.monitoring); also carries `round` and `span`: the round and id of
     # the span open on the compiling thread (None outside any span)
     "compile": {"dur_s"},
-    # round-program construction (algorithms/engine.py)
-    "round_fn_built": {"program", "donate"},
+    # the round program's scope map, once, when asked for
+    # (`FedAvgAPI.program_scopes`, `telemetry/scopes.py`: a traced benchmark
+    # run's readers, the CLI under --profile_rounds): the jitted function's
+    # name, the instructions that can run as ops, how many of them have an
+    # op_name, {declared scope: instructions under it}, and `stale`: the
+    # lowered module names a declared scope the compiled text does not (an
+    # executable from a compile cache another tree filled; the map is then
+    # empty). Also carries
+    # `op_scopes`, {instruction: its declared scopes "/"-joined, "" for
+    # none} of every named instruction, and `kernels`, the Pallas calls
+    # (no op_name: JAX lowers them without metadata): what
+    # tools/trace_report.py joins a profile with
+    "program_scopes": {"program", "instructions", "scoped", "stale"},
     # buffered aggregation (algorithms/buffered.py): one per admitted client
     # update (`fill` = buffer occupancy after the admit) and one per buffer
     # commit (`size` = rows committed, staleness in dispatch rounds)
     "update_admitted": {"round", "birth", "fill"},
     "buffer_committed": {"round", "size", "staleness_p50", "staleness_max"},
-    # data plane download retries (data/acquire.py), mirroring mqtt_reconnect
+    # data plane download retries (data/acquire.py)
     "download_retry": {"attempt", "status", "backoff_s"},
     # JSONL sink rotation (--trace_max_mb): last record of a retired segment
     # names its archive file, so fold() can chain segments back together

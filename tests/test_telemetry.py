@@ -99,7 +99,6 @@ _SAMPLE_EVENTS = {
     "chaos_inject": dict(round=0, dropped=2, nan=1, corrupt=0),
     "guard_verdict": dict(round=0, ok=True, reason=""),
     "guard_rollback": dict(round=1, retry=1),
-    "guard_exhausted": dict(round=2),
     "round_committed": dict(round=0, participated_count=6.0),
     "moe_load": dict(round=0, max=431.0, mean=384.0, empty=0),
     "model_built": dict(model="kimi_linear", layers=5,
@@ -107,10 +106,13 @@ _SAMPLE_EVENTS = {
                         experts_routed=256),
     "superstep_committed": dict(round=4, rounds=4, k=4),
     "checkpoint_save": dict(step=5),
-    "mqtt_reconnect": dict(client_id="c0", ok=True, attempts=2),
     "compile_cache": dict(name="persistent_cache_hit"),
     "compile": dict(dur_s=0.25, round=0, span=3),
-    "round_fn_built": dict(program="engine.round", donate=True),
+    "program_scopes": dict(program="round_fn", instructions=5448, named=4151,
+                           scoped={"experts": 519, "moe_layout": 96},
+                           stale=False,
+                           op_scopes={"fusion.12": "experts/moe_layout"},
+                           kernels=["moe_grouped_matmul.145"]),
     "update_admitted": dict(round=3, birth=1, fill=2),
     "buffer_committed": dict(round=3, size=4, staleness_p50=1.0,
                              staleness_max=2.0),

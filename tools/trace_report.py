@@ -6,6 +6,10 @@ Usage:
   python tools/trace_report.py TRACE.jsonl --out report.json  # write report
   python tools/trace_report.py TRACE.jsonl --gate             # exit 1 on a
                                                               # regression
+  python tools/trace_report.py RUN_DIR/TRACE.jsonl --profile RUN_DIR/trace
+      # + the round program's device seconds by declared scope (a run
+      # under --profile_rounds A:B leaves both: its TRACE.jsonl holds the
+      # `program_scopes` event the join needs)
 
 The gate (ROADMAP open item 5) compares the trace's measured rounds/s
 against the newest BENCH_*.json baseline within --tolerance (default 0.5x,
@@ -26,11 +30,29 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from fedml_tpu.telemetry.report import (  # noqa: E402
     DEFAULT_TOLERANCE,
+    device_by_scope,
     fold,
     load_trace,
     newest_bench,
     run_gate,
 )
+
+
+def _scopes(records, profile_dir: str) -> dict:
+    """The join of a profile with the trace's `program_scopes` event, or
+    what is missing for it."""
+    from benchmarks.harness.trace import read
+
+    said = [r for r in records if r.get("kind") == "program_scopes"]
+    if not said:
+        return {"error": "no program_scopes event in the trace"}
+    if said[-1]["stale"]:
+        return {"error": "the executable's text lacks this tree's scopes "
+                         "(stale: a compile cache another tree filled)"}
+    trace = read(profile_dir, 1)
+    if trace is None or not trace["modules"]:
+        return {"error": f"no device trace under {profile_dir}"}
+    return device_by_scope(said[-1], trace["ops"], trace["modules"][0])
 
 
 def main(argv=None) -> int:
@@ -51,9 +73,16 @@ def main(argv=None) -> int:
     parser.add_argument("--self-test-throttle", type=float, default=None,
                         help="scale measured rounds/s by this factor before "
                              "gating (CI proves the gate trips)")
+    parser.add_argument("--profile", default=None,
+                        help="a jax.profiler trace directory of the same "
+                             "run: print the round program's device "
+                             "seconds by declared scope")
     args = parser.parse_args(argv)
 
-    report = fold(load_trace(args.trace))
+    records = load_trace(args.trace)
+    report = fold(records)
+    if args.profile:
+        report["scopes"] = _scopes(records, args.profile)
     if args.self_test_throttle is not None:
         report["value"] = round(report["value"] * args.self_test_throttle, 4)
         report["throttled_for_self_test"] = args.self_test_throttle
