@@ -17,20 +17,23 @@ re-use it and every matrix is read from HBM once; `trans_rhs` contracts
 with the matrix's last dimension (the activation gradient) without a
 transposed copy of the weights. Off the chip it runs in interpret mode.
 
-Differentiation: `routed_experts` is a `jax.custom_vjp` whose backward gives
-the gradient with respect to `x` and `gate` ONLY. The expert matrices get
-none: they are a frozen base (`models/lora.py` gives 3-D kernels no
-adapter), which is the one way the model that uses this op is trained
-(it says `frozen_base_only`, and `experiments/common.py::build_trainer`
-refuses such a module without `--lora_rank`).
+**One dispatch (`routed_experts_share`), for a share of the experts or all
+of them.** An expert-parallel layer routes over more experts than a chip
+holds. `idx` names the router's `routed` experts and the matrices are those
+of experts first .. first + E - 1: a pair whose expert is absent leaves the
+dispatch before the grouped product (it gets no row, adds nothing to `y` and
+takes no gradient; `gate` keeps what the router gave it, renormalised over
+all k). A layer whose chip holds every expert (`routed_experts`) is the share
+with `first` None and `routed` = E: no pair is absent, the layout keeps no
+group for absent ones, and its bound is the worst case (below), so it is one
+path with no count and no `cond`. The backward gives the gradient with
+respect to `x` and `gate` ONLY: the expert matrices are a frozen base
+(`models/lora.py` gives 3-D kernels no adapter; the models that use this op
+say `frozen_base_only`, and `experiments/common.py::build_trainer` refuses
+such a module without `--lora_rank`).
 
-**A share of the experts (`routed_experts_share`).** An expert-parallel
-layer routes over more experts than a chip holds. `idx` names the router's
-`routed` experts and the matrices are those of experts first .. first + E - 1:
-a pair whose expert is absent leaves the dispatch before the grouped product
-(it gets no row, adds nothing to `y` and takes no gradient; `gate` keeps what
-the router gave it, renormalised over all k). How many pairs stay is data,
-and the buffers are static. Sized for the worst case, every pair on a held
+**A share's buffers.** How many pairs stay is data, and the buffers are
+static. Sized for the worst case, every pair on a held
 expert (M = N k + E tile rows, as when all are held), the gathers and the
 elementwise passes around the grouped product run over three to four times
 the rows a quarter share's pairs need (the product itself skips unused
@@ -60,8 +63,8 @@ again from x (two more grouped products, on that path only). The call also
 says which path it took (a [N] float32 flag, 1.0 the worst case), which
 `models/deepseek_v2.py::MoE` reduces and the `moe_load` event carries as
 `bounded` / `fallback`. Where c E / routed is 1 or more the bound IS the
-worst case and there is one path.
-Nothing of a share's path is pair-sized with a width: the backward works in
+worst case and there is one path; with every expert held it always is.
+Nothing of the dispatch is pair-sized with a width: the backward works in
 row space (dy gathered to the rows and weighted by the row's gate there,
 dgate from the rows' g, u and the gate-free dh, no [N, k, d] residual), and
 rows go back to tokens one gather of [N, d] a slot (`_to_tokens`).
@@ -74,7 +77,7 @@ which other tokens share the call). A share's count, bound and `cond` are
 then the joint call's, one scalar for all lanes (M_b of a joint call is at
 most lanes x M_b of a lane, so a lane's residual rows are a slice of it).
 
-Both paths name their phases with `jax.named_scope`, which adds no
+The dispatch names its phases with `jax.named_scope`, which adds no
 operation: `moe_layout` (`_layout`, `_held_rows`: the sort, scatters and
 count), `moe_gather` (tokens into expert order; in the backward dy's and
 the gates' rows) and `moe_combine` (rows back to tokens, the gate weighting
@@ -141,19 +144,19 @@ def _held_rows(idx, n_experts: int, tile: int, first: int):
 
 
 @jax.named_scope("moe_layout")
-def _layout(idx, n_experts: int, tile: int, first=None, m=None):
-    """Where each (token, slot) pair sits among the tiled rows.
+def _layout(idx, n_experts: int, tile: int, first, m: int):
+    """Where each (token, slot) pair sits among `m` tiled rows.
 
-    idx [N, k] -> (src [M] the pair that feeds each row, P = N * k for a
-    filler row; row_of_pair [P]; tile_group [M // tile] the expert of each
-    tile; n_tiles [1] the tiles that hold rows), M = P + n_experts * tile
-    rounded up to the tile. With `first`, `idx` counts the router's experts,
-    `n_experts` are held from there, a pair on an absent one gets no row
-    (its `row_of_pair` is M, out of range), and M is `m` where that is given
-    (the caller has counted that the held rows fit: `_held_rows`)."""
+    idx [N, k] -> (src [m] the pair that feeds each row, P = N * k for a
+    filler row; row_of_pair [P]; tile_group [m // tile] the expert of each
+    tile; n_tiles [1] the tiles that hold rows). `idx` counts the router's
+    experts, `n_experts` are held from `first` and a pair on an absent one
+    gets no row (its `row_of_pair` is m, out of range); `first` None: every
+    expert is held. `m` holds the call's rows: the worst case
+    (`_worst_rows`), or a bound the caller has counted them under
+    (`_held_rows`)."""
     n, k = idx.shape
     p = n * k
-    m = m or _worst_rows(p, n_experts, tile)
     flat = idx.reshape(p)
     groups = n_experts
     if first is not None:
@@ -250,57 +253,6 @@ def _rows(x, src, k: int):
     return jnp.take(x, src // k, axis=0, mode="fill", fill_value=0)
 
 
-def _pairs(rows, row_of_pair, n: int, k: int):
-    """Tiled rows back to [N, k, width]."""
-    return jnp.take(rows, row_of_pair, axis=0).reshape(n, k, rows.shape[-1])
-
-
-def _forward(x, idx, gate, wg, wu, wd, tile):
-    """-> (y [N, d], residuals (yk [N, k, d], g, u [M, f]))."""
-    n, k = idx.shape
-    src, row_of_pair, grp, nt = _layout(idx, wg.shape[0], tile)
-    mm = functools.partial(grouped_matmul, tile_group=grp, n_tiles=nt,
-                           tile=tile)
-    xs = _rows(x, src, k)
-    g, u = mm(xs, wg), mm(xs, wu)
-    h = (_silu(g.astype(jnp.float32)) * u.astype(jnp.float32)).astype(x.dtype)
-    yd = mm(h, wd)
-    with jax.named_scope("moe_combine"):
-        yk = _pairs(yd, row_of_pair, n, k)
-        # the k-term sums are elementwise (no float32 matrix product)
-        y = (gate.astype(jnp.float32)[:, :, None]
-             * yk.astype(jnp.float32)).sum(axis=1).astype(x.dtype)
-    return y, (yk, g, u)
-
-
-def _backward(idx, gate, wg, wu, wd, yk, g, u, dy, tile):
-    """-> (dx [N, d], dgate [N, k])."""
-    n, k = idx.shape
-    src, row_of_pair, grp, nt = _layout(idx, wg.shape[0], tile)
-    mm_t = functools.partial(grouped_matmul, tile_group=grp, n_tiles=nt,
-                             tile=tile, trans_rhs=True)
-    dy32 = dy.astype(jnp.float32)
-    with jax.named_scope("moe_combine"):
-        dgate = (dy32[:, None, :] * yk.astype(jnp.float32)).sum(axis=-1)
-    with jax.named_scope("moe_gather"):
-        dyk = (gate.astype(jnp.float32)[:, :, None] * dy32[:, None, :]).astype(
-            dy.dtype).reshape(n * k, -1)
-        dys = jnp.take(dyk, src, axis=0, mode="fill", fill_value=0)
-    dh = mm_t(dys, wd).astype(jnp.float32)
-    # (lanes dispatched together hand back lanes x M rows: the tail is filler)
-    g32 = g[:src.shape[0]].astype(jnp.float32)
-    u32 = u[:src.shape[0]].astype(jnp.float32)
-    sig = jax.nn.sigmoid(g32)
-    dg = (dh * u32 * sig * (1.0 + g32 * (1.0 - sig))).astype(dy.dtype)
-    du = (dh * g32 * sig).astype(dy.dtype)
-    # filler rows past a group's real ones carry what an unvisited tile left
-    # there: they are never gathered back
-    dxs = mm_t(dg, wg).astype(jnp.float32) + mm_t(du, wu).astype(jnp.float32)
-    with jax.named_scope("moe_combine"):
-        dx = _pairs(dxs, row_of_pair, n, k).sum(axis=1).astype(dy.dtype)
-    return dx, dgate.astype(gate.dtype)
-
-
 @jax.named_scope("moe_combine")
 def _to_tokens(rows, at, gate, dtype):
     """Rows back to tokens: out[n] = sum_j gate[n, j] * rows[at[n, j]] in
@@ -319,7 +271,7 @@ def _to_tokens(rows, at, gate, dtype):
 
 
 def _share_forward(x, idx, gate, wg, wu, wd, tile, first, m, keep):
-    """A share's forward over `m` tiled rows -> y [N, d], and with `keep`
+    """The forward over `m` tiled rows -> y [N, d], and with `keep`
     the residuals (g, u [m, f], then the layout: src [m], row_of_pair [P],
     tile_group [m // tile], n_tiles [1])."""
     n, k = idx.shape
@@ -335,7 +287,7 @@ def _share_forward(x, idx, gate, wg, wu, wd, tile, first, m, keep):
 
 
 def _share_backward(x, idx, gate, wg, wu, wd, res, dy, tile, first, m):
-    """A share's backward over `m` tiled rows, every array in row space
+    """The backward over `m` tiled rows, every array in row space
     -> (dx [N, d], dgate [N, k]). `res`: the forward's residuals, or None on
     the worst-case path, which kept none: it lays the rows out and makes
     g, u from x again."""
@@ -412,43 +364,11 @@ def _lanes_together(fn, n_lane_args: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _ops(tile: int):
-    def fwd(x, idx, gate, wg, wu, wd):
-        return _forward(x, idx, gate, wg, wu, wd, tile)
-
-    def bwd(idx, gate, yk, g, u, dy, wg, wu, wd):
-        return _backward(idx, gate, wg, wu, wd, yk, g, u, dy, tile)
-
-    return _lanes_together(fwd, 3), _lanes_together(bwd, 6)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def routed_experts(x, idx, gate, wg, wu, wd, tile: int = TILE):
-    """x [N, d], idx [N, k] int32, gate [N, k], wg/wu [E, d, f], wd [E, f, d]
-    -> y [N, d] in x's dtype (module docstring): every expert `idx` counts
-    is held."""
-    return _ops(tile)[0](x, idx, gate, wg, wu, wd)[0]
-
-
-def _re_fwd(x, idx, gate, wg, wu, wd, tile):
-    y, (yk, g, u) = _ops(tile)[0](x, idx, gate, wg, wu, wd)
-    return y, (idx, gate, yk, g, u, wg, wu, wd)
-
-
-def _re_bwd(tile, res, dy):
-    idx, gate, yk, g, u, wg, wu, wd = res
-    dx, dgate = _ops(tile)[1](idx, gate, yk, g, u, dy, wg, wu, wd)
-    return dx, None, dgate, None, None, None
-
-
-routed_experts.defvjp(_re_fwd, _re_bwd)
-
-
-@functools.lru_cache(maxsize=None)
-def _share_ops(tile: int, first: int, routed: int, room: float):
-    """(forward, forward that keeps residuals, backward) of a share, each a
-    joint call over the lanes: the bounded path where the call's held rows
-    fit `share_rows`, else the worst-case path, by one `lax.cond` a call.
+def _share_ops(tile: int, first: int | None, routed: int, room: float):
+    """(forward, forward that keeps residuals, backward) of the dispatch,
+    each a joint call over the lanes: the bounded path where the call's held
+    rows fit `share_rows`, else the worst-case path, by one `lax.cond` a
+    call; one path where the bound is the worst case (every expert held).
     Every forward also says which it took: [N] float32, 1.0 the worst case
     (per token, so that a lane's part of a joint call is a slice of it).
     Each is a `jax.jit` of its own: a model's expert layers have one shape,
@@ -459,8 +379,11 @@ def _share_ops(tile: int, first: int, routed: int, room: float):
 
     def sizes(idx, wg):
         p, n_held = idx.size, wg.shape[0]
-        return (share_rows(p, n_held, routed, tile, room),
-                _worst_rows(p, n_held, tile))
+        m = _worst_rows(p, n_held, tile)
+        # every expert held: the call's rows are all of its pairs, whatever
+        # the room
+        return (m if first is None
+                else share_rows(p, n_held, routed, tile, room)), m
 
     def fits(idx, wg, m_b):
         return _held_rows(idx, wg.shape[0], tile, first) <= m_b
@@ -513,11 +436,12 @@ def _share_ops(tile: int, first: int, routed: int, room: float):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
-def routed_experts_share(x, idx, gate, wg, wu, wd, first: int, routed: int,
-                         tile: int = TILE):
-    """`routed_experts` where the matrices are a SHARE of the router's
-    `routed` experts, first .. first + E - 1 of those `idx` counts (module
-    docstring) -> (y [N, d], worst [N] float32: 1.0 where the call's held
+def routed_experts_share(x, idx, gate, wg, wu, wd, first: int | None,
+                         routed: int, tile: int = TILE):
+    """x [N, d], idx [N, k] int32, gate [N, k], wg/wu [E, d, f], wd [E, f, d]
+    where the matrices are experts first .. first + E - 1 of the router's
+    `routed` that `idx` counts (module docstring; `first` None: all of them)
+    -> (y [N, d] in x's dtype, worst [N] float32: 1.0 where the call's held
     rows did not fit the bounded buffer and it took the worst-case path)."""
     return _share_ops(tile, first, routed, SHARE_ROOM)[0](
         x, idx, gate, wg, wu, wd)
@@ -537,3 +461,10 @@ def _res_bwd(first, routed, tile, saved, cot):
 
 
 routed_experts_share.defvjp(_res_fwd, _res_bwd)
+
+
+def routed_experts(x, idx, gate, wg, wu, wd, tile: int = TILE):
+    """The dispatch where every expert `idx` counts is held -> y [N, d]: the
+    share with `first` None and `routed` = E, one path with no `cond`."""
+    return routed_experts_share(x, idx, gate, wg, wu, wd, None, wg.shape[0],
+                                tile)[0]

@@ -361,26 +361,40 @@ def test_no_array_of_the_bounded_path_has_more_rows_than_the_bound_and_a_width()
     assert (M_WORST, F) in big
 
 
-def test_with_every_expert_held_the_program_is_the_one_it_was(case):
-    """`routed_experts` traces to the jaxpr it traced to before a share had
-    a path of its own (sha256 recorded from the parent of PR 39)."""
-    import hashlib
-    import re
+def _primitives(jaxpr, out):
+    """Names of every primitive a jaxpr runs, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        out.add(eqn.primitive.name)
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                _primitives(sub, out)
+    return out
 
-    zeros = (jnp.zeros((N, D)), jnp.zeros((N, K), jnp.int32),
-             jnp.zeros((N, K)))
-    w = (jnp.zeros((E, D, F)), jnp.zeros((E, D, F)), jnp.zeros((E, F, D)))
 
-    def step(x, g):
+@pytest.mark.parametrize("lanes", [0, 3], ids=["alone", "lanes_together"])
+def test_with_every_expert_held_the_dispatch_is_one_path_in_row_space(
+        case, lanes):
+    """Every expert held is the share with `first` None and `routed` = E:
+    no count and no `cond`, and nothing its forward or backward makes is
+    pair-sized with a width ([N k, >= D] or [N, k, >= D], nor the joint
+    call's [lanes N k, ..] of them): the rows go back to their tokens one
+    [N, d] gather a slot."""
+    x, w, gate, idx = case
+
+    def step(x, i, g):
         return jax.value_and_grad(lambda x, g: (moe.routed_experts(
-            x, zeros[1], g, *w, TILE) ** 2).sum(), (0, 1))(x, g)
+            x, i, g, *w, TILE) ** 2).sum(), (0, 1))(x, g)
 
-    # (outside `vmap` the text names the batching rule by its address)
-    one = re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(step)(
-        zeros[0], zeros[2])))
-    lanes = str(jax.make_jaxpr(jax.vmap(step))(
-        jnp.stack([zeros[0]] * 2), jnp.stack([zeros[2]] * 2)))
-    assert hashlib.sha256(one.encode()).hexdigest() == (
-        "d6d8e58d506a4d6ce118e39e8e2193cb32f19486e5c67153c5cf02efa1ddaf41")
-    assert hashlib.sha256(lanes.encode()).hexdigest() == (
-        "e4596d64a15ffc27fe6f389bdac6e8405fb48879cee9c72e6843f72cac5a6242")
+    if lanes:
+        args = [jnp.stack([a] + [a[::-1]] * (lanes - 1))
+                for a in (x, idx, gate)]
+        jaxpr = jax.make_jaxpr(jax.vmap(step))(*args).jaxpr
+    else:
+        jaxpr = jax.make_jaxpr(step)(x, idx, gate).jaxpr
+    assert "cond" not in _primitives(jaxpr, set())
+    n = N * max(lanes, 1)
+    pairs = {(n * K,), (n, K), (lanes, N * K), (lanes, N, K)}
+    shapes = _array_shapes(jaxpr, [])
+    assert (moe._worst_rows(n * K, E, TILE), F) in shapes
+    for shape in shapes:
+        assert not (shape[:-1] in pairs and shape[-1] >= D), shape

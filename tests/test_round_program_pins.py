@@ -3,7 +3,9 @@
 0's cohort, each cell's own configuration with its data cut. A change of
 lowering metadata alone (a `jax.named_scope`, PR 40) leaves every hash as it
 is; a change of a cell's arithmetic moves its hash, and the PR that makes it
-says so and re-pins it here."""
+says so and re-pins it here. `dsv2lite_lora` was re-pinned when its expert
+layers moved to the one row-space dispatch (`ops/moe.py`, every expert
+held); the other three hashes stayed as they were."""
 
 import hashlib
 import json
@@ -23,7 +25,7 @@ PINS = {
     "cross_silo":
         "5271f44fc834b77f139f8b26b2fa05ff7f2c9a3497616074622b3bdf7da6be92",
     "dsv2lite_lora":
-        "3b36bf55cc73652a49800effe9fecf00c347e2a30527643a3323572312666b41",
+        "fbe95401e33fbd57561b9f93f7c364464343cadbe4b59d66358997a3f019766b",
     "kimi_linear_lora":
         "444d729215f3d8ab0e77b5ec6aa967b1f78148056972e4e6dd14568992baf739",
 }
