@@ -26,6 +26,8 @@ from benchmarks.harness import correct, data, flops, trace  # noqa: E402
 from benchmarks.harness import readers  # noqa: E402
 
 TINY = os.path.join(ROOT, "tests", "benchmark", "cells", "BENCHMARK.json")
+REPO = os.path.join(ROOT, "BENCHMARK.json")
+LAYERS = os.path.join(ROOT, "benchmarks", "layer_metrics")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 
 
@@ -445,9 +447,11 @@ def test_slots_outside_the_harness_own_counts_end_the_run(slots, said):
         assert readers.executed_flops_roofline(spans_ctx(sound), {}) > 0
 
 
-@pytest.mark.parametrize("manifest", [os.path.join(ROOT, "BENCHMARK.json"),
-                                      TINY])
-def test_every_entry_resolves_to_files_and_every_moves_is_reported(manifest):
+def resolves_to_files_and_every_moves_is_reported(manifest,
+                                                  metric_dirs=(LAYERS,)):
+    """Every cell of `manifest` resolves to its files and reports the
+    end-to-end metric each of its per-layer metrics moves; a per-layer
+    metric's file is looked up in `metric_dirs`, first found."""
     with open(manifest) as f:
         bench = json.load(f)
     e2e = {m["name"]: m for m in bench["end_to_end"]}
@@ -460,8 +464,10 @@ def test_every_entry_resolves_to_files_and_every_moves_is_reported(manifest):
         assert spec["per_layer"]
         for m in spec["per_layer"]:
             assert m["moves"] in reported, (w["name"], m["name"])
-            with open(os.path.join(ROOT, "benchmarks", "layer_metrics",
-                                   m["name"] + ".json")) as f:
+            found = [p for p in (os.path.join(d, m["name"] + ".json")
+                                 for d in metric_dirs) if os.path.exists(p)]
+            assert found, (w["name"], m["name"])
+            with open(found[0]) as f:
                 lm = json.load(f)
             assert lm["reader"] in readers.GENERAL or lm["reader"] == "module"
             assert (lm["layer"], lm["unit"], lm["moves"]) == (
@@ -477,6 +483,53 @@ def test_every_entry_resolves_to_files_and_every_moves_is_reported(manifest):
         with open(os.path.join(ROOT, "benchmarks", "end_to_end",
                                m["name"] + ".json")) as f:
             assert json.load(f)["reader"] in readers.GENERAL
+
+
+@pytest.mark.parametrize("manifest", [REPO, TINY])
+def test_every_entry_resolves_to_files_and_every_moves_is_reported(manifest):
+    resolves_to_files_and_every_moves_is_reported(manifest)
+
+
+def test_an_appended_cell_and_metric_come_as_data(tmp_path):
+    """What a later PR brings as appended entries and new files, with no
+    edit to the harness or to an entry: a per-layer metric on a general
+    reader that lists one language-model cell, and a cell cloned from
+    another under a new name. The cells that were there keep their
+    per-layer metrics, the listed one gains the new metric alone, the new
+    cell takes every metric that lists no cell, and the manifest resolves
+    as the repo's does."""
+    with open(REPO) as f:
+        bench = json.load(f)
+    before = {w["name"]: run.load_cell(w["name"])["per_layer"]
+              for w in bench["workloads"]}
+    metric = {"name": "drive.dispatch_p95_ms", "unit": "ms",
+              "better": "lower", "source": "program_span",
+              "layer": "drive loop", "moves": "train_samples_per_s_chip",
+              "workloads": ["dsv2lite_lora.train"]}
+    metrics = tmp_path / "layer_metrics"
+    metrics.mkdir()
+    (metrics / (metric["name"] + ".json")).write_text(json.dumps(
+        {**{k: metric[k] for k in ("layer", "unit", "better", "source",
+                                   "moves")},
+         "reader": "span_percentile_ms",
+         "params": {"span": "dispatch", "q": 95}}))
+    clone = dict(next(w for w in bench["workloads"]
+                      if w["name"] == "kimi_linear_lora.train"),
+                 name="kimi_linear_lora.clone")
+    bench["per_layer"].append(metric)
+    bench["workloads"].append(clone)
+    manifest = str(tmp_path / "BENCHMARK.json")
+    with open(manifest, "w") as f:
+        json.dump(bench, f)
+
+    for cell, was in before.items():
+        got = run.load_cell(cell, manifest)["per_layer"]
+        assert got == was + ([metric] if cell == "dsv2lite_lora.train"
+                             else []), cell
+    assert run.load_cell(clone["name"], manifest)["per_layer"] == [
+        m for m in bench["per_layer"] if "workloads" not in m]
+    resolves_to_files_and_every_moves_is_reported(manifest,
+                                                  (str(metrics), LAYERS))
 
 
 def test_shapes_do_not_move_with_the_seed():

@@ -79,7 +79,7 @@ def test_the_share_cell_lists_the_metric_and_the_whole_model_cell_does_not():
         assert NAME not in {m["name"]
                             for m in run.load_cell(cell)["per_layer"]}
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        assert json.load(f)["per_layer"][-1]["name"] == NAME   # appended
+        assert NAME in [m["name"] for m in json.load(f)["per_layer"]]
     with open(os.path.join(ROOT, "benchmarks", "layer_metrics",
                            NAME + ".json")) as f:
         spec = json.load(f)

@@ -5,6 +5,7 @@ and scope maps: the shares by hand, and nothing (None, never 0) where there
 is nothing to read: no map, a map without the scopes, a stale map, a program
 that hands out none (the parent's), no trace."""
 
+import json
 import os
 import sys
 import types
@@ -16,6 +17,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from benchmarks import run  # noqa: E402
 from benchmarks.harness import readers  # noqa: E402
 from benchmarks.layer_metrics import program_scopes  # noqa: E402
 
@@ -113,9 +115,30 @@ def test_a_scope_matches_inside_wrappers_and_only_whole(path, scope, held):
     assert program_scopes.holds(path, scope) is held
 
 
-def test_the_files_name_the_readers_and_their_scopes():
-    import json
+@pytest.mark.parametrize("metric, unit, cells", [
+    ("moe.dispatch_share_pct", "%",
+     {"dsv2lite_lora.train", "kimi_linear_lora.train"}),
+    ("moe.layout_ms", "ms", {"dsv2lite_lora.train", "kimi_linear_lora.train"}),
+    ("kda.mixer_xla_share_pct", "%", {"kimi_linear_lora.train"})])
+def test_each_entry_is_listed_by_its_cells_and_by_no_other(metric, unit,
+                                                          cells):
+    """The entry of BENCHMARK.json as registered, and the cells whose
+    per-layer metrics it is: the cells that have the scopes, and no other
+    (`kda.mixer_xla_share_pct` not `dsv2lite_lora.train`, none the image
+    cells)."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    [entry] = [m for m in bench["per_layer"] if m["name"] == metric]
+    assert entry == {
+        "name": metric, "unit": unit, "better": "lower",
+        "source": "device_trace", "layer": "round program",
+        "moves": "train_samples_per_s_chip", "workloads": sorted(cells)}
+    for w in bench["workloads"]:
+        names = {m["name"] for m in run.load_cell(w["name"])["per_layer"]}
+        assert (metric in names) is (w["name"] in cells), w["name"]
 
+
+def test_the_files_name_the_readers_and_their_scopes():
     for name, params in SPECS.items():
         with open(os.path.join(ROOT, "benchmarks", "layer_metrics",
                                name + ".json")) as f:
