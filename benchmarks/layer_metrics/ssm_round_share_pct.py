@@ -1,0 +1,4 @@
+"""`ssm.round_share_pct`: `kda.round_share_pct`'s reader over the selective
+scan's calls, by the name pattern in the metric's file."""
+
+from benchmarks.layer_metrics.kda_round_share_pct import read  # noqa: F401

@@ -83,6 +83,25 @@ def tiny_kimi_linear(**sizes) -> dict:
     return cfg
 
 
+def tiny_jamba(**sizes) -> dict:
+    """AI21-Jamba2-3B's published configuration with every size cut to a CPU
+    test's (hidden 64, a Mamba layer of 128 channels and 16 states, then a
+    multi-query attention layer of 4 heads): the `config` a `jamba` factory
+    call takes. Vocabulary 10, as `tiny_deepseek_v2`; `sizes` override."""
+    import json
+
+    from fedml_tpu.models.jamba import PUBLISHED
+
+    with open(PUBLISHED) as f:
+        cfg = json.load(f)
+    cfg.update(hidden_size=64, intermediate_size=160, num_hidden_layers=2,
+               num_attention_heads=4, attn_layer_offset=1,
+               attn_layer_period=2, mamba_dt_rank=8, vocab_size=10,
+               max_position_embeddings=256)
+    cfg.update(sizes)
+    return cfg
+
+
 # model name -> (example input shape, input dtype, extra factory kwargs).
 # Every registered model MUST have a row (enforced by tests/test_lint.py and
 # tests/test_dtype_registry.py) — a new factory that drops the dtype knob
@@ -119,6 +138,7 @@ MODEL_EXAMPLES = {
     "transformer_nwp": ((2, 16), jnp.int32, {}),
     "deepseek_v2": ((2, 16), jnp.int32, {"config": tiny_deepseek_v2()}),
     "kimi_linear": ((2, 16), jnp.int32, {"config": tiny_kimi_linear()}),
+    "jamba": ((2, 16), jnp.int32, {"config": tiny_jamba()}),
 }
 
 

@@ -29,8 +29,9 @@ def add_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
     parser.add_argument("--model_config", type=str, default=None,
                         help="JSON of a model's published configuration "
                              "keys, for a model whose sizes are a file's "
-                             "and no argument's (deepseek_v2, kimi_linear; default: the "
-                             "file kept beside the model)")
+                             "and no argument's (deepseek_v2, kimi_linear, "
+                             "jamba; default: the file kept beside the "
+                             "model)")
     parser.add_argument("--dataset", type=str, default="mnist")
     parser.add_argument("--data_dir", type=str, default="./data")
     parser.add_argument("--partition_method", type=str, default="hetero")

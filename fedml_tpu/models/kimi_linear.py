@@ -190,14 +190,17 @@ class KimiLinearConfig:
         return self.num_experts_per_token
 
 
-def short_conv(x, taps):
-    """Depthwise causal convolution over time, a channel a filter, no bias:
-    y_t = sum_j taps[j] x_{t - (n - 1) + j}. x [B, T, C]; taps [n, C];
-    multiply-adds in float32, -> x's dtype."""
+def short_conv(x, taps, bias=None):
+    """Depthwise causal convolution over time, a channel a filter:
+    y_t = sum_j taps[j] x_{t - (n - 1) + j} (+ bias, `models/jamba.py`'s).
+    x [B, T, C]; taps [n, C]; bias [C]; multiply-adds in float32, -> x's
+    dtype."""
     n, t = taps.shape[0], x.shape[1]
     x32, w = x.astype(jnp.float32), taps.astype(jnp.float32)
     padded = jnp.pad(x32, ((0, 0), (n - 1, 0), (0, 0)))
     y = sum(w[j] * padded[:, j:j + t] for j in range(n))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return y.astype(x.dtype)
 
 

@@ -190,6 +190,25 @@ def _kimi_linear(output_dim, **kw):
     return KimiLinearLM(cfg, dtype=_compute_dtype(kw) or jnp.float32)
 
 
+@register_model("jamba")
+def _jamba(output_dim, **kw):
+    # Jamba decoder (Mamba-1 selective-scan layers beside multi-query NoPE
+    # attention, dense SwiGLU, tied head). Sizes as `deepseek_v2`'s: `config`
+    # is the published keys' dict or the path of a JSON that holds them
+    # (--model_config); None is AI21-Jamba2-3B as published
+    from fedml_tpu.models.jamba import JambaConfig, JambaLM
+
+    config = kw.get("config")
+    cfg = (JambaConfig.from_dict(config) if isinstance(config, dict)
+           else JambaConfig.from_file(config))
+    if output_dim != cfg.vocab_size:
+        raise ValueError(f"the data has {output_dim} token ids, the model "
+                         f"configuration a vocabulary of {cfg.vocab_size}")
+    import jax.numpy as jnp
+
+    return JambaLM(cfg, dtype=_compute_dtype(kw) or jnp.float32)
+
+
 @register_model("mobilenet_v3")
 def _mobilenet_v3(output_dim, **kw):
     # reference main_fedavg.py "mobilenet_v3" -> MobileNetV3(model_mode=...)

@@ -224,14 +224,20 @@ def device_by_scope(event: Dict[str, Any], ops: List[list],
     of its device seconds whose instruction has an op_name at all
     (`named_pct`), one under a declared scope (`scoped_pct`), and those of
     the Pallas kernels' calls, which have none and are known by their
-    kernel's name (`kernel_pct`)."""
+    kernel's name (`kernel_pct`; `by_kernel_s` by that name, the call's
+    number left off: `ssm_scan_bwd`)."""
     op_scopes = event.get("op_scopes", {})
     kernels = set(event.get("kernels", ()))
     by_scope: Dict[str, float] = {}
+    by_kernel: Dict[str, float] = {}
     named = scoped = kernel = 0.0
     for name, seconds, _ in ops:
-        held = op_scopes.get(name.split(" ")[0])
-        kernel += seconds if name.split(" ")[0] in kernels else 0.0
+        inst = name.split(" ")[0]
+        held = op_scopes.get(inst)
+        if inst in kernels:
+            kernel += seconds
+            call = re.sub(r"\.\d+$", "", inst)
+            by_kernel[call] = by_kernel.get(call, 0.0) + seconds
         if held is None:
             continue
         named += seconds
@@ -245,7 +251,9 @@ def device_by_scope(event: Dict[str, Any], ops: List[list],
             "scoped_pct": round(100.0 * scoped / total, 3),
             "kernel_pct": round(100.0 * kernel / total, 3),
             "by_scope_s": {k: round(v, 6) for k, v in sorted(
-                by_scope.items(), key=lambda kv: -kv[1])}}
+                by_scope.items(), key=lambda kv: -kv[1])},
+            "by_kernel_s": {k: round(v, 6) for k, v in sorted(
+                by_kernel.items(), key=lambda kv: -kv[1])}}
 
 
 # ------------------------------------------------------------------- gate

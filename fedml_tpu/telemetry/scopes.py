@@ -35,11 +35,12 @@ import re
 #: outer name for it; `moe_layout` the sort and scatters, `moe_gather` the
 #: rows into expert order, `moe_combine` the rows back to tokens), the KDA
 #: mixer (`models/kimi_linear.py`: the flax module `kda` and the scopes
-#: `kda`, `kda_conv`, `kda_gates`), the blockwise loss (`core/trainer.py`),
-#: and the flax modules `attn` and `router`
+#: `kda`, `kda_conv`, `kda_gates`), the Mamba mixer (`models/jamba.py`: the
+#: flax module `mamba` and the scopes `ssm_conv`, `ssm_gates`), the blockwise
+#: loss (`core/trainer.py`), and the flax modules `attn` and `router`
 DECLARED_SCOPES = ("experts", "moe_layout", "moe_gather", "moe_combine",
-                   "kda", "kda_conv", "kda_gates", "lm_loss", "attn",
-                   "router")
+                   "kda", "kda_conv", "kda_gates", "mamba", "ssm_conv",
+                   "ssm_gates", "lm_loss", "attn", "router")
 
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _LOC_NAME = re.compile(r'loc\("([^"]*)"')

@@ -367,3 +367,73 @@ def test_kimi_linear_lora_round_compiles_beside_its_base(one_chip,
     print(mem)
     assert mem.argument_size_in_bytes > 4.5e9 > 1e8 > mem.output_size_in_bytes
     assert _fits(compiled) < 13e9
+
+
+def test_ssm_kernels_compile_at_the_cells_shapes(one_chip, no_compile_cache):
+    """`ops/ssm.py`'s two Pallas calls as the Jamba training step runs them:
+    2 lanes x 1 sequence x 8,192 tokens x 5,120 channels x 16 states,
+    bfloat16 x, float32 delta, A, B and C, forward and backward under the
+    lanes' vmap, on the kernel's own chunk and block. The benchmark's
+    readers find them by name."""
+    from fedml_tpu.ops.ssm import selective_scan
+
+    def loss(x, delta, A, B, C):
+        y = jax.vmap(lambda x, d, b, c: selective_scan(
+            x, d, A, b, c, interpret=False))(x, delta, B, C)
+        return jnp.sum(y)
+
+    wide, narrow = (2, 1, 8192, 5120), (2, 1, 8192, 16)
+    args = _on(one_chip, (
+        jax.ShapeDtypeStruct(wide, jnp.bfloat16),
+        jax.ShapeDtypeStruct(wide, jnp.float32),
+        jax.ShapeDtypeStruct((5120, 16), jnp.float32),
+        *[jax.ShapeDtypeStruct(narrow, jnp.float32)] * 2))
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(*args).compile()
+    text = compiled.as_text()
+    assert "ssm_scan_fwd" in text and "ssm_scan_bwd" in text
+    _fits(compiled)
+
+
+@pytest.mark.slow  # minutes of TPU compile
+def test_jamba2_lora_round_compiles_beside_its_base(one_chip,
+                                                    no_compile_cache):
+    """engine.round for `benchmarks/configs/jamba2_lora.json` (14 layers of
+    AI21-Jamba2-3B, bfloat16 base, rank-16 adapters, 2 lanes x 1 x 8,192
+    tokens a step): fits beside its 3.2 GB base and returns none of it."""
+    from fedml_tpu.algorithms.aggregators import make_aggregator
+    from fedml_tpu.algorithms.engine import build_round_fn
+    from fedml_tpu.core.config import FedConfig
+    from fedml_tpu.core.trainer import NWPTrainer
+    from fedml_tpu.models.lora import LoRATrainer
+    from fedml_tpu.models.registry import create_model
+    from fedml_tpu.ops import attention, ssm
+
+    cfg = FedConfig(model="jamba", client_num_in_total=20,
+                    client_num_per_round=2, epochs=1, batch_size=1, lr=0.03,
+                    lora_rank=16, dtype="bfloat16")
+    trainer = LoRATrainer(NWPTrainer(create_model(
+        "jamba", output_dim=65536, dtype="bfloat16",
+        config="benchmarks/configs/jamba2_lora.json")), rank=16)
+    agg = make_aggregator("fedavg", cfg)
+    gv = jax.eval_shape(lambda: trainer.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32)))
+    tokens = jax.ShapeDtypeStruct((2, 4, 8192), jnp.int32)
+    args = _on(one_chip, (
+        gv, jax.eval_shape(agg.init_state, gv), tokens, tokens,
+        jax.ShapeDtypeStruct((2,), jnp.int32),
+        jax.eval_shape(lambda: jax.random.PRNGKey(0))))
+    mods = (attention, ssm)
+    was = [m.interpret_off_chip for m in mods]
+    for m in mods:
+        m.interpret_off_chip = lambda k: False
+    try:
+        compiled = build_round_fn(
+            trainer, cfg, agg, donate_data=True,
+            collect_stats=True).jitted.lower(*args).compile()
+    finally:
+        for m, f in zip(mods, was):
+            m.interpret_off_chip = f
+    mem = compiled.memory_analysis()
+    print(mem)
+    assert mem.argument_size_in_bytes > 3.1e9 > 1e8 > mem.output_size_in_bytes
+    assert _fits(compiled) < 15.5e9

@@ -1,7 +1,8 @@
 """The round program's scope map (`FedAvgAPI.program_scopes`,
 `telemetry/scopes.py`): on the tiny DeepSeek-V2 and Kimi Linear cells it
 names the expert dispatch's phases in the forward and the backward and the
-KDA mixer's scopes; a second call is the same map and compiles nothing;
+KDA mixer's scopes, and on the tiny Jamba cell the Mamba mixer's; a second
+call is the same map and compiles nothing;
 a compile cache filled without the scopes reads stale and maps nothing;
 the HLO parser reads the optimised text's numbered tuple headers; the
 operator's join of a profile (`telemetry/report.py::device_by_scope`)."""
@@ -102,6 +103,31 @@ def test_the_kda_mixer_is_named_in_kimi_linear(scoped, scope):
         assert held and event["scoped"][scope] == len(held)
     else:
         assert not held and scope not in event["scoped"]
+
+
+def test_the_mamba_mixer_is_named_in_jamba():
+    """The Jamba round program's compiled text names the Mamba mixer's
+    three scopes, forward and backward, and the map holds each; the other
+    decoders' mixers are not there."""
+    before = getattr(jax.config, METADATA_KEY)
+    jax.config.update(METADATA_KEY, True)
+    try:
+        api = _tiny_api("jamba2_lora")
+        tracer = telemetry.Tracer()
+        api.train_one_round(0, tracer=tracer)
+        names = api.program_scopes(tracer)
+        text = api._round_program.lower(*api._round_avals).compile().as_text()
+    finally:
+        jax.config.update(METADATA_KEY, before)
+    [event] = tracer.find_events("program_scopes")
+    paths = re.findall(r'op_name="([^"]*)"', text)
+    for scope in ("mamba", "ssm_conv", "ssm_gates"):
+        named = [p for p in paths if scopes.holds(p, scope)]
+        assert [p for p in named if "transpose(" in p], scope
+        assert [p for p in named if "transpose(" not in p], scope
+        held = [p for p in names.values() if scopes.holds(p, scope)]
+        assert held and event["scoped"][scope] == len(held), scope
+    assert not {"kda", "experts"} & set(event["scoped"])
 
 
 def test_the_map_comes_from_memory_and_the_event_says_what_it_holds(scoped):
@@ -238,16 +264,21 @@ def test_the_operators_join_of_a_profile_by_scope():
     event = {"op_scopes": {"fusion.1": "experts/moe_layout",
                            "fusion.2": "experts", "copy.3": "",
                            "fusion.4": "kda"},
-             "kernels": ["kda_fwd.6"]}
+             "kernels": ["kda_fwd.6", "ssm_scan_bwd.7", "ssm_scan_bwd.8"]}
     ops = [["fusion.1 s32[128] kLoop", 0.2, 8],
            ["fusion.2 bf16[64,2048] kOutput", 0.3, 8],
            ["copy.3 f32[2]", 0.1, 8], ["fusion.4 f32[2]", 0.25, 4],
            ["while.5", 0.05, 4],          # no op_name: neither share
-           ["kda_fwd.6 f32[2,4096]", 0.1, 4]]   # a kernel: its own share
+           ["kda_fwd.6 f32[2,4096]", 0.1, 4],   # a kernel: its own share
+           ["ssm_scan_bwd.7 f32[2,8192]", 0.02, 4],
+           ["ssm_scan_bwd.8 f32[2,8192]", 0.03, 4]]
     out = device_by_scope(event, ops, ["jit_round_fn", 4, 1.0])
     assert out["named_pct"] == pytest.approx(85.0)
     assert out["scoped_pct"] == pytest.approx(75.0)
-    assert out["kernel_pct"] == pytest.approx(10.0)
+    assert out["kernel_pct"] == pytest.approx(15.0)
+    assert out["by_kernel_s"] == pytest.approx(
+        {"kda_fwd": 0.1, "ssm_scan_bwd": 0.05})
+    assert list(out["by_kernel_s"]) == ["kda_fwd", "ssm_scan_bwd"]
     assert out["by_scope_s"] == pytest.approx(
         {"experts": 0.5, "kda": 0.25, "moe_layout": 0.2})
     assert list(out["by_scope_s"]) == ["experts", "kda", "moe_layout"]
