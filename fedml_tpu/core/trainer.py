@@ -20,10 +20,12 @@ Concrete trainers mirror the reference's three standalone trainers:
 
 from __future__ import annotations
 
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import optax
+from jax.custom_derivatives import SymbolicZero
 
 
 def _module_apply(module, variables, x, rng, train: bool):
@@ -126,6 +128,76 @@ class ClassificationTrainer(ModelTrainer):
         }
 
 
+def _block_sums(logits, y, mask):
+    """A block's masked cross-entropy sum, and its masked hit sum (float32:
+    the logits are)."""
+    per = optax.softmax_cross_entropy_with_integer_labels(logits, y)
+    hit = (jnp.argmax(logits, -1) == y).astype(jnp.float32)
+    return (per.astype(jnp.float32) * mask).sum(), (hit * mask).sum()
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _ce_sums(head, weights, states, y, mask):
+    """(masked cross-entropy sum, masked hit sum) over blocks: `states`
+    [k, block, hidden], `y` and `mask` [k, block]; `head(block, *weights)`
+    gives a block's float32 logits. Differentiated, `_ce_sums_fwd` stands
+    in for this."""
+    def one(sums, inp):
+        hb, yb, mb = inp
+        loss, hits = _block_sums(head(hb, *weights), yb, mb)
+        return (sums[0] + loss, sums[1] + hits), None
+
+    zero = jnp.zeros((), jnp.float32)
+    return jax.lax.scan(one, (zero, zero), (states, y, mask))[0]
+
+
+def _ce_sums_fwd(head, weights, states, y, mask):
+    """`_ce_sums` and, from each block's same logits, the loss sum's
+    gradient with respect to the block's states and to those of the head's
+    weights that are differentiated (summed over blocks): the backward only
+    scales them. A frozen head multiplies a block twice, for the logits and
+    for the states' gradient; a trained one a third time, for its own."""
+    inputs = [states, *weights]
+    wrt = [i for i, a in enumerate(inputs) if a.perturbed]
+    values = [a.value for a in inputs]
+
+    def one(carry, inp):
+        sums, dw = carry
+        hb, yb, mb = inp
+        args = [hb, *values[1:]]
+
+        def logits_of(*diff):
+            return head(*[dict(zip(wrt, diff)).get(i, a)
+                          for i, a in enumerate(args)])
+
+        logits, pull_head = jax.vjp(logits_of, *[args[i] for i in wrt])
+        loss, pull_loss, hits = jax.vjp(lambda l: _block_sums(l, yb, mb),
+                                        logits, has_aux=True)
+        grads = dict(zip(wrt, pull_head(*pull_loss(jnp.ones_like(loss)))))
+        dw = {i: dw[i] + grads[i] for i in dw}
+        return ((sums[0] + loss, sums[1] + hits), dw), grads.get(0)
+
+    zero = jnp.zeros((), jnp.float32)
+    dw0 = {i: jnp.zeros_like(values[i]) for i in wrt if i}
+    (sums, dw), dh = jax.lax.scan(one, ((zero, zero), dw0),
+                                  (values[0], y.value, mask.value))
+    return sums, [dh, *[dw.get(i) for i in range(1, len(inputs))]]
+
+
+def _ce_sums_bwd(head, grads, cts):
+    """The forward's gradients times the loss sum's cotangent (the hit sum
+    has no gradient)."""
+    g = cts[0]
+    if isinstance(g, SymbolicZero):
+        return None, None, None, None
+    # scaled in float32, rounded once to the input's dtype
+    grads = [None if a is None else (a * g).astype(a.dtype) for a in grads]
+    return grads[1:], grads[0], None, None
+
+
+_ce_sums.defvjp(_ce_sums_fwd, _ce_sums_bwd, symbolic_zeros=True)
+
+
 class NWPTrainer(ModelTrainer):
     """Next-word prediction with pad-id masking (reference
     my_model_trainer_nwp.py: CE with ignore_index=0, accuracy over non-pad).
@@ -137,10 +209,15 @@ class NWPTrainer(ModelTrainer):
     A module that brings ``hidden(tokens, train) -> (states, aux)`` and
     ``head(states) -> logits`` (models/deepseek_v2.py) owns how its loss is
     computed: the head and the cross-entropy run over blocks of
-    ``loss_block`` tokens, each block rematerialised in the backward pass,
-    so that no [tokens, vocab] float32 array of the whole batch exists (8,192
-    tokens over a 102,400-word vocabulary are 3.4 GB); the same sums to
-    float32 rounding. What the module's ``aux`` holds (the tokens every
+    ``loss_block`` tokens, so that no [tokens, vocab] float32 array of the
+    whole batch exists (8,192 tokens over a 102,400-word vocabulary are 3.4
+    GB); the same sums to float32 rounding. Differentiated, a block's forward
+    also computes from its logits the loss sum's gradient with respect to its
+    states (and to the head's weights where those are trained), which the
+    backward only scales (`_ce_sums`): what the loss keeps for the backward
+    is that gradient, [tokens, hidden] in the states' dtype, and the head's
+    product runs twice a block (a trained head's three times), with no block
+    computed again. What the module's ``aux`` holds (the tokens every
     expert received) rides the step's metrics. Its eval runs ``eval_rows``
     sequences at a time with loss blocks of ``eval_block`` tokens (the
     engine vmaps an eval over up to 64 clients, and every lane holds a block
@@ -172,7 +249,7 @@ class NWPTrainer(ModelTrainer):
 
     def _blockwise_ce(self, variables, batch, train, block=None):
         """`_masked_ce` of a module that owns its loss: float32 sums over
-        blocks of `block` (default `loss_block`) tokens."""
+        blocks of `block` (default `loss_block`) tokens, by `_ce_sums`."""
         states, extra = self.module.apply(variables, batch["x"], train=train,
                                           method="hidden")
         y = batch["y"]
@@ -186,18 +263,14 @@ class NWPTrainer(ModelTrainer):
                 for a in (states, y, mask)]
         blocks = [a.reshape((-1, block) + a.shape[1:]) for a in flat]
 
-        @jax.checkpoint
-        def one(sums, inp):
-            hb, yb, mb = inp
-            logits = self.module.apply(variables, hb, method="head")
-            per = optax.softmax_cross_entropy_with_integer_labels(logits, yb)
-            hit = (jnp.argmax(logits, -1) == yb).astype(jnp.float32)
-            return (sums[0] + (per.astype(jnp.float32) * mb).sum(),
-                    sums[1] + (hit * mb).sum()), None
-
-        zero = jnp.zeros((), jnp.float32)
+        # the head as a function of a block and of the arrays it reads
+        # (`closure_convert` hoists them), so the VJP below sees which of
+        # them are differentiated
+        head, weights = jax.closure_convert(
+            lambda h: self.module.apply(variables, h, method="head"),
+            jax.ShapeDtypeStruct(blocks[0].shape[1:], blocks[0].dtype))
         with jax.named_scope("lm_loss"):
-            (loss_sum, correct), _ = jax.lax.scan(one, (zero, zero), blocks)
+            loss_sum, correct = _ce_sums(head, weights, *blocks)
         total = mask.sum()
         aux = {"loss_sum": loss_sum, "correct": correct, "total": total}
         aux.update(extra)

@@ -137,6 +137,186 @@ def test_loss_over_blocks_of_tokens_equals_the_whole_batchs(seeded):
         float(old["loss_sum"] / old["total"] * 3), rel=1e-6)
 
 
+#: the two tiny decoders at a vocabulary no other width of theirs equals, so
+#: a product with the head is a `dot_general` with WORDS among its shapes
+TINY = {"deepseek_v2": CELL, "jamba": "tests/benchmark/cells/tiny_jamba2_lora.json"}
+WORDS = 320
+
+
+def checkpointed_ce(inner, variables, batch, train, block):
+    """The blockwise loss as `NWPTrainer` computed it before its custom VJP:
+    each block `jax.checkpoint`ed, so the backward runs the head's product
+    again and then its transpose. (loss, {loss_sum, correct, total})."""
+    states, _ = inner.module.apply(variables, batch["x"], train=train,
+                                   method="hidden")
+    y = batch["y"]
+    mask = ((y != inner.pad_id).astype(jnp.float32)
+            * batch["mask"].astype(jnp.float32)[:, None])
+    n = y.size
+    block = min(block, n)
+    pad = -n % block
+    blocks = [jnp.pad(a.reshape((n,) + a.shape[2:]),
+                      [(0, pad)] + [(0, 0)] * (a.ndim - 2)).reshape(
+                          (-1, block) + a.shape[2:])
+              for a in (states, y, mask)]
+
+    @jax.checkpoint
+    def one(sums, inp):
+        hb, yb, mb = inp
+        logits = inner.module.apply(variables, hb, method="head")
+        per = optax.softmax_cross_entropy_with_integer_labels(logits, yb)
+        hit = (jnp.argmax(logits, -1) == yb).astype(jnp.float32)
+        return (sums[0] + (per.astype(jnp.float32) * mb).sum(),
+                sums[1] + (hit * mb).sum()), None
+
+    zero = jnp.zeros((), jnp.float32)
+    (loss_sum, correct), _ = jax.lax.scan(one, (zero, zero), blocks)
+    total = mask.sum()
+    return loss_sum / jnp.maximum(total, 1.0), {
+        "loss_sum": loss_sum, "correct": correct, "total": total}
+
+
+def head_products(jaxpr) -> int:
+    """`dot_general`s with the vocabulary among their shapes, in `jaxpr`
+    and every jaxpr inside it (a scan's body counts once)."""
+    def subs(v):
+        if isinstance(v, jax.extend.core.ClosedJaxpr):
+            return [v.jaxpr]
+        if isinstance(v, jax.extend.core.Jaxpr):
+            return [v]
+        if isinstance(v, (tuple, list)):
+            return [j for x in v for j in subs(x)]
+        return []
+
+    count = 0
+    for eqn in jaxpr.eqns:
+        shapes = [a.aval.shape for a in eqn.invars + eqn.outvars]
+        count += eqn.primitive.name == "dot_general" and any(
+            WORDS in s for s in shapes)
+        count += sum(head_products(j) for v in eqn.params.values()
+                     for j in subs(v))
+    return count
+
+
+@pytest.fixture(scope="module", params=sorted(TINY))
+def tiny(request):
+    """(LoRA trainer over the tiny decoder, variables with adapters that are
+    not 0, a batch of 3 x 32 tokens with pad ids and a masked sample)."""
+    with open(os.path.join(ROOT, TINY[request.param])) as f:
+        spec = dict(json.load(f), vocab_size=WORDS)
+    module = create_model(request.param, output_dim=WORDS, config=spec)
+    tr = LoRATrainer(NWPTrainer(module), rank=4, alpha=4)
+    x = jax.random.randint(jax.random.PRNGKey(2), (3, 32), 1, WORDS)
+    y = jnp.concatenate([x[:, 1:], jnp.zeros_like(x[:, :1])], axis=1)
+    y = y.at[1, 5:9].set(0)
+    variables = tr.init(jax.random.PRNGKey(5), x[:1])
+    leaves, tree = jax.tree.flatten(variables["params"])
+    keys = jax.random.split(jax.random.PRNGKey(6), len(leaves))
+    variables["params"] = jax.tree.unflatten(tree, [
+        0.1 * jax.random.normal(k, a.shape, a.dtype)
+        for k, a in zip(keys, leaves)])
+    return tr, variables, {"x": x, "y": y, "mask": jnp.array([1.0, 1.0, 0.0])}
+
+
+def test_blockwise_loss_and_adapter_gradients_equal_the_checkpointed_forms(tiny):
+    """Blocks of 40 of 96 tokens: the last block holds 16 and 24 of padding."""
+    tr, variables, batch = tiny
+    inner = tr.inner
+    inner.loss_block = 40
+
+    def new(p):
+        return tr.loss_fn({**variables, "params": p}, batch, None, True)
+
+    def old(p):
+        merged = tr.merged_variables({**variables, "params": p})
+        loss, aux = checkpointed_ce(inner, merged, batch, True, 40)
+        return loss, (None, aux)
+
+    with jax.default_matmul_precision("highest"):
+        (loss_n, (_, aux_n)), grad_n = jax.value_and_grad(new, has_aux=True)(
+            variables["params"])
+        (loss_o, (_, aux_o)), grad_o = jax.value_and_grad(old, has_aux=True)(
+            variables["params"])
+    assert float(loss_n) == pytest.approx(float(loss_o), rel=1e-5)
+    assert float(aux_n["loss_sum"]) == pytest.approx(float(aux_o["loss_sum"]),
+                                                     rel=1e-5)
+    assert float(aux_n["total"]) == float(aux_o["total"]) == 2 * 31 - 4
+    assert float(aux_n["correct"]) == float(aux_o["correct"])
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(grad_n)[0],
+                            jax.tree.leaves(grad_o)):
+        assert float(jnp.linalg.norm(b)) > 0, path
+        assert float(jnp.linalg.norm(a - b)) <= 1e-5 * float(
+            jnp.linalg.norm(b)), path
+
+
+def test_the_training_gradient_multiplies_by_the_head_twice_a_block(tiny):
+    """The forward's logits and the states' gradient from them; the
+    checkpointed form also ran the forward's product again."""
+    tr, variables, batch = tiny
+
+    def count(loss):
+        return head_products(jax.make_jaxpr(jax.grad(
+            lambda p: loss({**variables, "params": p})))(
+                variables["params"]).jaxpr)
+
+    assert count(lambda v: tr.loss_fn(v, batch, None, True)[0]) == 2
+    assert count(lambda v: checkpointed_ce(
+        tr.inner, tr.merged_variables(v), batch, True, 40)[0]) == 3
+
+
+def test_eval_sums_equal_the_checkpointed_forms(tiny):
+    tr, variables, batch = tiny
+    inner = tr.inner
+    inner.eval_rows, inner.eval_block = 2, 20
+    with jax.default_matmul_precision("highest"):
+        ev = tr.eval_fn(variables, batch)
+        _, old = checkpointed_ce(inner, tr.merged_variables(variables), batch,
+                                 False, 20)
+    assert float(ev["test_total"]) == float(old["total"])
+    assert float(ev["test_correct"]) == float(old["correct"])
+    assert float(ev["test_loss"]) == pytest.approx(
+        float(old["loss_sum"] / old["total"] * 2), rel=1e-5)
+
+
+def test_a_trained_heads_gradient_equals_the_checkpointed_forms():
+    """Jamba without adapters: the head is the embedding, trained, so its
+    gradient takes a third product a block, from the same logits."""
+    with open(os.path.join(ROOT, TINY["jamba"])) as f:
+        spec = dict(json.load(f), vocab_size=WORDS)
+    inner = NWPTrainer(create_model("jamba", output_dim=WORDS, config=spec))
+    inner.loss_block = 40
+    x = jax.random.randint(jax.random.PRNGKey(3), (3, 32), 1, WORDS)
+    y = jnp.concatenate([x[:, 1:], jnp.zeros_like(x[:, :1])], axis=1)
+    batch = {"x": x, "y": y, "mask": jnp.array([1.0, 0.0, 1.0])}
+    variables = inner.init(jax.random.PRNGKey(4), x[:1])
+
+    def new(p):
+        return inner.loss_fn({**variables, "params": p}, batch, None, True)[0]
+
+    def old(p):
+        return checkpointed_ce(inner, {**variables, "params": p}, batch,
+                               True, 40)[0]
+
+    with jax.default_matmul_precision("highest"):
+        grad_n = jax.grad(new)(variables["params"])
+        grad_o = jax.grad(old)(variables["params"])
+        n_products = head_products(jax.make_jaxpr(jax.grad(new))(
+            variables["params"]).jaxpr)
+    assert n_products == 3
+    # the embedding's gradient is the head's and the input's; the final
+    # norm's comes from the states' gradient alone
+    for name in ("embed", "final_norm"):
+        a, b = grad_n[name], grad_o[name]
+        assert jax.tree.structure(a) == jax.tree.structure(b)
+        for u, v in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+            assert float(jnp.linalg.norm(u - v)) <= 1e-5 * float(
+                jnp.linalg.norm(v)), name
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(grad_n)[0],
+                            jax.tree.leaves(grad_o)):
+        assert float(jnp.linalg.norm(a - b)) <= 1e-5 * float(
+            jnp.linalg.norm(b)), path
+
+
 def test_a_model_without_hidden_and_head_takes_the_whole_batchs_logits():
     module = create_model("transformer_nwp", output_dim=64)
     assert NWPTrainer(module).blockwise is False

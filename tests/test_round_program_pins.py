@@ -5,7 +5,9 @@ lowering metadata alone (a `jax.named_scope`, PR 40) leaves every hash as it
 is; a change of a cell's arithmetic moves its hash, and the PR that makes it
 says so and re-pins it here. `dsv2lite_lora` was re-pinned when its expert
 layers moved to the one row-space dispatch (`ops/moe.py`, every expert
-held); the other three hashes stayed as they were."""
+held); `dsv2lite_lora` and `kimi_linear_lora` were re-pinned when the
+blockwise loss took its states' gradient from its forward pass
+(`core/trainer.py::_ce_sums`); the image cells' hashes never moved."""
 
 import hashlib
 import json
@@ -25,9 +27,9 @@ PINS = {
     "cross_silo":
         "5271f44fc834b77f139f8b26b2fa05ff7f2c9a3497616074622b3bdf7da6be92",
     "dsv2lite_lora":
-        "fbe95401e33fbd57561b9f93f7c364464343cadbe4b59d66358997a3f019766b",
+        "d1b96081e85b6638f08820304c094fa866e5f72676967d437fc3832062d9d1c3",
     "kimi_linear_lora":
-        "444d729215f3d8ab0e77b5ec6aa967b1f78148056972e4e6dd14568992baf739",
+        "30990de46b8ceb22dd92328083e710735467d8d5f0ff9e884b764d4ad5d37d58",
 }
 
 
