@@ -102,6 +102,29 @@ def tiny_jamba(**sizes) -> dict:
     return cfg
 
 
+def tiny_mellum(**sizes) -> dict:
+    """Mellum2-12B-A2.5B's published configuration with every size cut to a
+    CPU test's (hidden 64, 4 query heads of 16 over 2 key-value heads, 8
+    experts of 32 top-2, a window of 8 on the first of two layers, the
+    second full under YaRN): the `config` a `mellum` factory call takes.
+    Vocabulary 10, as `tiny_deepseek_v2`; `sizes` override."""
+    import json
+
+    from fedml_tpu.models.mellum import PUBLISHED
+
+    with open(PUBLISHED) as f:
+        cfg = json.load(f)
+    cfg.update(hidden_size=64, num_hidden_layers=2, num_attention_heads=4,
+               num_key_value_heads=2, head_dim=16, num_experts=8,
+               num_experts_per_tok=2, moe_intermediate_size=32,
+               intermediate_size=96, sliding_window=8, vocab_size=10,
+               max_position_embeddings=256,
+               layer_types=["sliding_attention", "full_attention"],
+               mlp_layer_types=["sparse", "sparse"])
+    cfg.update(sizes)
+    return cfg
+
+
 # model name -> (example input shape, input dtype, extra factory kwargs).
 # Every registered model MUST have a row (enforced by tests/test_lint.py and
 # tests/test_dtype_registry.py) — a new factory that drops the dtype knob
@@ -139,6 +162,7 @@ MODEL_EXAMPLES = {
     "deepseek_v2": ((2, 16), jnp.int32, {"config": tiny_deepseek_v2()}),
     "kimi_linear": ((2, 16), jnp.int32, {"config": tiny_kimi_linear()}),
     "jamba": ((2, 16), jnp.int32, {"config": tiny_jamba()}),
+    "mellum": ((2, 16), jnp.int32, {"config": tiny_mellum()}),
 }
 
 

@@ -30,8 +30,8 @@ def add_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
                         help="JSON of a model's published configuration "
                              "keys, for a model whose sizes are a file's "
                              "and no argument's (deepseek_v2, kimi_linear, "
-                             "jamba; default: the file kept beside the "
-                             "model)")
+                             "jamba, mellum; default: the file kept beside "
+                             "the model)")
     parser.add_argument("--dataset", type=str, default="mnist")
     parser.add_argument("--data_dir", type=str, default="./data")
     parser.add_argument("--partition_method", type=str, default="hetero")
@@ -305,9 +305,14 @@ def build_trainer(args, cfg: FedConfig, ds):
     from fedml_tpu import telemetry
 
     said = module.describe() if hasattr(module, "describe") else {}
+    train = getattr(ds, "train", None)
+    if hasattr(module, "attention_tiles") and train is not None:
+        # the tiles its attention computes at the data's sequence length
+        said["attention_tiles"] = module.attention_tiles(
+            int(train.x.shape[-1]))
     telemetry.emit("model_built", model=model_name, **{
-        k: said.get(k) for k in ("layers", "mixers", "experts_held",
-                                 "experts_routed")})
+        **{k: None for k in ("layers", "mixers", "experts_held",
+                             "experts_routed")}, **said})
     if getattr(module, "frozen_base_only", False) and cfg.lora_rank <= 0:
         raise SystemExit(f"{model_name} trains as a frozen base only: give "
                          f"--lora_rank > 0")

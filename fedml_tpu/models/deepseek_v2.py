@@ -18,6 +18,7 @@ published, with its `source_url`): nothing is a Python default.
          k scores as they are (x routed_scaling_factor; renormalised only if
          norm_topk_prob);  y = sum_k s_k E_k(x) + S(x), dropless
          (`ops/moe.py`), S one SwiGLU of n_shared_experts x the expert width
+         (none where n_shared_experts is 0)
 
 In training keys and values are expanded for every head and no cache is
 kept; the attention core is the Pallas flash kernel (`ops/attention.py`)
@@ -167,20 +168,28 @@ def softmax_scale(cfg) -> float:
 
 
 def yarn_inv_freq(cfg) -> np.ndarray:
-    """The rotary inverse frequencies [rope / 2]: extrapolated (as trained)
+    """The rotary inverse frequencies [rope / 2] of `cfg`'s YaRN settings
+    (`yarn_blend`)."""
+    return yarn_blend(cfg.qk_rope_head_dim, cfg.rope_theta, cfg.rope_factor,
+                      cfg.rope_original, cfg.rope_beta_fast,
+                      cfg.rope_beta_slow)
+
+
+def yarn_blend(dim: int, base: float, factor: float, original: int,
+               beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's rotary inverse frequencies [dim / 2]: extrapolated (as trained)
     above the `beta_fast` correction, interpolated by `factor` below the
-    `beta_slow` one, a linear ramp between."""
-    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
+    `beta_slow` one, a linear ramp between (`models/mellum.py` shares it)."""
     exponent = np.arange(0, dim, 2, dtype=np.float64) / dim
     extra = 1.0 / base ** exponent
-    inter = 1.0 / (cfg.rope_factor * base ** exponent)
+    inter = 1.0 / (factor * base ** exponent)
 
     def correction_dim(rotations):
-        return (dim * math.log(cfg.rope_original / (rotations * 2 * math.pi))
+        return (dim * math.log(original / (rotations * 2 * math.pi))
                 / (2 * math.log(base)))
 
-    low = max(math.floor(correction_dim(cfg.rope_beta_fast)), 0)
-    high = min(math.ceil(correction_dim(cfg.rope_beta_slow)), dim - 1)
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
     if low == high:
         high += 0.001
     ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
@@ -194,6 +203,12 @@ def rotary(x, cos, sin):
     x [B, T, H, rope]; cos, sin [T, rope]."""
     b, t, h, d = x.shape
     x = x.reshape(b, t, h, d // 2, 2).swapaxes(-1, -2).reshape(b, t, h, d)
+    return rotate_half(x, cos, sin)
+
+
+def rotate_half(x, cos, sin):
+    """x cos + rotate_half(x) sin, the halves [x1 | x2] turned as
+    [-x2 | x1], in float32 -> x's dtype. x [B, T, H, d]; cos, sin [T, d]."""
     x1, x2 = jnp.split(x, 2, axis=-1)
     rot = jnp.concatenate([-x2, x1], axis=-1)
     c, s = cos[None, :, None, :], sin[None, :, None, :]
@@ -318,8 +333,10 @@ class MoE(nn.Module):
                 y, worst = moe.routed_experts_share(
                     flat, idx, gate, w_gate, w_up, w_down, held[0], e)
                 path = jnp.stack([1.0 - worst[0], worst[0]])
-        shared = SwiGLU(c.n_shared_experts * f, self.dtype, name="shared")(flat)
-        return (y + shared).reshape(b, t, d), moe.expert_load(idx, e), path
+        if c.n_shared_experts:
+            y = y + SwiGLU(c.n_shared_experts * f, self.dtype,
+                           name="shared")(flat)
+        return y.reshape(b, t, d), moe.expert_load(idx, e), path
 
 
 def biased_route(scores, bias, k: int):

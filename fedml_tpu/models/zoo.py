@@ -209,6 +209,26 @@ def _jamba(output_dim, **kw):
     return JambaLM(cfg, dtype=_compute_dtype(kw) or jnp.float32)
 
 
+@register_model("mellum")
+def _mellum(output_dim, **kw):
+    # Mellum 2 decoder (grouped-query attention, sliding-window and full
+    # layers by `layer_types` with a rotary setting each, routed experts top-k
+    # renormalised). Sizes as `deepseek_v2`'s: `config` is the published keys'
+    # dict or the path of a JSON that holds them (--model_config); None is
+    # Mellum2-12B-A2.5B as published
+    from fedml_tpu.models.mellum import MellumConfig, MellumLM
+
+    config = kw.get("config")
+    cfg = (MellumConfig.from_dict(config) if isinstance(config, dict)
+           else MellumConfig.from_file(config))
+    if output_dim != cfg.vocab_size:
+        raise ValueError(f"the data has {output_dim} token ids, the model "
+                         f"configuration a vocabulary of {cfg.vocab_size}")
+    import jax.numpy as jnp
+
+    return MellumLM(cfg, dtype=_compute_dtype(kw) or jnp.float32)
+
+
 @register_model("mobilenet_v3")
 def _mobilenet_v3(output_dim, **kw):
     # reference main_fedavg.py "mobilenet_v3" -> MobileNetV3(model_mode=...)

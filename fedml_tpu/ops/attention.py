@@ -25,6 +25,15 @@ Design (flash-attention-1 style, /opt/skills/guides/pallas_guide.md):
   head and 14.2 ms on 128 x 128 (PERF.md section 6, PR 37). A causal tile
   above the diagonal skips its body and its copy: its K/V (in dkv its Q/dO)
   index map repeats a live block's index.
+- a sliding window (`window=W`, causal only): query i sees keys j with
+  i - W < j <= i. The key sweep of a Q block (in dkv the Q sweep of a K
+  block) runs over the band's blocks alone, not over the whole axis: its
+  grid axis has as many steps as the widest band (`_band_steps`), a step
+  names its K block as the band's first plus the step, held at the band's
+  last past its end. Tiles outside the band are neither copied nor
+  computed. The calls are named `flash_window_*`, the causal ones
+  `flash_attention_*`, so that a trace tells them apart; `window=None` is
+  the causal kernel as it was.
 - training: `flash_attention` is a jax.custom_vjp with a BLOCKED backward
   (FlashAttention-2 style): the forward also emits the per-row logsumexp,
   and two streaming kernels recompute p block-by-block — dQ sweeping K
@@ -107,18 +116,26 @@ def _vmem_bytes(sweep: str, bq: int, bk: int, d: int, dv: int,
 
 
 def flash_blocks(sweep: str, tq: int, tk: int, d: int, dv: int, dtype,
-                 block_q: int | None = None, block_k: int | None = None
-                 ) -> tuple[int, int]:
+                 block_q: int | None = None, block_k: int | None = None,
+                 window: int | None = None) -> tuple[int, int]:
     """(block_q, block_k) of one sweep ("fwd", "dq" or "dkv") from what the
     kernel can see. An explicit block is taken as given (cut to the axis, and
     it has to divide it);
     the kernel's own is the largest candidate of the axis that keeps
     `_vmem_bytes` under the budget, the larger block giving way first (Q on
-    a tie: the K sweep's length costs the forward most)."""
+    a tie: the K sweep's length costs the forward most). Under a `window`
+    the kernel's own candidates are no wider than the band (and never under
+    128): at T 8,192 and W 1,024 on a v5e the forward and backward took
+    19.8 ms on 1,024 tiles, 20.7 on 512 and 35.0 on 256 (PERF.md section
+    4), fewer grid steps winning over fewer masked pairs."""
     itemsize = jnp.dtype(dtype).itemsize
     qs = ([min(block_q, tq)] if block_q
           else _candidate_blocks(tq, 8 * max(1, 4 // itemsize)))
     ks = [min(block_k, tk)] if block_k else _candidate_blocks(tk, _LANES)
+    if window is not None:
+        cap = max(_LANES, window)
+        qs = qs if block_q else [b for b in qs if b <= cap] or qs[-1:]
+        ks = ks if block_k else [b for b in ks if b <= cap] or ks[-1:]
     iq = ik = 0
     while _vmem_bytes(sweep, qs[iq], ks[ik], d, dv, itemsize) > _VMEM_BUDGET:
         q_next, k_next = iq + 1 < len(qs), ik + 1 < len(ks)
@@ -165,28 +182,76 @@ def _first_live_q(ki, q_block, k_block, n_qb):
     return jnp.minimum((ki * k_block) // q_block, n_qb - 1)
 
 
-def _masked_scores(qb, kb, qi, ki, q_block, k_block, scale, causal, precision):
+def _band_k(qi, q_block, k_block, window, xp=jnp):
+    """(first, last) K block holding a key of Q block qi's band: the keys
+    max(0, i - window + 1) .. i of its rows i, one contiguous stretch, so
+    every block between the two holds some row's key."""
+    first = xp.maximum(qi * q_block - window + 1, 0) // k_block
+    return first, ((qi + 1) * q_block - 1) // k_block
+
+
+def _band_q(ki, q_block, k_block, window, n_qb, xp=jnp):
+    """(first, last) Q block whose band reaches K block ki: rows
+    ki * k_block .. (ki + 1) * k_block + window - 2, cut at the axis."""
+    last = xp.minimum(((ki + 1) * k_block + window - 2) // q_block, n_qb - 1)
+    return (ki * k_block) // q_block, last
+
+
+def _band_steps(sweep: str, t: int, q_block: int, k_block: int,
+                window: int) -> int:
+    """Grid steps of a band sweep: the most blocks one Q block's band spans
+    (dkv: one K block's), over every block of the axis."""
+    if sweep == "dkv":
+        first, last = _band_q(np.arange(t // k_block), q_block, k_block,
+                              window, t // q_block, np)
+    else:
+        first, last = _band_k(np.arange(t // q_block), q_block, k_block,
+                              window, np)
+    return int((last - first).max()) + 1
+
+
+def band_tiles(t: int, q_block: int, k_block: int, window: int | None) -> int:
+    """Tiles a head computes in the forward sweep: the band's live tiles, or
+    under `window=None` the causal ones."""
+    qi = np.arange(t // q_block)
+    if window is None:
+        return int((np.minimum(((qi + 1) * q_block - 1) // k_block,
+                               t // k_block - 1) + 1).sum())
+    first, last = _band_k(qi, q_block, k_block, window, np)
+    return int((last - first + 1).sum())
+
+
+def _masked_scores(qb, kb, qi, ki, q_block, k_block, scale, causal, precision,
+                   window=None):
     """Scaled (and causally masked) f32 score tile s = (q @ k^T) * scale —
     the single definition shared by the forward and both backward kernels so
     masking/scaling can never desynchronize between them. The scale is on
-    the f32 tile: q k^T keeps exact products of the stored operands."""
+    the f32 tile: q k^T keeps exact products of the stored operands. A
+    window masks both edges of the band: i - window < j <= i."""
     s = _dot(qb, kb, _NT, precision) * scale
     if causal:
         q_idx = qi * q_block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         k_idx = ki * k_block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(q_idx >= k_idx, s, -jnp.inf)
+        seen = q_idx >= k_idx
+        if window is not None:
+            seen = seen & (q_idx - k_idx < window)
+        s = jnp.where(seen, s, -jnp.inf)
     return s
 
 
-def attention_reference(q, k, v, causal: bool = False, scale=None):
+def attention_reference(q, k, v, causal: bool = False, scale=None,
+                        window: int | None = None):
     """Plain-jnp scaled dot-product attention. q/k: [B, T, H, D], v:
-    [B, T, H, Dv]; `scale` as `flash_attention`'s."""
+    [B, T, H, Dv]; `scale` and `window` as `flash_attention`'s."""
     d = q.shape[-1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
     s = s / np.sqrt(d) if scale is None else s * scale
     if causal:
         tq, tk = q.shape[1], k.shape[1]
         mask = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]
+        if window is not None:
+            mask = mask & (jnp.arange(tq)[:, None] - jnp.arange(tk)[None, :]
+                           < window)
         s = jnp.where(mask[None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
@@ -194,12 +259,13 @@ def attention_reference(q, k, v, causal: bool = False, scale=None):
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_scr, m_scr,
                       l_scr, *, causal, n_kb, q_block, k_block, scale,
-                      precision):
+                      precision, window=None):
     """Grid (batch*head, q_blocks, k_blocks): TPU iterates the last grid dim
     sequentially, so the f32 scratch accumulators (numerator O, running max
     M, denominator L; M and L a [block_q, 1] column) persist across the
     K-block sweep — K/V truly stream through VMEM one [block_k, D] tile at a
-    time."""
+    time. Under a window the last grid dim counts the band's steps (`n_kb`
+    of them) and step ki is K block first + ki."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
@@ -212,16 +278,21 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_scr, m_scr,
         l_scr[:] = jnp.zeros_like(l_scr)
 
     # causal: K blocks strictly after this Q block's last row are all masked
-    @pl.when(_block_live(qi, ki, q_block, k_block, causal))
+    live, kb = _sweep_step(qi, ki, q_block, k_block, causal, window)
+
+    @pl.when(live)
     def _block():
         vb = v_ref[:]
-        s = _masked_scores(q_ref[:], k_ref[:], qi, ki, q_block, k_block,
-                           scale, causal, precision)
+        s = _masked_scores(q_ref[:], k_ref[:], qi, kb, q_block, k_block,
+                           scale, causal, precision, window)
         m = m_scr[:]
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         # exp(-inf - -inf) guard: rows with no valid keys yet keep m=-inf
         alpha = jnp.exp(jnp.where(m == -jnp.inf, 0.0, m - m_new))
-        p = jnp.exp(s - m_new)
+        if window is None:
+            p = jnp.exp(s - m_new)
+        else:   # a band's row may have no key in its first live tile
+            p = jnp.exp(s - jnp.where(m_new == -jnp.inf, 0.0, m_new))
         l_scr[:] = l_scr[:] * alpha + p.sum(axis=-1, keepdims=True)
         o_scr[:] = o_scr[:] * alpha + _dot(p.astype(vb.dtype), vb, _NN,
                                            precision)
@@ -236,45 +307,77 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, o_scr, m_scr,
         lse_ref[:] = m_scr[:] + jnp.log(l)
 
 
+def _sweep_step(qi, ki, q_block, k_block, causal, window):
+    """(whether tile (qi, step ki) computes, the K block it names) of a
+    sweep over K blocks: step ki is block ki, or under a window the band's
+    first block + ki, live up to the band's last."""
+    if window is None:
+        return _block_live(qi, ki, q_block, k_block, causal), ki
+    first, last = _band_k(qi, q_block, k_block, window)
+    return first + ki <= last, first + ki
+
+
 def _softmax_scale(scale, d: int) -> float:
     """The factor on q k^T: the caller's, or d^-0.5 of the q/k width."""
     return 1.0 / np.sqrt(d) if scale is None else float(scale)
 
 
-def _kv_sweep_maps(causal, block_q, block_k, n_kb):
+def _kv_sweep_maps(causal, block_q, block_k, n_kb, window=None):
     """Index maps of a (batch*head, q_blocks, k_blocks) grid: Q-side blocks
     follow the Q block, K-side blocks the K block, held at the last live one
-    under a causal mask."""
+    under a causal mask; under a window the K block is the band's first +
+    the step, held at the band's last."""
     def q_map(g, i, j):
         return g, i, 0
 
     def k_map(g, i, j):
-        if causal:
+        if window is not None:
+            first, last = _band_k(i, block_q, block_k, window)
+            j = jnp.minimum(first + j, last)
+        elif causal:
             j = jnp.minimum(j, _last_live_k(i, block_q, block_k, n_kb))
         return g, j, 0
 
     return q_map, k_map
 
 
+def _sweep_len(sweep, causal, t, block_q, block_k, window, n_blocks):
+    """Grid steps of a sweep: the axis's blocks, or the band's widest."""
+    if window is None:
+        return n_blocks
+    if not causal:
+        raise ValueError("a window is a causal band: give causal=True")
+    return _band_steps(sweep, t, block_q, block_k, window)
+
+
+def _call_name(sweep: str, window) -> str:
+    return f"flash_{'attention' if window is None else 'window'}_{sweep}"
+
+
 def _flash_fwd(q, k, v, causal: bool, block_q, block_k, interpret: bool,
-               return_lse: bool = False, scale=None):
+               return_lse: bool = False, scale=None, window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, tq, h, d = q.shape
     tk, dv = k.shape[1], v.shape[-1]
+    if window is not None and tq != tk:
+        raise ValueError(f"a window needs as many queries as keys, not "
+                         f"({tq}, {tk})")
     block_q, block_k = flash_blocks("fwd", tq, tk, d, dv, q.dtype,
-                                    block_q, block_k)
+                                    block_q, block_k, window)
     # [B, T, H, D] -> [B*H, T, D] program-major layout
     qr = q.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
     kr = k.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
     vr = v.transpose(0, 2, 1, 3).reshape(b * h, tk, dv)
-    n_kb = tk // block_k
+    n_kb = _sweep_len("fwd", causal, tk, block_q, block_k, window,
+                      tk // block_k)
     kernel = functools.partial(
         _flash_fwd_kernel, causal=causal, n_kb=n_kb,
         q_block=block_q, k_block=block_k,
-        scale=_softmax_scale(scale, d), precision=_precision(q.dtype))
-    q_map, k_map = _kv_sweep_maps(causal, block_q, block_k, n_kb)
+        scale=_softmax_scale(scale, d), precision=_precision(q.dtype),
+        **({} if window is None else {"window": window}))
+    q_map, k_map = _kv_sweep_maps(causal, block_q, block_k, n_kb, window)
 
     out, lse = pl.pallas_call(
         kernel,
@@ -301,7 +404,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q, block_k, interpret: bool,
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_BUDGET),
         interpret=interpret,
-        name="flash_attention_fwd",
+        name=_call_name("fwd", window),
     )(qr, kr, vr)
     out4 = out.reshape(b, h, tq, dv).transpose(0, 2, 1, 3)
     if return_lse:
@@ -309,15 +412,17 @@ def _flash_fwd(q, k, v, causal: bool, block_q, block_k, interpret: bool,
     return out4
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal: bool = False,
                     block_q: int | None = None, block_k: int | None = None,
                     interpret: bool | None = None,
-                    scale: float | None = None):
+                    scale: float | None = None, window: int | None = None):
     """Flash attention, pallas kernels both ways. q/k: [B, T, H, D], v:
     [B, T, H, Dv]; the output has v's width. Dv may differ from D (latent
     attention trains with 192-wide q/k and 128-wide v): v is never padded to
-    D. `scale` multiplies q k^T; None is D^-0.5.
+    D. `scale` multiplies q k^T; None is D^-0.5. `window` W (with `causal`,
+    as many queries as keys) is a sliding window: query i sees keys
+    i - W < j <= i, and only the band's tiles are visited.
 
     `block_q` / `block_k` None is the kernel's own choice for each of its
     three sweeps (`flash_blocks`); a given block is used in all three.
@@ -325,12 +430,13 @@ def flash_attention(q, k, v, causal: bool = False,
     (the CPU CI path). The backward is BLOCKED too (p recomputed per tile
     from the saved logsumexp) — O(T) memory for training as well."""
     return _flash_fwd(q, k, v, causal, block_q, block_k,
-                      _resolve_interpret(interpret), scale=scale)
+                      _resolve_interpret(interpret), scale=scale,
+                      window=window)
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                          dq_ref, dq_scr, *, causal, n_kb, q_block, k_block,
-                         scale, precision):
+                         scale, precision, window=None):
     """Grid (batch*head, q_blocks, k_blocks): sweeps K blocks, accumulating
     this Q block's gradient in f32 scratch. p is recomputed from the saved
     logsumexp, so only [block_q, block_k] tiles ever exist."""
@@ -343,11 +449,13 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    @pl.when(_block_live(qi, ki, q_block, k_block, causal))
+    live, kb_i = _sweep_step(qi, ki, q_block, k_block, causal, window)
+
+    @pl.when(live)
     def _block():
         kb = k_ref[:]
-        s = _masked_scores(q_ref[:], kb, qi, ki, q_block, k_block,
-                           scale, causal, precision)
+        s = _masked_scores(q_ref[:], kb, qi, kb_i, q_block, k_block,
+                           scale, causal, precision, window)
         p = jnp.exp(s - lse_ref[:])                       # [bq, bk] via [bq,1]
         dp = _dot(do_ref[:], v_ref[:], _NT, precision)    # [bq, bk]
         ds = p * (dp - dl_ref[:])
@@ -360,9 +468,12 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                           dk_ref, dv_ref, dk_scr, dv_scr, *, causal, n_qb,
-                          q_block, k_block, scale, precision):
+                          q_block, k_block, scale, precision, window=None,
+                          n_q=None):
     """Grid (batch*head, k_blocks, q_blocks): sweeps Q blocks, accumulating
-    this K block's dK and dV in f32 scratch."""
+    this K block's dK and dV in f32 scratch. Under a window the last grid
+    dim counts the band's steps (`n_qb` of them) over the axis's `n_q`
+    blocks, and step qi is Q block first + qi."""
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
@@ -373,12 +484,18 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(_block_live(qi, ki, q_block, k_block, causal))
+    if window is None:
+        live, qb_i = _block_live(qi, ki, q_block, k_block, causal), qi
+    else:
+        first, last = _band_q(ki, q_block, k_block, window, n_q)
+        live, qb_i = first + qi <= last, first + qi
+
+    @pl.when(live)
     def _block():
         qb = q_ref[:]
         dob = do_ref[:]
-        s = _masked_scores(qb, k_ref[:], qi, ki, q_block, k_block,
-                           scale, causal, precision)
+        s = _masked_scores(qb, k_ref[:], qb_i, ki, q_block, k_block,
+                           scale, causal, precision, window)
         p = jnp.exp(s - lse_ref[:])                       # [bq, bk] via [bq,1]
         dv_scr[:] = dv_scr[:] + _dot(p.astype(dob.dtype), dob, _TN, precision)
         dp = _dot(dob, v_ref[:], _NT, precision)
@@ -392,7 +509,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 
 
 def _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k, interpret,
-               scale=None):
+               scale=None, window=None):
     """Blocked backward: dq/dk/dv without materializing [T, T]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -413,14 +530,16 @@ def _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k, interpret,
     scale = _softmax_scale(scale, d)
     bwd_in = (qr, kr, vr, gr, lse3, delta)
     params = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_BUDGET)
+    band = {} if window is None else {"window": window}
 
-    bq, bk = flash_blocks("dq", tq, tk, d, dv, q.dtype, block_q, block_k)
-    n_kb = tk // bk
-    q_map, k_map = _kv_sweep_maps(causal, bq, bk, n_kb)
+    bq, bk = flash_blocks("dq", tq, tk, d, dv, q.dtype, block_q, block_k,
+                          window)
+    n_kb = _sweep_len("dq", causal, tk, bq, bk, window, tk // bk)
+    q_map, k_map = _kv_sweep_maps(causal, bq, bk, n_kb, window)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, causal=causal, n_kb=n_kb,
                           q_block=bq, k_block=bk, scale=scale,
-                          precision=precision),
+                          precision=precision, **band),
         grid=(b * h, tq // bq, n_kb),
         in_specs=[
             pl.BlockSpec((None, bq, d), q_map),
@@ -435,26 +554,34 @@ def _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k, interpret,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
-        name="flash_attention_dq",
+        name=_call_name("dq", window),
     )(*bwd_in)
 
-    bq, bk = flash_blocks("dkv", tq, tk, d, dv, q.dtype, block_q, block_k)
-    n_qb = tq // bq
+    bq, bk = flash_blocks("dkv", tq, tk, d, dv, q.dtype, block_q, block_k,
+                          window)
+    n_q = tq // bq
+    n_qb = _sweep_len("dkv", causal, tq, bq, bk, window, n_q)
 
     def k_map(g_, j, i):
         return g_, j, 0
 
     def q_map(g_, j, i):
         # a causal K block's dead tiles come first: they name its first
-        # live Q block, which the first live step then finds in place
-        if causal:
+        # live Q block, which the first live step then finds in place; a
+        # band's come last and name its last live Q block
+        if window is not None:
+            first, last = _band_q(j, bq, bk, window, n_q)
+            i = jnp.minimum(first + i, last)
+        elif causal:
             i = jnp.maximum(i, _first_live_q(j, bq, bk, n_qb))
         return g_, i, 0
 
     dk, dv_ = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, causal=causal, n_qb=n_qb,
                           q_block=bq, k_block=bk, scale=scale,
-                          precision=precision),
+                          precision=precision,
+                          **({} if window is None else
+                             {"window": window, "n_q": n_q})),
         grid=(b * h, tk // bk, n_qb),
         in_specs=[
             pl.BlockSpec((None, bq, d), q_map),
@@ -476,7 +603,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k, interpret,
                         pltpu.VMEM((bk, dv), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
-        name="flash_attention_dkv",
+        name=_call_name("dkv", window),
     )(*bwd_in)
 
     def back4(t, tlen):
@@ -485,17 +612,17 @@ def _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k, interpret,
     return back4(dq, tq), back4(dk, tk), back4(dv_, tk)
 
 
-def _fa_fwd(q, k, v, causal, block_q, block_k, interpret, scale):
+def _fa_fwd(q, k, v, causal, block_q, block_k, interpret, scale, window):
     out, lse = _flash_fwd(q, k, v, causal, block_q, block_k,
                           _resolve_interpret(interpret), return_lse=True,
-                          scale=scale)
+                          scale=scale, window=window)
     return out, (q, k, v, out, lse)
 
 
-def _fa_bwd(causal, block_q, block_k, interpret, scale, res, g):
+def _fa_bwd(causal, block_q, block_k, interpret, scale, window, res, g):
     q, k, v, out, lse = res
     return _flash_bwd(q, k, v, out, lse, g, causal, block_q, block_k,
-                      _resolve_interpret(interpret), scale)
+                      _resolve_interpret(interpret), scale, window)
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)

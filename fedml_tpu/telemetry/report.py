@@ -198,6 +198,8 @@ def fold(records: List[Dict[str, Any]]) -> Dict[str, Any]:
     if built:
         report["model"] = {k: built[-1].get(k) for k in (
             "model", "layers", "mixers", "experts_held", "experts_routed")}
+        report["model"].update({k: built[-1][k] for k in (
+            "window", "attention_tiles") if k in built[-1]})
     loads = [e for e in events if e["kind"] == "moe_load"]
     if loads:
         # routed experts: the means over the rounds of every attribute the

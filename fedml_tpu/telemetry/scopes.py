@@ -36,11 +36,14 @@ import re
 #: rows into expert order, `moe_combine` the rows back to tokens), the KDA
 #: mixer (`models/kimi_linear.py`: the flax module `kda` and the scopes
 #: `kda`, `kda_conv`, `kda_gates`), the Mamba mixer (`models/jamba.py`: the
-#: flax module `mamba` and the scopes `ssm_conv`, `ssm_gates`), the blockwise
-#: loss (`core/trainer.py`), and the flax modules `attn` and `router`
+#: flax module `mamba` and the scopes `ssm_conv`, `ssm_gates`), the
+#: attention of a window layer and of a full one (`models/mellum.py`:
+#: `attn_window`, `attn_full`), the blockwise loss (`core/trainer.py`), and
+#: the flax modules `attn` and `router`
 DECLARED_SCOPES = ("experts", "moe_layout", "moe_gather", "moe_combine",
                    "kda", "kda_conv", "kda_gates", "mamba", "ssm_conv",
-                   "ssm_gates", "lm_loss", "attn", "router")
+                   "ssm_gates", "attn_window", "attn_full", "lm_loss", "attn",
+                   "router")
 
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _LOC_NAME = re.compile(r'loc\("([^"]*)"')

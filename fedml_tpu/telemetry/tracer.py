@@ -79,7 +79,8 @@ EVENT_SCHEMAS: Dict[str, set] = {
     # which program a trace is of, once, from the model's `describe()`
     # (`experiments/common.py::build_trainer`): registry name, layers,
     # mixers ({"kda": 4, "mla": 1}), the experts a layer holds and routes
-    # over; None where the model does not say
+    # over; None where the model does not say. A model with a band adds
+    # `window` and `attention_tiles` (`models/mellum.py`)
     "model_built": {"model", "layers", "mixers", "experts_held",
                     "experts_routed"},
     # superstep drive (algorithms/fedavg.py): one fused K-round dispatch

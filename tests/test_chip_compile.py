@@ -437,3 +437,77 @@ def test_jamba2_lora_round_compiles_beside_its_base(one_chip,
     print(mem)
     assert mem.argument_size_in_bytes > 3.1e9 > 1e8 > mem.output_size_in_bytes
     assert _fits(compiled) < 15.5e9
+
+
+@pytest.mark.parametrize("block", [256, 512, None],
+                         ids=["256", "512", "own-tiles"])
+def test_the_band_compiles_at_the_window_layers_widths(one_chip,
+                                                       no_compile_cache,
+                                                       block):
+    """The three band kernels (`window=1024`) as `mellum2_lora`'s window
+    layers call them: 2 lanes x 8,192 tokens x 32 heads of 128, bfloat16,
+    forward and backward, on explicit tiles and on the kernel's own
+    (1,024, no wider than the band). The benchmark's band roofline finds them by these
+    names."""
+    from fedml_tpu.ops.attention import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, True, block, block, False,
+                                       0.088, 1024).astype(jnp.float32))
+
+    arg = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16,
+                               sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(arg, arg, arg).compile()
+    text = compiled.as_text()
+    assert all(f"flash_window_{k}" in text for k in ("fwd", "dq", "dkv"))
+    assert "flash_attention_" not in text
+    _fits(compiled)
+
+
+@pytest.mark.slow  # a minute of TPU compile
+def test_mellum2_lora_round_compiles_beside_its_base(one_chip,
+                                                     no_compile_cache):
+    """engine.round for `benchmarks/configs/mellum2_lora.json` (4 layers of
+    Mellum2-12B-A2.5B, bfloat16 base, rank-16 adapters, 2 lanes x 1 x 8,192
+    tokens a step): fits beside its 4.25 GB base, returns none of it, and
+    holds both kinds of flash call and the grouped expert product."""
+    from fedml_tpu.algorithms.aggregators import make_aggregator
+    from fedml_tpu.algorithms.engine import build_round_fn
+    from fedml_tpu.core.config import FedConfig
+    from fedml_tpu.core.trainer import NWPTrainer
+    from fedml_tpu.models.lora import LoRATrainer
+    from fedml_tpu.models.registry import create_model
+    from fedml_tpu.ops import attention, moe
+
+    cfg = FedConfig(model="mellum", client_num_in_total=20,
+                    client_num_per_round=2, epochs=1, batch_size=1, lr=0.03,
+                    lora_rank=16, dtype="bfloat16")
+    trainer = LoRATrainer(NWPTrainer(create_model(
+        "mellum", output_dim=98304, dtype="bfloat16",
+        config="benchmarks/configs/mellum2_lora.json")), rank=16)
+    agg = make_aggregator("fedavg", cfg)
+    gv = jax.eval_shape(lambda: trainer.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32)))
+    tokens = jax.ShapeDtypeStruct((2, 4, 8192), jnp.int32)
+    args = _on(one_chip, (
+        gv, jax.eval_shape(agg.init_state, gv), tokens, tokens,
+        jax.ShapeDtypeStruct((2,), jnp.int32),
+        jax.eval_shape(lambda: jax.random.PRNGKey(0))))
+    mods = (attention, moe)
+    was = [m.interpret_off_chip for m in mods]
+    for m in mods:
+        m.interpret_off_chip = lambda k: False
+    try:
+        compiled = build_round_fn(
+            trainer, cfg, agg, donate_data=True,
+            collect_stats=True).jitted.lower(*args).compile()
+    finally:
+        for m, f in zip(mods, was):
+            m.interpret_off_chip = f
+    mem = compiled.memory_analysis()
+    print(mem)
+    assert mem.argument_size_in_bytes > 4.2e9 > 1e8 > mem.output_size_in_bytes
+    assert _fits(compiled) < 15.5e9
+    text = compiled.as_text()
+    assert all(k in text for k in ("flash_window_fwd", "flash_attention_fwd",
+                                   "moe_grouped_matmul"))

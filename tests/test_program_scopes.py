@@ -1,7 +1,8 @@
 """The round program's scope map (`FedAvgAPI.program_scopes`,
 `telemetry/scopes.py`): on the tiny DeepSeek-V2 and Kimi Linear cells it
 names the expert dispatch's phases in the forward and the backward and the
-KDA mixer's scopes, and on the tiny Jamba cell the Mamba mixer's; a second
+KDA mixer's scopes, on the tiny Jamba cell the Mamba mixer's and on the
+tiny Mellum cell its two kinds of attention; a second
 call is the same map and compiles nothing;
 a compile cache filled without the scopes reads stale and maps nothing;
 the HLO parser reads the optimised text's numbered tuple headers; the
@@ -128,6 +129,32 @@ def test_the_mamba_mixer_is_named_in_jamba():
         held = [p for p in names.values() if scopes.holds(p, scope)]
         assert held and event["scoped"][scope] == len(held), scope
     assert not {"kda", "experts"} & set(event["scoped"])
+
+
+def test_the_two_kinds_of_attention_are_named_in_mellum():
+    """The Mellum round program's compiled text names the window layer's
+    attention and the full layer's, forward and backward, and the map holds
+    each; the other decoders' mixers are not there."""
+    before = getattr(jax.config, METADATA_KEY)
+    jax.config.update(METADATA_KEY, True)
+    try:
+        api = _tiny_api("mellum2_lora")
+        tracer = telemetry.Tracer()
+        api.train_one_round(0, tracer=tracer)
+        names = api.program_scopes(tracer)
+        text = api._round_program.lower(*api._round_avals).compile().as_text()
+    finally:
+        jax.config.update(METADATA_KEY, before)
+    [event] = tracer.find_events("program_scopes")
+    paths = re.findall(r'op_name="([^"]*)"', text)
+    for scope in ("attn_window", "attn_full"):
+        named = [p for p in paths if scopes.holds(p, scope)]
+        assert [p for p in named if "transpose(" in p], scope
+        assert [p for p in named if "transpose(" not in p], scope
+        held = [p for p in names.values() if scopes.holds(p, scope)]
+        assert held and event["scoped"][scope] == len(held), scope
+    assert not {"kda", "mamba"} & set(event["scoped"])
+    assert "experts" in event["scoped"]
 
 
 def test_the_map_comes_from_memory_and_the_event_says_what_it_holds(scoped):

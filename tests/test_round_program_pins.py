@@ -1,4 +1,4 @@
-"""The four cells' round programs, pinned at tiny sizes by PR 34's method
+"""The cells' round programs, pinned at tiny sizes by PR 34's method
 (PERF.md section 6): the sha256 of `jax.make_jaxpr(api.round_fn)` on round
 0's cohort, each cell's own configuration with its data cut. A change of
 lowering metadata alone (a `jax.named_scope`, PR 40) leaves every hash as it
@@ -7,7 +7,10 @@ says so and re-pins it here. `dsv2lite_lora` was re-pinned when its expert
 layers moved to the one row-space dispatch (`ops/moe.py`, every expert
 held); `dsv2lite_lora` and `kimi_linear_lora` were re-pinned when the
 blockwise loss took its states' gradient from its forward pass
-(`core/trainer.py::_ce_sums`); the image cells' hashes never moved."""
+(`core/trainer.py::_ce_sums`); the image cells' hashes never moved.
+`jamba2_lora` was pinned on the tree before the flash kernel took a sliding
+window, so that the band is shown to leave every cell's round program as
+it was; `mellum2_lora` is the program that window came for."""
 
 import hashlib
 import json
@@ -30,6 +33,10 @@ PINS = {
         "d1b96081e85b6638f08820304c094fa866e5f72676967d437fc3832062d9d1c3",
     "kimi_linear_lora":
         "30990de46b8ceb22dd92328083e710735467d8d5f0ff9e884b764d4ad5d37d58",
+    "jamba2_lora":
+        "86a01b09ee94246cb9a654a5a19f3fd56ab2cb0eaa2fb3f350438de870a0efca",
+    "mellum2_lora":
+        "6c9a41c2becca2dbfc207b66897ca355c4172ef01b3ed968be6cb7a6223465c8",
 }
 
 
